@@ -36,7 +36,7 @@ def test_ring_attention_exact_over_8_shards(causal):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from znicz_tpu.parallel.mesh import make_mesh, shard_map
+    from znicz_tpu.parallel.mesh import make_mesh
 
     mesh = make_mesh(axes=("sp",))
     n = mesh.shape["sp"]
@@ -46,7 +46,7 @@ def test_ring_attention_exact_over_8_shards(causal):
     q, k, v = (rng.normal(size=(2, T, 2, 4)).astype(np.float32)
                for _ in range(3))
 
-    ring = shard_map(
+    ring = jax.shard_map(
         lambda q, k, v: ring_attention(q, k, v, "sp", causal=causal),
         mesh=mesh,
         in_specs=(P(None, "sp"), P(None, "sp"), P(None, "sp")),
@@ -270,7 +270,7 @@ def test_sequence_parallel_training_grads_match_and_learn():
     from jax.sharding import PartitionSpec as P
 
     from znicz_tpu.ops.attention import attention, ring_attention
-    from znicz_tpu.parallel.mesh import make_mesh, shard_map
+    from znicz_tpu.parallel.mesh import make_mesh
 
     B, T, H, D, E = 2, 32, 2, 8, 16
     rng = np.random.default_rng(11)
@@ -300,8 +300,8 @@ def test_sequence_parallel_training_grads_match_and_learn():
         return jax.lax.pmean(local, "sp")
 
     spec = P(None, "sp", None)
-    sharded_loss = shard_map(sp_loss, mesh=mesh, in_specs=(P(), spec, spec),
-                             out_specs=P())
+    sharded_loss = jax.shard_map(sp_loss, mesh=mesh,
+                                 in_specs=(P(), spec, spec), out_specs=P())
 
     def ref_loss(p, x, y):
         return jnp.mean(jnp.square(model(p, x, ring=False) - y))

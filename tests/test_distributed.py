@@ -19,8 +19,7 @@ WORKER = textwrap.dedent("""\
     # verify=False: the count check would initialize the backend, which
     # must not happen before jax.distributed.initialize
     provision_cpu_devices(1, verify=False)
-    from znicz_tpu.parallel.mesh import (distributed_init, make_mesh,
-                                         shard_map)
+    from znicz_tpu.parallel.mesh import distributed_init, make_mesh
 
     pid, n, port = int(sys.argv[1]), int(sys.argv[2]), sys.argv[3]
     distributed_init(coordinator=f"127.0.0.1:{port}",
@@ -34,8 +33,8 @@ WORKER = textwrap.dedent("""\
     d = len(jax.devices())                   # global across BOTH processes
     assert d > len(jax.local_devices()), "no cross-process devices visible"
     mesh = make_mesh(axes=("data",))         # all d global devices
-    psum = shard_map(lambda x: jax.lax.psum(x, "data"), mesh=mesh,
-                     in_specs=P("data"), out_specs=P())
+    psum = jax.shard_map(lambda x: jax.lax.psum(x, "data"), mesh=mesh,
+                         in_specs=P("data"), out_specs=P())
     # every process passes the same [0..d) array; jit shards it over the
     # global mesh, so the psum crosses the process (DCN) boundary
     x = np.arange(float(d), dtype=np.float32)

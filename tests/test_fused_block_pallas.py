@@ -76,15 +76,16 @@ def test_fused_block_forward_bf16_within_tolerance():
                                np.asarray(ref), rtol=2e-2, atol=2e-2)
 
 
-def test_fused_block_grad_matches_composed_vjp():
+@pytest.mark.parametrize("channels", [32, 256])   # 256: two lane chunks
+def test_fused_block_grad_matches_composed_vjp(channels):
     import jax
     import jax.numpy as jnp
 
     from znicz_tpu.pallas_fused_block import fused_block
 
-    x = _rand((2, 9, 9, 32), 11, 2.0)
-    b = _rand((32,), 12, 0.1)
-    cot = _rand((2, 4, 4, 32), 13)
+    x = _rand((2, 9, 9, channels), 11, 2.0)
+    b = _rand((channels,), 12, 0.1)
+    cot = _rand((2, 4, 4, channels), 13)
 
     gx, gb = jax.grad(
         lambda xx, bb: jnp.sum(
@@ -182,8 +183,10 @@ def _tiny_alexstyle_workflow(minibatch_size=50, max_epochs=2,
 
 
 def test_plan_matches_conv_block_and_respects_flag():
-    from znicz_tpu.pallas_fused_block import plan_fused_blocks
+    from znicz_tpu.pallas_fused_block import lanes_tile, plan_fused_blocks
 
+    # strided VMEM windows address whole 128-lane tiles (Mosaic)
+    assert lanes_tile(96) and lanes_tile(256) and not lanes_tile(192)
     wf = _tiny_alexstyle_workflow()
     assert plan_fused_blocks(wf.forwards) == {}      # flag off -> no plan
     root.common.engine.fused_elementwise = True

@@ -186,6 +186,40 @@ def test_train_shard_mesh_1x1_is_bitexact_single_device(engine_mesh):
         assert np.array_equal(w_off[name], w_on[name]), name
 
 
+def test_local_fused_engine_takes_the_config_mesh(engine_mesh):
+    """``engine.train``'s local fused branch reads the gate a fused
+    slave reads (ISSUE 21): off, it is the plain single-device trainer
+    bit for bit whatever the mesh knobs say; on, every parameter lives
+    on all four devices of the slice."""
+    from znicz_tpu.engine import train
+
+    def run_engine():
+        wf = _tiny_mnist_wf(layers=(100, 10))
+        losses = []
+        wf.decision.on_epoch_end.append(
+            lambda d: losses.append(d.epoch_metrics[2]["loss"]))
+        train(wf)
+        return wf, losses
+
+    _, l_ref, w_ref = _run_fused(_tiny_mnist_wf(layers=(100, 10)))
+    root.common.engine.fused = True
+    try:
+        engine_mesh(4, 1, shard=False)
+        wf, l_off = run_engine()
+        assert l_off == l_ref
+        for f in wf.forwards:
+            if f.has_weights:
+                assert np.array_equal(f.weights.map_read(), w_ref[f.name])
+        engine_mesh(4, 1)
+        wf, l_on = run_engine()
+        np.testing.assert_allclose(l_on, l_ref, rtol=1e-3)
+        for f in wf.forwards:
+            for arr in f.params().values():
+                assert len(arr.devmem.sharding.device_set) == 4, f.name
+    finally:
+        root.common.engine.fused = False
+
+
 # -- sharded staged segments (ISSUE 18 satellite 2) ---------------------------
 
 

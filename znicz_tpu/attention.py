@@ -116,12 +116,11 @@ class MultiHeadAttention(ForwardBase):
             # the shard_map (tests/test_attention.py proves exactness +
             # grad parity).  A shape the mesh cannot split (a short
             # serving bucket) falls back to the dense core — same math.
+            import jax
             from jax.sharding import PartitionSpec as P
 
-            from znicz_tpu.parallel.mesh import shard_map
-
             spec = P(bax, sax)
-            o = shard_map(
+            o = jax.shard_map(
                 lambda q, k, v: self._core(q, k, v, sax),
                 mesh=self._sp_mesh,
                 in_specs=(spec, spec, spec), out_specs=spec)(q, k, v)

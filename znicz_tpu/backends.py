@@ -9,6 +9,7 @@ the reference's master/slave distribution, SURVEY.md §2.4).
 
 from __future__ import annotations
 
+import functools
 import os
 import sys
 from typing import Optional, Sequence, Tuple
@@ -69,6 +70,60 @@ def configure_xla_flags(environ=None) -> Tuple[str, ...]:
             return ()
     environ["XLA_FLAGS"] = (current + " " + " ".join(fresh)).strip()
     return fresh
+
+
+def checkout_dir() -> str:
+    """The checkout root (the directory holding ``znicz_tpu/``), found
+    from this file — never from the working directory."""
+    return os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def cache_dir() -> str:
+    """``root.common.dirs.cache``, a relative value resolved against the
+    checkout: what the program builds (the native library, compiled
+    executables) is found again from any working directory."""
+    from znicz_tpu.core.config import root
+
+    return os.path.join(checkout_dir(),
+                        str(root.common.dirs.get("cache", ".znicz_cache")))
+
+
+def configure_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; returns the directory
+    in effect.  Where ``JAX_COMPILATION_CACHE_DIR`` is set jax reads it
+    itself and the program sets no directory in code — whoever runs the
+    program owns the placement.  Otherwise the cache lives at
+    ``<cache_dir>/jax``: a fixed path (it is part of the cache key, so a
+    directory that moves never hits).  Every executable is kept,
+    however quick its compile, so a second run of the same program adds
+    no entry.  Call before the first compile."""
+    import jax
+
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    path = os.path.join(cache_dir(), "jax")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+@functools.cache
+def pallas_interpret() -> bool:
+    """Whether the Pallas kernels run interpreted: only on the ``cpu``
+    backend, which has no Mosaic compiler (said once, at INFO).  Any
+    other backend compiles them, and a kernel its compiler refuses fails
+    loudly instead of quietly running interpreted."""
+    import jax
+
+    if jax.default_backend() != "cpu":
+        return False
+    import logging
+
+    logging.getLogger("znicz").info(
+        "Pallas kernels run in interpret mode on the cpu backend")
+    return True
 
 
 class Device:

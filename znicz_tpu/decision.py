@@ -49,6 +49,8 @@ class DecisionBase(Unit):
         self.minibatch_loss = 0.0
         # epoch accumulators / history
         self.epoch_metrics = [None, None, None]   # last finished epoch
+        #: {class name: mean loss} of every finished epoch, oldest first
+        self.epoch_history: List[dict] = []
         self._acc_loss = [0.0, 0.0, 0.0]
         self._acc_batches = [0, 0, 0]
         self.best_metric = np.inf
@@ -117,6 +119,10 @@ class DecisionBase(Unit):
                      self._fails >= self.fail_iterations))
             self.complete.set(done)
             self.epoch_ended.set(True)
+            self.epoch_history.append(
+                {CLASS_NAMES[k]: self.epoch_metrics[k]["loss"]
+                 for k in (TEST, VALID, TRAIN)
+                 if self.class_lengths[k] and self.epoch_metrics[k]})
             self._log_epoch()
             for cb in self.on_epoch_end:
                 cb(self)

@@ -88,9 +88,14 @@ def train(workflow) -> None:
     if _fused_capable(workflow):
         from znicz_tpu.parallel.fused import FusedTrainer, \
             FusedUnsupportedError
+        from znicz_tpu.parallel.mesh import train_mesh_from_config
 
         try:
-            trainer = FusedTrainer(workflow)
+            # root.common.engine.train_shard + engine.mesh.* place the
+            # local run over a pod slice exactly as they do a fused
+            # slave's (None, the single-device path, when gated off)
+            trainer = FusedTrainer(workflow,
+                                   mesh=train_mesh_from_config())
         except FusedUnsupportedError as exc:    # e.g. tied weights
             workflow.warning(
                 "--fused requested but the fused path cannot run this "

@@ -6,16 +6,20 @@ C++ piece: xorshift128+ PRNG (the reference's rand kernel family),
 Fisher-Yates shuffling, minibatch row gather, u8->f32 decode.
 
 The shared library is built on first use with g++ (cached under
-``root.common.dirs.cache``); every function has a numpy fallback so the
-framework works without a toolchain.  Consumers: the Loader's opt-in
-``native_shuffle`` path (``root.common.engine.native_shuffle`` or the
-per-loader kwarg), the image loader's u8->f32 decode, and host-side
-minibatch assembly via ``gather_f32``.
+``root.common.dirs.cache``, resolved against the checkout); every function
+has a numpy fallback so the framework works without a toolchain, and the
+first use says at INFO which of the two this process got.  Consumers: the
+Loader's opt-in ``native_shuffle`` path
+(``root.common.engine.native_shuffle`` or the per-loader kwarg), the image
+loader's u8->f32 decode, and host-side minibatch assembly via
+``gather_f32``.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
 import threading
@@ -23,36 +27,41 @@ from typing import Optional
 
 import numpy as np
 
+log = logging.getLogger("znicz")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
 def _source_path() -> str:
-    return os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "native", "znicz_native.cpp")
+    from znicz_tpu.backends import checkout_dir
 
-
-def _cache_dir() -> str:
-    from znicz_tpu.core.config import root
-
-    d = root.common.dirs.get("cache", ".znicz_cache")
-    os.makedirs(d, exist_ok=True)
-    return d
+    return os.path.join(checkout_dir(), "native", "znicz_native.cpp")
 
 
 def build() -> Optional[str]:
-    """Compile the shared library; returns its path or None."""
+    """Compile the shared library; returns its path or None.  The library
+    is named by a hash of its source, so a library left in the cache by
+    another revision is never loaded in place of this one's source."""
+    from znicz_tpu.backends import cache_dir
+
     src = _source_path()
     if not os.path.exists(src):
         return None
-    out = os.path.join(_cache_dir(), "libznicz_native.so")
-    if (os.path.exists(out)
-            and os.path.getmtime(out) >= os.path.getmtime(src)):
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    cache = cache_dir()
+    os.makedirs(cache, exist_ok=True)
+    out = os.path.join(cache, f"libznicz_native-{digest}.so")
+    if os.path.exists(out):
         return out
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", src, "-o", out]
+    # build under a private name, then rename: a concurrent or killed
+    # build must never leave a half-written library under the final name
+    tmp = f"{out}.{os.getpid()}.tmp"
+    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", src, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, out)
         return out
     except (OSError, subprocess.SubprocessError):
         return None
@@ -64,32 +73,47 @@ def _load() -> Optional[ctypes.CDLL]:
         if _tried:
             return _lib
         _tried = True
-        path = build()
-        if path is None:
-            return None
-        try:
-            lib = ctypes.CDLL(path)
-        except OSError:
-            return None
-        u64p = ctypes.POINTER(ctypes.c_uint64)
-        f32p = ctypes.POINTER(ctypes.c_float)
-        i32p = ctypes.POINTER(ctypes.c_int32)
-        u8p = ctypes.POINTER(ctypes.c_uint8)
-        lib.znicz_seed.argtypes = [u64p, ctypes.c_uint64]
-        lib.znicz_fill_uniform.argtypes = [u64p, f32p, ctypes.c_size_t,
-                                           ctypes.c_float, ctypes.c_float]
-        lib.znicz_fill_normal.argtypes = [u64p, f32p, ctypes.c_size_t,
-                                          ctypes.c_float]
-        lib.znicz_shuffle_i32.argtypes = [u64p, i32p, ctypes.c_size_t]
-        lib.znicz_gather_f32.argtypes = [f32p, i32p, f32p, ctypes.c_size_t,
-                                         ctypes.c_size_t]
-        lib.znicz_u8_to_f32.argtypes = [u8p, f32p, ctypes.c_size_t,
-                                        ctypes.c_float, ctypes.c_float]
-        lib.znicz_native_abi.restype = ctypes.c_int
-        if lib.znicz_native_abi() != 1:
-            return None
-        _lib = lib
+        _lib = _load_library()
+        log.info("host runtime: %s", _describe(_lib))
         return _lib
+
+
+def _load_library() -> Optional[ctypes.CDLL]:
+    path = build()
+    if path is None:
+        return None
+    try:
+        lib = ctypes.CDLL(path)
+    except OSError:
+        return None
+    u64p = ctypes.POINTER(ctypes.c_uint64)
+    f32p = ctypes.POINTER(ctypes.c_float)
+    i32p = ctypes.POINTER(ctypes.c_int32)
+    u8p = ctypes.POINTER(ctypes.c_uint8)
+    lib.znicz_seed.argtypes = [u64p, ctypes.c_uint64]
+    lib.znicz_fill_uniform.argtypes = [u64p, f32p, ctypes.c_size_t,
+                                       ctypes.c_float, ctypes.c_float]
+    lib.znicz_fill_normal.argtypes = [u64p, f32p, ctypes.c_size_t,
+                                      ctypes.c_float]
+    lib.znicz_shuffle_i32.argtypes = [u64p, i32p, ctypes.c_size_t]
+    lib.znicz_gather_f32.argtypes = [f32p, i32p, f32p, ctypes.c_size_t,
+                                     ctypes.c_size_t]
+    lib.znicz_u8_to_f32.argtypes = [u8p, f32p, ctypes.c_size_t,
+                                    ctypes.c_float, ctypes.c_float]
+    lib.znicz_native_abi.restype = ctypes.c_int
+    if lib.znicz_native_abi() != 1:
+        return None
+    return lib
+
+
+def _describe(lib: Optional[ctypes.CDLL]) -> str:
+    return "numpy fallback" if lib is None else f"native {lib._name}"
+
+
+def implementation() -> str:
+    """Which host-runtime implementation this process uses: the loaded
+    C++ library's path, or the numpy fallback."""
+    return _describe(_load())
 
 
 def available() -> bool:

@@ -199,12 +199,10 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False):
         return m_new, l_new, acc_new, k_next, v_next
 
     def vary(x):
-        """Mark a fresh array as varying over the mesh axis (newer jax
-        shard_map tracks varying-axis types; loop carries must match)."""
-        try:
-            return lax.pcast(x, (axis_name,), to="varying")
-        except (AttributeError, TypeError):
-            return x
+        """Mark a fresh array as varying over every mesh axis q varies
+        over — the ring axis, and the batch axis too on a training mesh
+        (shard_map tracks varying-axis types; loop carries must match)."""
+        return lax.pcast(x, tuple(jax.typeof(q).vma), to="varying")
 
     m0 = vary(jnp.full((b, h, t), neg, jnp.float32))
     l0 = vary(jnp.zeros((b, h, t), jnp.float32))

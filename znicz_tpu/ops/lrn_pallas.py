@@ -11,7 +11,7 @@ The tensor is processed as (rows, C) tiles resident in VMEM: one pass for
 the forward, one for the backward, with the windowed channel sum unrolled
 (n is tiny and static).  The XLA fallback (`znicz_tpu/lrn.py`) remains the
 oracle; `lrn(x, ...)` is exactly substitutable and carries a custom_vjp.
-On non-TPU backends the kernel runs in interpreter mode (tests), or
+On the cpu backend the kernel runs in interpreter mode (tests), or
 callers just use the jnp path.
 
 Measured honestly (bench.py, 1x v5e, 2026-07-30): the AlexNet step runs
@@ -27,6 +27,8 @@ from __future__ import annotations
 import functools
 
 import numpy as np
+
+from znicz_tpu.backends import pallas_interpret
 
 TILE_R = 1024          # rows per grid step (multiple of 8 for f32 tiling)
 
@@ -127,12 +129,6 @@ def _as_rows(x):
     return flat, R
 
 
-def _use_interpret() -> bool:
-    import jax
-
-    return jax.default_backend() != "tpu"
-
-
 def _make():
     import jax
 
@@ -140,7 +136,7 @@ def _make():
     def lrn(x, n, alpha, beta, k):
         flat, R = _as_rows(x)
         y = _pallas_2d(functools.partial(_fwd_kernel, n, alpha, beta, k),
-                       [flat], _use_interpret())
+                       [flat], pallas_interpret())
         return y[:R].reshape(x.shape)
 
     def fwd(x, n, alpha, beta, k):
@@ -152,7 +148,7 @@ def _make():
         flat_x, R = _as_rows(x)
         flat_dy, _ = _as_rows(dy)
         dx = _pallas_2d(functools.partial(_bwd_kernel, n, alpha, beta, k),
-                        [flat_x, flat_dy], _use_interpret())
+                        [flat_x, flat_dy], pallas_interpret())
         return (dx[:R].reshape(x.shape).astype(x.dtype),)
 
     lrn.defvjp(fwd, bwd)

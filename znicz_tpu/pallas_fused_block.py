@@ -16,8 +16,9 @@ argmax masks) living only in VMEM.
 Grid: one image per grid step — a (1, H, W, C) block is VMEM-resident
 (conv1: 55*55*96*4 B = 1.2 MB f32).  The channel-window sum is unrolled
 static lane shifts (identical summation order to ops/lrn_pallas.py); the
-pool is unrolled ky*kx strided max/compare; the pool backward re-dilates
-window contributions with interior padding (lax.pad) — the same formulation
+pool is unrolled ky*kx strided max/compare over windows of a VMEM scratch
+plane; the pool backward re-dilates window contributions by strided
+accumulation into a second scratch plane — the same formulation
 ``pooling._masked_maxpool`` uses, but fused in VMEM where its ~18
 intermediate tensors are free instead of 18 HBM round trips.
 
@@ -39,9 +40,10 @@ block"), and only where the graph shape matches exactly:
 Conv(+bias)+StrictRELU (fused or as a standalone activation unit) ->
 LRNormalizerForward (odd window) -> MaxPooling whose windows tile the plane
 exactly (AlexNet's 55/27/13 planes all do; partial edge windows fall back
-to the composed ops).  The LRN-formulation experiment knobs
-(``lrn_pow`` / ``lrn_autodiff`` / ``pallas_lrn``) disable fusion so their
-side-by-side re-runs stay pure.
+to the composed ops), with a channel count that splits into whole
+128-lane tiles (``lanes_tile``; 96 and 256 do).  The LRN-formulation
+experiment knobs (``lrn_pow`` / ``lrn_autodiff`` / ``pallas_lrn``) disable
+fusion so their side-by-side re-runs stay pure.
 
 Backward wiring: ``fused_block`` carries a ``jax.custom_vjp``, so wherever
 the fused trainer's forward_pass routes through it, ``jax.grad`` of the
@@ -59,6 +61,8 @@ from typing import Dict, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
+from znicz_tpu.backends import pallas_interpret
+
 
 class FusedBlockSpec(NamedTuple):
     """One matched conv-block occurrence in a forwards list."""
@@ -70,12 +74,6 @@ class FusedBlockSpec(NamedTuple):
     beta: float
     k: float
     pool: Tuple[int, int, int, int]   # (ky, kx, sy, sx)
-
-
-def _use_interpret() -> bool:
-    import jax
-
-    return jax.default_backend() != "tpu"
 
 
 def _relu_lrn(x, b, n, alpha, beta, k):
@@ -94,38 +92,56 @@ def _relu_lrn(x, b, n, alpha, beta, k):
     return a, r, s, r * inv_pow_rsqrt(s, beta)
 
 
-def _pool_windows(y, ky, kx, sy, sx, oh, ow):
-    """ky*kx strided (OH, OW, C) window views of an exactly-tiling plane."""
-    from jax import lax
-
-    C = y.shape[-1]
-    wins = []
-    for i in range(ky):
-        for j in range(kx):
-            wins.append(lax.slice(
-                y, (i, j, 0),
-                (i + (oh - 1) * sy + 1, j + (ow - 1) * sx + 1, C),
-                (sy, sx, 1)))
-    return wins
+#: Mosaic's strided VMEM access addresses ONE 128-lane tile: planes wider
+#: than that live as ``C // _LANES`` lane chunks (``_plane_scratch``)
+_LANES = 128
 
 
-def _fwd_kernel(n, alpha, beta, k, ky, kx, sy, sx, x_ref, b_ref, out_ref):
+def _store_plane(ref, v):
+    """(H, W, C) value -> the (chunks, H, W, lanes) VMEM plane."""
+    lanes = ref.shape[-1]
+    for c in range(ref.shape[0]):
+        ref[c] = v[..., c * lanes:(c + 1) * lanes]
+
+
+def _load_plane(ref, win=(slice(None),) * 3):
+    """The (chunks, H, W, lanes) VMEM plane (or one window of it, see
+    ``_pool_windows``) as ONE (.., .., C) value."""
+    import jax.numpy as jnp
+
+    parts = [ref[(c,) + win] for c in range(ref.shape[0])]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=-1)
+
+
+def _pool_windows(ky, kx, sy, sx, oh, ow):
+    """Index tuples of the ky*kx strided (OH, OW, C) windows of an
+    exactly-tiling (H, W, C) VMEM plane.  Windows are strided REF
+    accesses: Mosaic lowers those (``tpu.strided_load``/``strided_store``)
+    but refuses a strided ``lax.slice`` of a value, and interior
+    ``lax.pad``."""
+    from jax.experimental import pallas as pl
+
+    return [(pl.ds(i, oh, stride=sy), pl.ds(j, ow, stride=sx), slice(None))
+            for i in range(ky) for j in range(kx)]
+
+
+def _fwd_kernel(n, alpha, beta, k, ky, kx, sy, sx,
+                x_ref, b_ref, out_ref, y_ref):
     import jax.numpy as jnp
 
     x = x_ref[0].astype(jnp.float32)
     b = b_ref[0].astype(jnp.float32)
-    _, _, _, y = _relu_lrn(x, b, n, alpha, beta, k)
+    _store_plane(y_ref, _relu_lrn(x, b, n, alpha, beta, k)[3])
     oh, ow = out_ref.shape[1], out_ref.shape[2]
-    p = None
-    for win in _pool_windows(y, ky, kx, sy, sx, oh, ow):
-        p = win if p is None else jnp.maximum(p, win)
+    p = functools.reduce(
+        jnp.maximum, (_load_plane(y_ref, win)
+                      for win in _pool_windows(ky, kx, sy, sx, oh, ow)))
     out_ref[0] = p.astype(out_ref.dtype)
 
 
 def _bwd_kernel(n, alpha, beta, k, ky, kx, sy, sx,
-                x_ref, b_ref, dp_ref, dx_ref, db_ref):
+                x_ref, b_ref, dp_ref, dx_ref, db_ref, y_ref, dy_ref):
     import jax.numpy as jnp
-    from jax import lax
     from jax.experimental import pallas as pl
 
     from znicz_tpu.ops.lrn_pallas import (inv_pow_rsqrt,
@@ -135,34 +151,27 @@ def _bwd_kernel(n, alpha, beta, k, ky, kx, sy, sx,
     b = b_ref[0].astype(jnp.float32)
     dp = dp_ref[0].astype(jnp.float32)
     a, r, s, y = _relu_lrn(x, b, n, alpha, beta, k)
+    _store_plane(y_ref, y)
     sb = inv_pow_rsqrt(s, beta)
-    H, W, _ = y.shape
     oh, ow = dp.shape[0], dp.shape[1]
     # pool backward: recompute window maxima, split dp among ties
     # (mass-conserving; see module docstring for the tie semantics)
-    wins = _pool_windows(y, ky, kx, sy, sx, oh, ow)
-    p = None
-    for win in wins:
-        p = win if p is None else jnp.maximum(p, win)
-    masks, nt = [], None
-    for win in wins:
-        mk = (win == p).astype(jnp.float32)
-        masks.append(mk)
-        nt = mk if nt is None else nt + mk
+    wins = _pool_windows(ky, kx, sy, sx, oh, ow)
+    vals = [_load_plane(y_ref, win) for win in wins]
+    p = functools.reduce(jnp.maximum, vals)
+    masks = [(v == p).astype(jnp.float32) for v in vals]
+    nt = functools.reduce(jnp.add, masks)
     g = dp / nt
-    dy, mi = None, 0
-    for i in range(ky):
-        for j in range(kx):
-            contrib = g * masks[mi]
-            mi += 1
-            # interior padding re-dilates the strided window back to
-            # plane coordinates — pure pad, no scatter, all in VMEM
-            part = lax.pad(
-                contrib, jnp.zeros((), jnp.float32),
-                ((i, H - (i + (oh - 1) * sy + 1), sy - 1),
-                 (j, W - (j + (ow - 1) * sx + 1), sx - 1),
-                 (0, 0, 0)))
-            dy = part if dy is None else dy + part
+    # strided accumulation re-dilates each window's share back to plane
+    # coordinates — no scatter, all in VMEM
+    dy_ref[...] = jnp.zeros(dy_ref.shape, jnp.float32)
+    lanes = dy_ref.shape[-1]
+    for win, mk in zip(wins, masks):
+        contrib = g * mk
+        for c in range(dy_ref.shape[0]):
+            dy_ref[(c,) + win] = (dy_ref[(c,) + win]
+                                  + contrib[..., c * lanes:(c + 1) * lanes])
+    dy = _load_plane(dy_ref)
     # LRN backward — the closed form from znicz_tpu/lrn.py:
     #   dr = dy*s^-beta - 2*alpha*beta * r * W(dy * r * s^(-beta-1))
     t = dy * r * (sb / s)
@@ -207,11 +216,27 @@ def _bias_spec(c):
                         memory_space=pltpu.VMEM)
 
 
+def lanes_tile(c: int) -> bool:
+    """Whether a C-channel plane splits into whole strided-access lane
+    tiles (see ``_LANES``): AlexNet's 96 and 256 both do."""
+    return c <= _LANES or c % _LANES == 0
+
+
+def _plane_scratch(h, w, c):
+    """One f32 (H, W, C) plane in VMEM as (chunks, H, W, lanes) — the home
+    of the LRN output (and, in the backward, of d_y) that the pool's
+    strided windows address."""
+    import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
+
+    lanes = min(c, _LANES)
+    return pltpu.VMEM((c // lanes, h, w, lanes), jnp.float32)
+
+
 def _compiler_params():
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(vmem_limit_bytes=_VMEM_LIMIT)
+    return pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
 
 
 def _call_fwd(x, bias, n, alpha, beta, k, pool):
@@ -227,8 +252,9 @@ def _call_fwd(x, bias, n, alpha, beta, k, pool):
         in_specs=[_img_spec(x.shape), _bias_spec(C)],
         out_specs=_img_spec((B, oh, ow, C)),
         out_shape=jax.ShapeDtypeStruct((B, oh, ow, C), x.dtype),
+        scratch_shapes=[_plane_scratch(H, W, C)],
         compiler_params=_compiler_params(),
-        interpret=_use_interpret(),
+        interpret=pallas_interpret(),
     )(x, bias.reshape(1, C))
 
 
@@ -247,8 +273,9 @@ def _call_bwd(x, bias, dp, n, alpha, beta, k, pool):
         out_specs=(_img_spec(x.shape), _bias_spec(C)),
         out_shape=(jax.ShapeDtypeStruct((B, H, W, C), x.dtype),
                    jax.ShapeDtypeStruct((1, C), jnp.float32)),
+        scratch_shapes=[_plane_scratch(H, W, C)] * 2,
         compiler_params=_compiler_params(),
-        interpret=_use_interpret(),
+        interpret=pallas_interpret(),
     )(x, bias.reshape(1, C), dp)
     return dx, db.reshape(bias.shape).astype(bias.dtype)
 
@@ -286,9 +313,11 @@ def fused_block(x, bias, n=5, alpha=1e-4, beta=0.75, k=2.0,
     if _fused is None:
         _fused = _make()
     ky, kx, sy, sx = (int(v) for v in pool)
-    _, H, W, _ = x.shape
+    _, H, W, C = x.shape
     assert (H - ky) % sy == 0 and (W - kx) % sx == 0, \
         f"pool {pool} does not tile ({H}, {W}) exactly"
+    assert lanes_tile(C), \
+        f"{C} channels do not split into whole {_LANES}-lane tiles"
     return _fused(x, bias, int(n), float(alpha), float(beta), float(k),
                   (ky, kx, sy, sx))
 
@@ -306,7 +335,8 @@ def match_fused_block(forwards: Sequence, i: int) -> Optional[FusedBlockSpec]:
     from znicz_tpu.pooling import MaxPooling
 
     conv = forwards[i]
-    if not isinstance(conv, Conv) or not conv.include_bias:
+    if not isinstance(conv, Conv) or not conv.include_bias \
+            or not lanes_tile(conv.n_kernels):
         return None
     j = i + 1
     if conv.ACTIVATION is activations.strict_relu:
@@ -430,7 +460,7 @@ def _call_bias_relu_fwd(x, bias):
         out_specs=_img_spec(x.shape),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         compiler_params=_compiler_params(),
-        interpret=_use_interpret(),
+        interpret=pallas_interpret(),
     )(x, bias.reshape(1, C))
 
 
@@ -448,7 +478,7 @@ def _call_bias_relu_bwd(x, bias, dp):
         out_shape=(jax.ShapeDtypeStruct(x.shape, x.dtype),
                    jax.ShapeDtypeStruct((1, C), jnp.float32)),
         compiler_params=_compiler_params(),
-        interpret=_use_interpret(),
+        interpret=pallas_interpret(),
     )(x, bias.reshape(1, C), dp)
     return dx, db.reshape(bias.shape).astype(bias.dtype)
 

@@ -26,19 +26,6 @@ from typing import Dict, Optional, Sequence, Tuple
 import numpy as np
 
 
-def shard_map(f, *args, **kwargs):
-    """``jax.shard_map`` across jax versions: promoted out of
-    ``jax.experimental.shard_map`` after the 0.4.x line, and this is
-    the one spot that has to know which home this interpreter has."""
-    import jax
-
-    try:
-        sm = jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as sm
-    return sm(f, *args, **kwargs)
-
-
 def make_mesh(shape: Optional[Tuple[int, ...]] = None,
               axes: Sequence[str] = ("data",), devices=None):
     """Build a Mesh over ``devices`` (default: all).  shape=None puts every
@@ -244,15 +231,6 @@ def distributed_init(coordinator: Optional[str] = None,
     import jax
 
     if num_processes and num_processes > 1:
-        try:
-            # jax 0.4.x CPU backends refuse multiprocess computations
-            # ("not implemented") unless a CPU collectives impl is
-            # switched on explicitly; newer jax defaults to gloo.  Must
-            # happen before initialize() wires the backend.
-            jax.config.update("jax_cpu_collectives_implementation",
-                              "gloo")
-        except (AttributeError, KeyError):
-            pass                     # option gone (newer jax): default ok
         jax.distributed.initialize(coordinator_address=coordinator,
                                    num_processes=num_processes,
                                    process_id=process_id)

@@ -179,7 +179,10 @@ def run(device=None, fused: bool = True, mesh=None) -> AlexNetWorkflow:
     wf.initialize(device=device)
     if fused:
         from znicz_tpu.parallel.fused import FusedTrainer
+        from znicz_tpu.parallel.mesh import train_mesh_from_config
 
+        if mesh is None:
+            mesh = train_mesh_from_config()
         FusedTrainer(wf, mesh=mesh).run()
         wf.print_stats()
     else:

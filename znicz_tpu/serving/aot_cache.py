@@ -61,18 +61,6 @@ from typing import Dict, Optional
 log = logging.getLogger("znicz.serving")
 
 
-def available() -> bool:
-    """True when this jax build ships ``serialize_executable`` (the
-    cache degrades to plain compile-every-boot when absent — serving
-    still works, elasticity is just slower)."""
-    try:
-        from jax.experimental import serialize_executable  # noqa: F401
-
-        return True
-    except Exception:                   # pragma: no cover - jax-version dep
-        return False
-
-
 def dir_for_snapshot(snapshot_path: str) -> str:
     """The cache directory for a snapshot: ``aot_cache/`` NEXT TO the
     snapshot file, so the cache travels with the weights it warms (a
@@ -172,9 +160,16 @@ class ExecutableCache:
             if blob.get("key") != self._key(entry):
                 raise ValueError("cached key does not match the "
                                  "requested entry (stale or tampered)")
+            import jax
             from jax.experimental import serialize_executable as se
 
-            return se.deserialize_and_load(*blob["payload"])
+            # load onto the devices it was compiled for: the default is
+            # EVERY device of the backend, which a single-device (or
+            # sub-mesh) executable refuses at its first call
+            by_id = {d.id: d for d in jax.devices()}
+            return se.deserialize_and_load(
+                *blob["payload"],
+                execution_devices=[by_id[i] for i in blob["devices"]])
         except Exception as exc:
             self.refuse(entry, exc)
             return None
@@ -190,8 +185,11 @@ class ExecutableCache:
             from jax.experimental import serialize_executable as se
 
             payload = se.serialize(compiled)
+            devices = [d.id for d in
+                       compiled.runtime_executable().local_devices()]
             atomic_write_bytes(self._path(entry), pickle.dumps(
-                {"key": self._key(entry), "payload": payload},
+                {"key": self._key(entry), "payload": payload,
+                 "devices": devices},
                 protocol=pickle.HIGHEST_PROTOCOL))
         except Exception as exc:
             self._n["store_failures"] += 1

@@ -617,9 +617,10 @@ class InferenceServer:
         self._thread = threading.Thread(target=self.serve, daemon=True,
                                         name="znicz-serve")
         self._thread.start()
-        if not self._ready.wait(timeout=120):
-            raise RuntimeError(f"inference server failed to come up on "
-                               f"{self.bind} within 120s")
+        # serve() sets _ready on success AND on failure, so this waits
+        # out a warm-up of any length (a cold compile of the executable
+        # family on the chip takes minutes) and still returns on an error
+        self._ready.wait()
         if self._serve_error is not None:
             # bind conflict / bad snapshot / warmup failure: surface the
             # REAL cause immediately instead of a generic bind message
@@ -740,9 +741,7 @@ class InferenceServer:
             if self._aot_enabled:
                 # arm the AOT executable cache (ISSUE 17) BEFORE any
                 # warmup dispatch: warmup then loads cached executables
-                # where they exist and serializes the ones it compiles.
-                # A jax build without serialize support degrades to
-                # plain compile-every-boot (enable returns False)
+                # where they exist and serializes the ones it compiles
                 self.runner.enable_aot_cache(self._aot_dir)
             if self._warmup:
                 # compile every rung BEFORE taking traffic: first-

@@ -523,13 +523,9 @@ class ModelRunner:
         """Arm the on-disk AOT executable cache (serving/aot_cache.py):
         warmup and dispatch misses probe the cache before compiling,
         and fresh compiles are serialized back.  ``directory`` defaults
-        to ``aot_cache/`` next to this runner's snapshot.  False (and
-        inert) when this jax build cannot serialize executables —
-        serving falls back to compile-every-boot, nothing breaks."""
+        to ``aot_cache/`` next to this runner's snapshot."""
         from znicz_tpu.serving import aot_cache
 
-        if not aot_cache.available():
-            return False
         if not directory:
             if not self.snapshot_path:
                 raise ValueError(
@@ -643,6 +639,7 @@ class ModelRunner:
 
     def stats(self) -> Dict:
         return {"compiles": self.compiles,
+                "donate": self.donate,
                 "aot_enabled": self.aot_enabled,
                 "aot_loaded": len(self._aot),
                 "warm_source": self.warm_source,
