@@ -96,11 +96,19 @@ def configure_compile_cache() -> str:
     ``<cache_dir>/jax``: a fixed path (it is part of the cache key, so a
     directory that moves never hits).  Every executable is kept,
     however quick its compile, so a second run of the same program adds
-    no entry.  Call before the first compile."""
+    no entry; the key includes the operations' metadata, so an entry is
+    only ever fetched by the source that wrote it.  Call before the
+    first compile."""
     import jax
 
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # the names the program gives its operations (``jax.named_scope`` in
+    # the fused step) are metadata, which the cache's key leaves out by
+    # default: a tree without them, or with other unit names, would hand
+    # this one executables whose trace names nothing — silently, wherever
+    # two checkouts share one directory (the chip tool's machines do)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed

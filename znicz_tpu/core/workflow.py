@@ -175,6 +175,13 @@ class Workflow(Unit):
                 line += (f"; warm (excl. compiles): "
                          f"{fused['warm_img_per_sec']} img/s over "
                          f"{fused['warm_steps']} steps")
+            if fused.get("dispatches"):
+                # what the device waits for: the host blocked on its
+                # values, in the Decision, in the epoch-end hook
+                line += (f"; {fused['dispatches']} dispatches, host "
+                         f"{fused['sync_wait_s']:.3f}s in sync, "
+                         f"{fused['decide_s']:.3f}s in decision, "
+                         f"{fused['epoch_hook_s']:.3f}s in epoch hooks")
             lines.append(line)
         table = "\n".join(lines)
         self.info("unit timing:\n%s", table)

@@ -72,12 +72,12 @@ class Launcher:
                                  "run into this directory")
         parser.add_argument("--profile-dir", default="", metavar="DIR",
                             help="programmatic jax profiler capture "
-                                 "(start_trace/stop_trace) into DIR, with "
-                                 "every fused train step wrapped in a "
-                                 "jax.profiler.StepTraceAnnotation so the "
-                                 "timeline shows named steps (telemetry, "
-                                 "ISSUE 5; supersedes --profile when both "
-                                 "are given)")
+                                 "(start_trace/stop_trace) into DIR; the "
+                                 "program's spans are in it as znicz:* "
+                                 "annotations, every fused dispatch a "
+                                 "jax.profiler.StepTraceAnnotation "
+                                 "(supersedes --profile when both are "
+                                 "given)")
         parser.add_argument("--fused", action="store_true",
                             help="train with the fused SPMD fast path "
                                  "(one jitted scan step) instead of the "
@@ -382,14 +382,11 @@ class Launcher:
             kwargs["snapshot"] = args.snapshot
         if args.profile_dir:
             # programmatic capture (TPU hand-off protocol, BASELINE.md):
-            # unlike the --profile context manager this pairs with the
-            # telemetry step annotations, so the profiler timeline shows
-            # one named StepTraceAnnotation block per fused train step
+            # the trace holds the program's own spans as ``znicz:*``
+            # annotations (telemetry/trace.py) — one StepTraceAnnotation
+            # per fused dispatch — with nothing to arm here
             import jax
 
-            from znicz_tpu import telemetry
-
-            telemetry.set_profile_steps(True)
             jax.profiler.start_trace(args.profile_dir)
             try:
                 wf = mod.run(**kwargs)

@@ -40,7 +40,9 @@ for this.
 
 from __future__ import annotations
 
+import contextlib
 import sys as _sys
+import time
 from typing import Dict
 
 import numpy as np
@@ -164,15 +166,20 @@ class FusedTrainer:
                       "wall_s": 0.0, "steps_per_sec": 0.0,
                       "img_per_sec": 0.0, "last_step_ms": 0.0,
                       "warm_steps": 0, "warm_images": 0, "warm_wall_s": 0.0,
-                      "warm_img_per_sec": 0.0}
+                      "warm_img_per_sec": 0.0,
+                      # programs launched (train/eval steps, scans, whole
+                      # epochs), and the host seconds spent blocked on the
+                      # device's values, in the Decision and in the
+                      # epoch-end hook: what the device waits for
+                      "dispatches": 0, "warm_dispatches": 0,
+                      "sync_wait_s": 0.0, "decide_s": 0.0,
+                      "epoch_hook_s": 0.0}
         workflow.fused_stats = self.stats
-        # telemetry (ISSUE 5): hot-loop metrics + spans.  The registry
-        # counters/histogram observe only while telemetry is enabled —
-        # bench.py --telemetry gates the whole layer's cost (<2%) by
-        # interleaving enabled/disabled windows of this very loop.
+        # telemetry (ISSUE 5): hot-loop metrics + spans.  The histogram
+        # observes and the spans record only while telemetry is enabled
+        # (what the layer costs on the chip: PERF.md section 6).
         from znicz_tpu import telemetry
 
-        self._telemetry = telemetry
         self._tracer = telemetry.tracer()
         _sc = telemetry.scope("trainer")
         self._m_train_steps = _sc.counter("train_steps",
@@ -469,71 +476,79 @@ class FusedTrainer:
         i = 0
         while i < len(self.forwards):
             f = self.forwards[i]
-            if cast is not None:
-                h = cast(h)
-            p = params.get(f.name, {})
-            blk = plan.get(i)
-            if blk is not None:
-                h = f.apply_linear(p, h)
-                h = fused_block(h, p["bias"], blk.n, blk.alpha, blk.beta,
-                                blk.k, blk.pool)
-                # dropout/stochpool never sit inside a fused block, so
-                # later units keep their own fold_in(key, i) indices
-                i += blk.span
-                continue
-            tl = tail_plan.get(i)
-            if tl is not None:
-                if tl.kind == "conv_bias_relu":
+            span = 1
+            # the device trace speaks the model's names: one scope per
+            # forward unit (a fused block or tail span takes its first
+            # unit's); jax names the backward ``transpose(jvp(<unit>))``
+            with jax.named_scope(f.name):
+                if cast is not None:
+                    h = cast(h)
+                p = params.get(f.name, {})
+                blk = plan.get(i)
+                tl = tail_plan.get(i) if blk is None else None
+                if blk is not None:
                     h = f.apply_linear(p, h)
-                    h = fused_bias_relu(h, p["bias"])
-                elif tl.kind == "seq_epilogue":
-                    # position-wise FFN (ISSUE 15): the raw per-token
-                    # matmul plus the SAME fused bias+ReLU custom-vjp
-                    # epilogue fc6/fc7 ride (no dropout absorbed; the
-                    # backward recomputes the gate from (y, bias))
+                    h = fused_block(h, p["bias"], blk.n, blk.alpha,
+                                    blk.beta, blk.k, blk.pool)
+                    # dropout/stochpool never sit inside a fused block,
+                    # so later units keep their own fold_in(key, i)
+                    # indices
+                    span = blk.span
+                elif tl is not None:
+                    if tl.kind == "conv_bias_relu":
+                        h = f.apply_linear(p, h)
+                        h = fused_bias_relu(h, p["bias"])
+                    elif tl.kind == "seq_epilogue":
+                        # position-wise FFN (ISSUE 15): the raw per-token
+                        # matmul plus the SAME fused bias+ReLU custom-vjp
+                        # epilogue fc6/fc7 ride (no dropout absorbed; the
+                        # backward recomputes the gate from (y, bias))
+                        from znicz_tpu.ops.linear import seq_linear
+
+                        y = seq_linear(
+                            h, p["weights"],
+                            weights_transposed=f.weights_transposed)
+                        h = fused_fc_epilogue(y, p["bias"], None, 0.0,
+                                              False)
+                    else:                           # fc_epilogue
+                        y = linear(h, p["weights"],
+                                   weights_transposed=f.weights_transposed)
+                        masked = train and tl.dropout_index >= 0
+                        k = (jax.random.fold_in(key, tl.dropout_index)
+                             if masked else None)
+                        y = fused_fc_epilogue(y, p["bias"], k, tl.ratio,
+                                              masked)
+                        h = y.reshape((x.shape[0],)
+                                      + f.output_sample_shape)
+                    span = tl.span
+                elif isinstance(f, self._dropout_cls):
+                    if train:
+                        k = jax.random.fold_in(key, i)
+                        m = f.make_mask(k, h.shape, f.dropout_ratio)
+                        h = h * m
+                    # eval: identity
+                elif isinstance(f, self._stochpool_cls):
+                    win = f.windows(h)
+                    if train:
+                        k = jax.random.fold_in(key, i)
+                        h, _ = f._select_stochastic(win, k)
+                    else:
+                        h, _ = f._select_expected(win)
+                elif f is last and isinstance(f, self._softmax_cls):
+                    h = linear(h, p["weights"], p.get("bias"),
+                               weights_transposed=f.weights_transposed)
+                    h = h.reshape((x.shape[0],) + f.output_sample_shape)
+                elif f is last and isinstance(f, self._seq_softmax_cls):
+                    # per-position logits (ISSUE 15): the softmax is
+                    # folded into the loss head exactly like the
+                    # All2AllSoftmax path
                     from znicz_tpu.ops.linear import seq_linear
 
-                    y = seq_linear(h, p["weights"],
+                    h = seq_linear(h, p["weights"], p.get("bias"),
                                    weights_transposed=f.weights_transposed)
-                    h = fused_fc_epilogue(y, p["bias"], None, 0.0, False)
-                else:                           # fc_epilogue
-                    y = linear(h, p["weights"],
-                               weights_transposed=f.weights_transposed)
-                    masked = train and tl.dropout_index >= 0
-                    k = (jax.random.fold_in(key, tl.dropout_index)
-                         if masked else None)
-                    y = fused_fc_epilogue(y, p["bias"], k, tl.ratio,
-                                          masked)
-                    h = y.reshape((x.shape[0],) + f.output_sample_shape)
-                i += tl.span
-                continue
-            if isinstance(f, self._dropout_cls):
-                if train:
-                    k = jax.random.fold_in(key, i)
-                    m = f.make_mask(k, h.shape, f.dropout_ratio)
-                    h = h * m
-                # eval: identity
-            elif isinstance(f, self._stochpool_cls):
-                win = f.windows(h)
-                if train:
-                    k = jax.random.fold_in(key, i)
-                    h, _ = f._select_stochastic(win, k)
                 else:
-                    h, _ = f._select_expected(win)
-            elif f is last and isinstance(f, self._softmax_cls):
-                h = linear(h, p["weights"], p.get("bias"),
-                           weights_transposed=f.weights_transposed)
-                h = h.reshape((x.shape[0],) + f.output_sample_shape)
-            elif f is last and isinstance(f, self._seq_softmax_cls):
-                # per-position logits (ISSUE 15): the softmax is folded
-                # into the loss head exactly like the All2AllSoftmax path
-                from znicz_tpu.ops.linear import seq_linear
-
-                h = seq_linear(h, p["weights"], p.get("bias"),
-                               weights_transposed=f.weights_transposed)
-            else:
-                h = f.apply(p, h)
-            i += 1
+                    h = f.apply(p, h)
+            i += span
         return h
 
     def loss_and_metrics(self, params, data, target, batch_size, key,
@@ -550,9 +565,26 @@ class FusedTrainer:
             def cast(t):
                 return t.astype("bfloat16") if t.dtype == jnp.float32 else t
 
-            cparams = jax.tree_util.tree_map(cast, params)
-            out = self.forward_pass(cparams, cast(data), key, train,
-                                    cast=cast)
+            # each layer's weights drop to the compute dtype under that
+            # layer's name, the minibatch under ``input`` (sorted like
+            # tree_map flattens a dict: the traced program is the same)
+            cparams = {}
+            for name in sorted(params):
+                with jax.named_scope(name):
+                    cparams[name] = jax.tree_util.tree_map(cast,
+                                                           params[name])
+            with jax.named_scope("input"):
+                data = cast(data)
+            out = self.forward_pass(cparams, data, key, train, cast=cast)
+        with jax.named_scope("loss"):
+            return self._loss_head(out, target, batch_size)
+
+    def _loss_head(self, out, target, batch_size):
+        """The evaluator's math on the last unit's output (traced under
+        the ``loss`` scope): ``(loss, (loss, n_err, confusion))``."""
+        import jax
+        import jax.numpy as jnp
+
         out = out.astype("float32")
         n = out.shape[0]
         valid = (jnp.arange(n) < batch_size)
@@ -676,17 +708,30 @@ class FusedTrainer:
         ``u8*scale + shift``, fused by XLA into whatever produced it, so
         HBM/link traffic stays 1 byte/value and the f32 tensor only ever
         exists inside the step."""
+        import jax
         import jax.numpy as jnp
 
         if data.dtype == jnp.uint8:
             scale, shift = self._decode_params
-            data = data.astype(jnp.float32) * scale + shift
+            with jax.named_scope("input"):
+                data = data.astype(jnp.float32) * scale + shift
         return data
 
-    def _gather_decode(self, dataset, idx):
+    def _gather(self, dataset, targets, idx):
+        """Rows ``idx`` of the resident set and of its targets, in their
+        storage dtype.  Everything between the resident set and the
+        first unit's operand — this gather, ``_decode``, the cast to the
+        compute dtype — is traced under the scope ``input``."""
+        import jax
         import jax.numpy as jnp
 
-        return self._decode(jnp.take(dataset, idx, axis=0))
+        with jax.named_scope("input"):
+            return (jnp.take(dataset, idx, axis=0),
+                    jnp.take(targets, idx, axis=0))
+
+    def _gather_decode(self, dataset, targets, idx):
+        data, tgt = self._gather(dataset, targets, idx)
+        return self._decode(data), tgt
 
     def _step_core(self, params, velocities, hypers, dataset, targets, idx,
                    batch_size, key):
@@ -694,11 +739,8 @@ class FusedTrainer:
         sgd update.  Shared by the single-step jit and the scan chunk.
         The gather hands RAW storage-dtype rows to ``_update_core``, which
         owns the decode (single decode point on the update path)."""
-        import jax.numpy as jnp
-
         return self._update_core(params, velocities, hypers,
-                                 jnp.take(dataset, idx, axis=0),
-                                 jnp.take(targets, idx, axis=0),
+                                 *self._gather(dataset, targets, idx),
                                  batch_size, key)
 
     def _update_core(self, params, velocities, hypers, data, tgt,
@@ -717,8 +759,9 @@ class FusedTrainer:
             from znicz_tpu.parallel.mesh import data_sharding
 
             shard = data_sharding(self.mesh)
-            data = jax.lax.with_sharding_constraint(data, shard)
-            tgt = jax.lax.with_sharding_constraint(tgt, shard)
+            with jax.named_scope("input"):
+                data = jax.lax.with_sharding_constraint(data, shard)
+                tgt = jax.lax.with_sharding_constraint(tgt, shard)
 
         def lf(p):
             return self.loss_and_metrics(p, data, tgt, batch_size, key,
@@ -735,21 +778,22 @@ class FusedTrainer:
             lr, lrb, wd, wdb, l1l2, mom, momb, clip = hypers[name]
             new_p[name], new_v[name] = {}, {}
             for k, w in layer_p.items():
-                g = grads[name][k].astype("float32")
-                is_bias = (k == "bias")
-                # bf16-master: storage bf16, update arithmetic f32 (the
-                # cast pair fuses into the update; traffic is what the
-                # storage dtype says)
-                w_in = (w if self._master_dtype is None
-                        else w.astype("float32"))
-                p_new, v_new = sgd_update(
-                    w_in, g, velocities[name][k],
-                    lr=(lrb if is_bias else lr),
-                    weights_decay=(wdb if is_bias else wd),
-                    l1_vs_l2=l1l2,
-                    momentum=(momb if is_bias else mom), clip=clip)
-                if self._master_dtype is not None:
-                    p_new = p_new.astype(self._master_dtype)
+                with jax.named_scope(f"update/{name}"):
+                    g = grads[name][k].astype("float32")
+                    is_bias = (k == "bias")
+                    # bf16-master: storage bf16, update arithmetic f32
+                    # (the cast pair fuses into the update; traffic is
+                    # what the storage dtype says)
+                    w_in = (w if self._master_dtype is None
+                            else w.astype("float32"))
+                    p_new, v_new = sgd_update(
+                        w_in, g, velocities[name][k],
+                        lr=(lrb if is_bias else lr),
+                        weights_decay=(wdb if is_bias else wd),
+                        l1_vs_l2=l1l2,
+                        momentum=(momb if is_bias else mom), clip=clip)
+                    if self._master_dtype is not None:
+                        p_new = p_new.astype(self._master_dtype)
                 new_p[name][k], new_v[name][k] = p_new, v_new
         return new_p, new_v, metrics
 
@@ -828,12 +872,9 @@ class FusedTrainer:
         """Gather variant of ``_train_body``: xs = (idx, batch_size,
         step_number, hypers row), rows gathered from the resident
         dataset (used by the segmented chunks and the deep epoch fn)."""
-        import jax.numpy as jnp
-
         def unpack(xs):
             idx, bs, step, hypers = xs
-            return (jnp.take(dataset, idx, axis=0),
-                    jnp.take(targets, idx, axis=0), bs, step, hypers)
+            return (*self._gather(dataset, targets, idx), bs, step, hypers)
 
         return self._train_body(base_key, unpack)
 
@@ -852,12 +893,9 @@ class FusedTrainer:
 
     def _eval_scan_body(self, params, dataset, targets):
         """Gather variant of ``_eval_body``: xs = (idx, batch_size)."""
-        import jax.numpy as jnp
-
         def unpack(xs):
             idx, bs = xs
-            return (self._gather_decode(dataset, idx),
-                    jnp.take(targets, idx, axis=0), bs)
+            return (*self._gather_decode(dataset, targets, idx), bs)
 
         return self._eval_body(params, unpack)
 
@@ -937,8 +975,7 @@ class FusedTrainer:
 
         def step(params, dataset, targets, idx, batch_size, key, train):
             compiles.inc()
-            data = self._gather_decode(dataset, idx)
-            tgt = jax.numpy.take(targets, idx, axis=0)
+            data, tgt = self._gather_decode(dataset, targets, idx)
             _, metrics = self.loss_and_metrics(
                 params, data, tgt, batch_size, key, train=train)
             return metrics
@@ -975,7 +1012,27 @@ class FusedTrainer:
     #: state (``root.common.engine.pipeline_depth``).
     pipeline_depth = 1
 
+    @contextlib.contextmanager
+    def _timed(self, stat, name, **args):
+        """A ``train`` span whose seconds are also summed into
+        ``stats[stat]`` (the counters run with telemetry off too)."""
+        t0 = time.perf_counter()
+        try:
+            with self._tracer.span("train", name, **args):
+                yield
+        finally:
+            self.stats[stat] += time.perf_counter() - t0
+
+    def _sync(self, *values):
+        """The blocking pull: device values as host arrays, under the
+        ``sync`` span — where the host waits for the device."""
+        with self._timed("sync_wait_s", "sync"):
+            return tuple(np.asarray(v) for v in values)
+
     def _feed_decision(self, mb, metrics):
+        """One minibatch's HOST-side metrics (``_sync`` pulled them; the
+        confusion matrix stays a device array) into the Decision.
+        Callers wrap their group of feeds in the ``decide`` span."""
         loss, n_err, conf = metrics
         decision = self.decision
         decision.minibatch_class = mb["class"]
@@ -999,16 +1056,14 @@ class FusedTrainer:
         self._acct_last_end = None
 
     def _account(self, n_steps, n_images, t0, is_train, kind="train",
-                 n_eval=0):
+                 n_eval=0, n_dispatch=1):
         # charge [max(t0, last interval end), now]: with the pipeline,
         # segment N's flush happens during iteration N+1, whose own
         # t0 predates the flush — naive (now - t0) intervals overlap
         # and double-count wall time.  ``n_eval`` books the eval share of
         # a mixed (whole-epoch) interval under eval_steps.
-        import time as _time
-
         stats = self.stats
-        now = _time.perf_counter()
+        now = time.perf_counter()
         start = t0 if self._acct_last_end is None \
             else max(t0, self._acct_last_end)
         dt = max(now - start, 1e-9)
@@ -1033,7 +1088,9 @@ class FusedTrainer:
         stats["steps_per_sec"] = round(total / stats["wall_s"], 2)
         stats["img_per_sec"] = round(
             stats["images"] / stats["wall_s"], 2)
+        stats["dispatches"] += n_dispatch
         if kind in self._acct_seen:     # first call of a kind pays compile
+            stats["warm_dispatches"] += n_dispatch
             stats["warm_steps"] += n_steps + n_eval
             stats["warm_images"] += n_images
             stats["warm_wall_s"] += dt
@@ -1221,9 +1278,10 @@ class FusedTrainer:
         def step(params, velocities, hypers, data_seg, tgt_seg,
                  batch_size, key):
             compiles.inc()
-            return self._update_core(params, velocities, hypers,
-                                     data_seg[0], tgt_seg[0], batch_size,
-                                     key)
+            with jax.named_scope("input"):
+                data, tgt = data_seg[0], tgt_seg[0]
+            return self._update_core(params, velocities, hypers, data, tgt,
+                                     batch_size, key)
 
         return jax.jit(step, donate_argnums=(0, 1), **kw)
 
@@ -1238,9 +1296,11 @@ class FusedTrainer:
 
         def step(params, data_seg, tgt_seg, batch_size, key, train):
             compiles.inc()
+            with jax.named_scope("input"):
+                data, tgt = data_seg[0], tgt_seg[0]
             _, metrics = self.loss_and_metrics(
-                params, self._decode(data_seg[0]), tgt_seg[0], batch_size,
-                key, train=train)
+                params, self._decode(data), tgt, batch_size, key,
+                train=train)
             return metrics
 
         return jax.jit(step, static_argnums=(5,), **kw)
@@ -1355,9 +1415,11 @@ class FusedTrainer:
                                          decision.improved)
                     if tags:
                         copy = jax.tree_util.tree_map
-                        snap.save_async(self.snapshot_from_trees(
-                            copy(jnp.copy, params),
-                            copy(jnp.copy, velocities)), tags)
+                        with span("train", "snapshot_copy"):
+                            trees = (copy(jnp.copy, params),
+                                     copy(jnp.copy, velocities))
+                        snap.save_async(self.snapshot_from_trees(*trees),
+                                        tags)
                 elif snap_due:
                     snap.run()
             # wired plotters count as consumers, so whenever they run the
@@ -1370,9 +1432,9 @@ class FusedTrainer:
             for plotter in plotters:
                 plotter.run()
 
-        import time as _time
         from collections import deque
 
+        span = self._tracer.span
         was_indices_only = loader.indices_only
         loader.indices_only = True
         fifo = deque()                  # advanced-but-unprocessed mbs
@@ -1425,9 +1487,10 @@ class FusedTrainer:
             stager when armed (a predicted group is a cache pop; the
             fallback assembles inline and counts a miss)."""
             rows = [s["idx"] for s in seg]
-            if stager is not None:
-                return stager.take(rows)
-            return self._stage_direct(rows, put)
+            with span("train", "stage", steps=len(seg)):
+                if stager is not None:
+                    return stager.take(rows)
+                return self._stage_direct(rows, put)
 
         def upcoming_segments():
             """The dispatch groups the loop WILL form from the fifo — the
@@ -1533,36 +1596,36 @@ class FusedTrainer:
             nonlocal inflight, epoch_conf
             if inflight is None:
                 return
-            seg, kind, res, t0 = inflight
+            seg, kind, res, t0, step0 = inflight
             inflight = None
-            t_flush = _time.perf_counter()
-            if kind == "single":
-                loss, n_err, conf = res
-                epoch_conf = conf if epoch_conf is None \
-                    else epoch_conf + conf
-                stacked = [(loss, n_err, None)]
-            else:
-                ms, conf_sum = res
-                epoch_conf = conf_sum if epoch_conf is None \
-                    else epoch_conf + conf_sum
-                losses, n_errs = (np.asarray(m) for m in ms)
-                stacked = [(losses[i], n_errs[i], None)
-                           for i in range(len(seg))]
-            if self._tracer.enabled:
-                # the host-sync span: waiting out the previous dispatch's
-                # device work + pulling its metrics
-                self._tracer.add("train", "flush", t_flush,
-                                 _time.perf_counter() - t_flush,
-                                 {"steps": len(seg), "kind": kind})
-            for s, m in zip(seg, stacked):
-                feed_decision(s, m)
+            # the host-sync span: waiting out the previous dispatch's
+            # device work + pulling its metrics, then the Decision
+            with span("train", "flush", steps=len(seg), kind=kind,
+                      step0=step0):
+                if kind == "single":
+                    loss, n_err, conf = res
+                    epoch_conf = conf if epoch_conf is None \
+                        else epoch_conf + conf
+                    losses, n_errs = self._sync(loss, n_err)
+                    stacked = [(losses, n_errs, None)]
+                else:
+                    ms, conf_sum = res
+                    epoch_conf = conf_sum if epoch_conf is None \
+                        else epoch_conf + conf_sum
+                    losses, n_errs = self._sync(*ms)
+                    stacked = [(losses[i], n_errs[i], None)
+                               for i in range(len(seg))]
+                with self._timed("decide_s", "decide"):
+                    for s, m in zip(seg, stacked):
+                        feed_decision(s, m)
             account(len(seg), sum(s["size"] for s in seg), t0, True,
                     kind=f"train_{kind}_{len(seg)}")
 
         try:
             while not bool(decision.complete):
-                t_iter = _time.perf_counter()
-                mb = take_mb()
+                t_iter = time.perf_counter()
+                with span("train", "advance"):
+                    mb = take_mb()
                 is_train = (mb["class"] == TRAIN)
                 if is_train and not mb["last_minibatch"]:
                     # collect the segment of consecutive non-tail TRAIN
@@ -1570,15 +1633,18 @@ class FusedTrainer:
                     # as one scan dispatch
                     seg = [mb]
                     max_seg = self.scan_chunk if self._train_scan else 1
-                    while len(seg) < max_seg:
-                        nxt = take_mb()
-                        if nxt["class"] == TRAIN and \
-                                not nxt["last_minibatch"]:
-                            seg.append(nxt)
-                        else:
-                            fifo.appendleft(nxt)
-                            break
-                    extend_lookahead()  # future segments' decode starts
+                    with span("train", "advance", steps=max_seg):
+                        while len(seg) < max_seg:
+                            nxt = take_mb()
+                            if nxt["class"] == TRAIN and \
+                                    not nxt["last_minibatch"]:
+                                seg.append(nxt)
+                            else:
+                                fifo.appendleft(nxt)
+                                break
+                        extend_lookahead()  # future segments' decode starts
+                        if stager is not None:
+                            submit_upcoming()
                     if stager is not None:
                         # ping-pong ordering (ISSUE 7): upcoming groups'
                         # assemblies are already in flight — sync the
@@ -1586,7 +1652,6 @@ class FusedTrainer:
                         # overlaps them, then take this segment's staged
                         # buffers (ready by then; the wait histogram is
                         # the proof the --ingest gate checks)
-                        submit_upcoming()
                         flush()
                     gen = prng.get("fused_trainer")
 
@@ -1597,12 +1662,14 @@ class FusedTrainer:
                                               self.steps_done + len(seg),
                                               dtype=np.int32)))
 
-                    # ISSUE 5: named profiler step (--profile-dir) +
-                    # a dispatch span; t_disp measures HOST dispatch time
-                    # (the device work lands in flush()'s sync span)
-                    t_disp = _time.perf_counter()
+                    # the dispatch span measures HOST dispatch time (the
+                    # device work lands in flush()'s sync span); in a
+                    # profiler session it is a named step
                     step0 = self.steps_done
-                    with self._telemetry.step_annotation(step0):
+                    kind = ("single" if len(seg) == 1 and not staging
+                            else "scan")
+                    with span("train", f"dispatch:{kind}", step=step0,
+                              steps=len(seg)):
                         if staging:
                             # staged-direct: minibatches ride in the scan xs
                             # (even a lone step goes through the K=1 scan);
@@ -1616,15 +1683,14 @@ class FusedTrainer:
                                     params, velocities,
                                     put(hypers_rows(len(seg))), dseg, tseg,
                                     bs_vec, put(gen.jax_base_key()), steps)
-                            result = ("scan", (ms, conf_sum))
+                            result = (ms, conf_sum)
                         elif len(seg) == 1:
                             key = gen.jax_key(self.steps_done)
-                            params, velocities, metrics = self._train_step(
+                            params, velocities, result = self._train_step(
                                 params, velocities, self.hypers(), dataset,
                                 targets, put(seg[0]["idx"]),
                                 np.int32(seg[0]["size"]), key)
                             advance_lr()
-                            result = ("single", metrics)
                         else:
                             idx_op = put(np.stack([s["idx"] for s in seg]))
                             bs_vec, steps = seg_ops()
@@ -1634,12 +1700,7 @@ class FusedTrainer:
                                     put(hypers_rows(len(seg))), dataset,
                                     targets, idx_op, bs_vec,
                                     put(gen.jax_base_key()), steps)
-                            result = ("scan", (ms, conf_sum))
-                    if self._tracer.enabled:
-                        self._tracer.add(
-                            "train", f"dispatch:{result[0]}", t_disp,
-                            _time.perf_counter() - t_disp,
-                            {"steps": len(seg), "step0": step0})
+                            result = (ms, conf_sum)
                     self.steps_done += len(seg)
                     # start staging the NEXT groups before anything
                     # blocks: their host assembly + H2D overlap this
@@ -1647,46 +1708,56 @@ class FusedTrainer:
                     submit_upcoming()
                     if stager is None:
                         flush()         # previous segment, AFTER dispatch
-                    inflight = (seg, result[0], result[1], t_iter)
+                    inflight = (seg, kind, result, t_iter, step0)
                 elif is_train:
                     flush()
                     # epoch tail: metrics first, Decision rules, and the
                     # update applies only if gd_skip stayed open
                     # (unit-path parity).  The epoch's device-side
                     # confusion sum rides along in this one transfer.
-                    bs = np.int32(mb["size"])
-                    key = prng.get("fused_trainer").jax_key(self.steps_done)
-                    if staging:
-                        dseg, tseg = stage_segment([mb])
-                        loss, n_err, conf = self._eval_step(
-                            params, dseg, tseg, bs, key, True)
-                    else:
-                        idx = put(mb["idx"])
-                        loss, n_err, conf = self._eval_step(
-                            params, dataset, targets, idx, bs, key, True)
-                    if epoch_conf is not None:
-                        conf = epoch_conf + conf
-                        epoch_conf = None
-                    feed_decision(mb, (loss, n_err, conf))
-                    if not bool(decision.gd_skip):
-                        with self._telemetry.step_annotation(
-                                self.steps_done):
+                    # The leaves say which of these the device waits for.
+                    with span("train", "tail",
+                              epoch=int(mb["epoch_number"])):
+                        with span("train", "tail_eval"):
+                            bs = np.int32(mb["size"])
+                            # the step's key is a small device program
+                            key = prng.get("fused_trainer").jax_key(
+                                self.steps_done)
                             if staging:
-                                params, velocities, _ = self._train_step(
-                                    params, velocities, self.hypers(),
-                                    dseg, tseg, bs, key)
+                                dseg, tseg = stage_segment([mb])
+                                loss, n_err, conf = self._eval_step(
+                                    params, dseg, tseg, bs, key, True)
                             else:
-                                params, velocities, _ = self._train_step(
-                                    params, velocities, self.hypers(),
-                                    dataset, targets, idx, bs, key)
-                        advance_lr()    # adj is gated like the gds
-                    self.steps_done += 1
-                    if self._tracer.enabled:
-                        self._tracer.add(
-                            "train", "tail", t_iter,
-                            _time.perf_counter() - t_iter,
-                            {"epoch": int(mb["epoch_number"])})
-                    account(1, mb["size"], t_iter, True, kind="tail")
+                                idx = put(mb["idx"])
+                                loss, n_err, conf = self._eval_step(
+                                    params, dataset, targets, idx, bs, key,
+                                    True)
+                            if epoch_conf is not None:
+                                conf = epoch_conf + conf
+                                epoch_conf = None
+                        loss, n_err = self._sync(loss, n_err)
+                        with self._timed("decide_s", "decide"):
+                            feed_decision(mb, (loss, n_err, conf))
+                        applied = not bool(decision.gd_skip)
+                        if applied:
+                            with span("train", "tail_update",
+                                      step=self.steps_done):
+                                if staging:
+                                    params, velocities, _ = \
+                                        self._train_step(
+                                            params, velocities,
+                                            self.hypers(), dseg, tseg, bs,
+                                            key)
+                                else:
+                                    params, velocities, _ = \
+                                        self._train_step(
+                                            params, velocities,
+                                            self.hypers(), dataset,
+                                            targets, idx, bs, key)
+                                advance_lr()    # adj is gated like the gds
+                        self.steps_done += 1
+                    account(1, mb["size"], t_iter, True, kind="tail",
+                            n_dispatch=1 + applied)
                 else:
                     flush()
                     # TEST/VALID: params are frozen, so consecutive eval
@@ -1696,68 +1767,66 @@ class FusedTrainer:
                     # to the first minibatch's class)
                     seg = [mb]
                     max_seg = self.scan_chunk if self._eval_scan else 1
-                    while len(seg) < max_seg:
-                        nxt = take_mb()
-                        if nxt["class"] == mb["class"]:
-                            seg.append(nxt)
-                        else:
-                            fifo.appendleft(nxt)
-                            break
-                    extend_lookahead()
-                    # the upcoming groups stage while this eval segment
-                    # computes (the eval/train boundary is where each
-                    # epoch's first train segment would otherwise pay
-                    # the full assembly inline)
-                    submit_upcoming()
-                    if staging:
-                        dseg, tseg = stage_segment(seg)
-                        bs_vec = put(np.array([s["size"] for s in seg],
-                                              np.int32))
-                        ms, conf_sum = self._eval_scan(
-                            params, dseg, tseg, bs_vec)
-                        losses, n_errs = (np.asarray(m) for m in ms)
-                        stacked = [(losses[i], n_errs[i],
-                                    conf_sum if i == 0 else None)
-                                   for i in range(len(seg))]
-                    elif len(seg) == 1:
-                        stacked = [self._eval_step(
-                            params, dataset, targets, put(mb["idx"]),
-                            np.int32(mb["size"]), self._key0, False)]
-                    else:
-                        idx_op = put(np.stack([s["idx"] for s in seg]))
-                        bs_vec = put(np.array([s["size"] for s in seg],
-                                              np.int32))
-                        ms, conf_sum = self._eval_scan(
-                            params, dataset, targets, idx_op, bs_vec)
-                        losses, n_errs = (np.asarray(m) for m in ms)
+                    with span("train", "eval", klass=int(mb["class"])):
+                        with span("train", "advance", steps=max_seg):
+                            while len(seg) < max_seg:
+                                nxt = take_mb()
+                                if nxt["class"] == mb["class"]:
+                                    seg.append(nxt)
+                                else:
+                                    fifo.appendleft(nxt)
+                                    break
+                            extend_lookahead()
+                            # the upcoming groups stage while this eval
+                            # segment computes (the eval/train boundary is
+                            # where each epoch's first train segment would
+                            # otherwise pay the full assembly inline)
+                            submit_upcoming()
                         # segment confusion fed once, with the first step
-                        stacked = [(losses[i], n_errs[i],
-                                    conf_sum if i == 0 else None)
-                                   for i in range(len(seg))]
-                    for s, m in zip(seg, stacked):
-                        feed_decision(s, m)
-                    if self._tracer.enabled:
-                        self._tracer.add("train", "eval", t_iter,
-                                         _time.perf_counter() - t_iter,
-                                         {"steps": len(seg),
-                                          "class": int(mb["class"])})
+                        if staging:
+                            dseg, tseg = stage_segment(seg)
+                            bs_vec = put(np.array([s["size"] for s in seg],
+                                                  np.int32))
+                            ms, conf = self._eval_scan(
+                                params, dseg, tseg, bs_vec)
+                        elif len(seg) == 1:
+                            loss, n_err, conf = self._eval_step(
+                                params, dataset, targets, put(mb["idx"]),
+                                np.int32(mb["size"]), self._key0, False)
+                            ms = (loss, n_err)
+                        else:
+                            idx_op = put(np.stack([s["idx"] for s in seg]))
+                            bs_vec = put(np.array([s["size"] for s in seg],
+                                                  np.int32))
+                            ms, conf = self._eval_scan(
+                                params, dataset, targets, idx_op, bs_vec)
+                        # a lone step's scalars feed like a scan's stack
+                        losses, n_errs = (np.atleast_1d(v)
+                                          for v in self._sync(*ms))
+                        with self._timed("decide_s", "decide"):
+                            for i, s in enumerate(seg):
+                                feed_decision(s, (losses[i], n_errs[i],
+                                                  conf if i == 0 else None))
                     account(len(seg), 0, t_iter, False,
                             kind=f"eval_{len(seg)}")
                 if bool(decision.epoch_ended):
-                    epoch_end_hook()
+                    with self._timed("epoch_hook_s", "epoch_hook",
+                                     epoch=int(decision.epoch_number)):
+                        epoch_end_hook()
                     # consume the flag: with the pipeline, the next loop
                     # iteration may not feed the decision before this
                     # check runs again, and a stale True would re-save
                     # the 'best' snapshot with weights already advanced
                     # past the epoch boundary
                     decision.epoch_ended.set(False)
-                if not bool(decision.complete):
+                if look_mbs and not bool(decision.complete):
                     # refill the lookahead AFTER the epoch hook: a
                     # boundary snapshot must record the tail state, not a
                     # loader already advanced (and reshuffled) into the
                     # next epoch — resume parity depends on this ordering
-                    extend_lookahead()
-                    submit_upcoming()
+                    with span("train", "advance"):
+                        extend_lookahead()
+                        submit_upcoming()
             flush()
             self.writeback(params, velocities)
         finally:
@@ -1911,9 +1980,9 @@ class FusedTrainer:
         speculated epochs are discarded, including the host-side
         LR-schedule/prng/loader bookkeeping."""
         import copy
-        import time as _time
         from collections import deque
 
+        span = self._tracer.span
         decision, loader = self.decision, self.loader
         self._reset_accounting()
         params, velocities, dataset, targets, put = self._device_state()
@@ -1944,7 +2013,7 @@ class FusedTrainer:
                 concat_jit[n] = jax.jit(
                     lambda *xs: jnp.concatenate(xs))
             recs = [inflight[i] for i in range(n)]
-            vals = np.asarray(
+            vals, = self._sync(
                 concat_jit[n](*[r["scalars"] for r in recs]))
             size = vals.shape[0] // n
             for i in range(n):
@@ -1956,27 +2025,29 @@ class FusedTrainer:
             nonlocal params, velocities
             rec = inflight.popleft()
             if vals is None:
-                vals = np.asarray(rec["scalars"])   # one transfer/epoch
+                vals, = self._sync(rec["scalars"])  # one transfer/epoch
             confs = rec["confs"]
             off, ci = 0, 0
-            for _klass, mbs in rec["evals"]:
-                n = len(mbs)
-                losses = vals[off:off + n]
-                nerrs = vals[off + n:off + 2 * n]
-                off += 2 * n
-                for i, mb in enumerate(mbs):
-                    self._feed_decision(
-                        mb, (losses[i], nerrs[i],
-                             confs[ci] if i == 0 else None))
-                ci += 1
             k = len(rec["train"]) - 1
-            losses = vals[off:off + k]
-            nerrs = vals[off + k:off + 2 * k]
-            off += 2 * k
-            for i, mb in enumerate(rec["train"][:k]):
-                self._feed_decision(mb, (losses[i], nerrs[i], None))
-            self._feed_decision(rec["train"][k],
-                                (vals[off], vals[off + 1], confs[ci]))
+            with self._timed("decide_s", "decide",
+                             epoch=rec["epoch_number"]):
+                for _klass, mbs in rec["evals"]:
+                    n = len(mbs)
+                    losses = vals[off:off + n]
+                    nerrs = vals[off + n:off + 2 * n]
+                    off += 2 * n
+                    for i, mb in enumerate(mbs):
+                        self._feed_decision(
+                            mb, (losses[i], nerrs[i],
+                                 confs[ci] if i == 0 else None))
+                    ci += 1
+                losses = vals[off:off + k]
+                nerrs = vals[off + k:off + 2 * k]
+                off += 2 * k
+                for i, mb in enumerate(rec["train"][:k]):
+                    self._feed_decision(mb, (losses[i], nerrs[i], None))
+                self._feed_decision(rec["train"][k],
+                                    (vals[off], vals[off + 1], confs[ci]))
             # snapshot gating must be read NOW: an epoch-wired gate
             # (~decision.epoch_ended) is only open while the tail feed's
             # epoch_ended=True is live
@@ -1997,11 +2068,15 @@ class FusedTrainer:
                 # are no-ops (the tail was already dispatched un-adopted
                 # and nothing was speculated past it).
                 if rec["applied_tail"] or inflight:
-                    params, velocities, _, _ = epoch_fn(
-                        rec["params_in"], rec["vels_in"], rec["hypers"],
-                        dataset, targets, rec["train_idx"],
-                        rec["train_bs"], rec["eval_idx"], rec["eval_bs"],
-                        rec["base_key"], rec["step_nums"], False)
+                    with span("train", "dispatch:rollback",
+                              step=int(rec["step_nums"][0])):
+                        params, velocities, _, _ = epoch_fn(
+                            rec["params_in"], rec["vels_in"],
+                            rec["hypers"], dataset, targets,
+                            rec["train_idx"], rec["train_bs"],
+                            rec["eval_idx"], rec["eval_bs"],
+                            rec["base_key"], rec["step_nums"], False)
+                    self.stats["dispatches"] += 1
                     inflight.clear()
                 self.steps_done = rec["steps_end"]
                 if self._lr_adjust is not None:
@@ -2014,7 +2089,9 @@ class FusedTrainer:
             if snap_open:
                 snap.epoch_number = decision.epoch_number
                 snap.improved = decision.improved
-                if snap_due:
+            if snap_due:
+                with self._timed("epoch_hook_s", "epoch_hook",
+                                 epoch=int(decision.epoch_number)):
                     # the flushed epoch's POST-epoch params: the next
                     # in-flight epoch's inputs, or the live trees (which
                     # for a just-rolled-back stop ARE the recomputed
@@ -2044,7 +2121,7 @@ class FusedTrainer:
                     assert inflight, "decision never completed"
                     flush_one()
                     continue
-                t0 = _time.perf_counter()
+                t0 = time.perf_counter()
                 lr_iter_start = (self._lr_adjust.iteration
                                  if self._lr_adjust is not None else 0)
                 rec = self._collect_epoch()
@@ -2088,11 +2165,13 @@ class FusedTrainer:
                     step_nums=np.arange(self.steps_done,
                                         self.steps_done + k + 1,
                                         dtype=np.int32))
-                params, velocities, scal, confs = epoch_fn(
-                    params, velocities, rec["hypers"], dataset, targets,
-                    rec["train_idx"], rec["train_bs"], rec["eval_idx"],
-                    rec["eval_bs"], rec["base_key"], rec["step_nums"],
-                    apply_tail)
+                with span("train", "dispatch:epoch", step=self.steps_done,
+                          steps=k + 1):
+                    params, velocities, scal, confs = epoch_fn(
+                        params, velocities, rec["hypers"], dataset,
+                        targets, rec["train_idx"], rec["train_bs"],
+                        rec["eval_idx"], rec["eval_bs"], rec["base_key"],
+                        rec["step_nums"], apply_tail)
                 self.steps_done += k + 1
                 rec.update(scalars=scal, confs=confs,
                            steps_end=self.steps_done,

@@ -10,10 +10,15 @@ Surfaces:
   - ``/trace.json`` on web_status: the span ring as Chrome trace-event
     JSON, loadable in Perfetto (trace.py);
   - ``--profile-dir`` on the launcher: programmatic
-    ``jax.profiler.start_trace``/``stop_trace`` capture with
-    ``StepTraceAnnotation`` wrapped around each fused train step
-    (:func:`step_annotation`);
-  - ``bench.py --telemetry``: the <2% hot-loop overhead gate.
+    ``jax.profiler.start_trace``/``stop_trace`` capture and nothing
+    else — every span recorded while the ring is enabled is also a
+    ``znicz:<cat>:<name>`` annotation in the profiler's trace (trace.py),
+    a ``StepTraceAnnotation`` at the fused trainer's dispatches, so the
+    capture holds the program's spans on its own clock with nothing armed;
+  - the benchmark's cells, run with ``root.common.telemetry.enabled`` on
+    and off, measure what the layer costs on the chip (PERF.md section
+    6); ``bench.py --telemetry`` is the older CPU-relative gate, which
+    nothing runs.
 
 ``set_enabled(False)`` turns the OPTIONAL layer off: spans stop
 recording and the trainer's step histogram stops observing.  Service
@@ -45,7 +50,6 @@ from .trace import NULL_SPAN, TraceRing  # noqa: F401
 TELEMETRY_DEFAULTS = {
     "enabled": True,            # optional layer (spans + hot histograms)
     "trace_capacity": 16384,    # process span-ring size (events)
-    "profile_steps": False,     # jax StepTraceAnnotation on train steps
     # -- fleet observability plane (ISSUE 20) ------------------------------
     "events_capacity": 512,     # process event-journal ring (events)
     "span_export_capacity": 1024,   # exporter buffer (spans, drops-oldest)
@@ -58,7 +62,6 @@ TELEMETRY_DEFAULTS = {
 _REGISTRY = MetricsRegistry()
 _TRACER = None
 _TRACER_LOCK = threading.Lock()
-_PROFILE_STEPS = False
 _IDENTITY = None
 _JOURNAL = None
 _EXPORTER = None
@@ -280,27 +283,3 @@ def slo_snapshot() -> dict:
                else "warn" if "warn" in states
                else "ok" if states else "idle")
     return {"state": overall, "planes": planes}
-
-
-def set_profile_steps(on: bool) -> None:
-    """Arm :func:`step_annotation` (the launcher's ``--profile-dir``
-    does this so fused train steps land as named steps in the jax
-    profiler timeline)."""
-    global _PROFILE_STEPS
-    _PROFILE_STEPS = bool(on)
-
-
-def profile_steps() -> bool:
-    return _PROFILE_STEPS or bool(
-        root.common.telemetry.get("profile_steps", False))
-
-
-def step_annotation(step: int, name: str = "train_step"):
-    """``jax.profiler.StepTraceAnnotation`` around one train step when
-    step-profiling is armed; a shared no-op context otherwise (jax is
-    not even imported on the cold path)."""
-    if not profile_steps():
-        return NULL_SPAN
-    import jax
-
-    return jax.profiler.StepTraceAnnotation(name, step_num=int(step))
