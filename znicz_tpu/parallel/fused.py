@@ -41,6 +41,7 @@ for this.
 from __future__ import annotations
 
 import contextlib
+import functools
 import sys as _sys
 import time
 from typing import Dict
@@ -65,6 +66,87 @@ class FusedStagingUnsupportedError(FusedUnsupportedError):
     the engine's slave fallback catches exactly the two known refusals —
     this and the base FusedUnsupportedError — instead of a blanket
     ``ValueError`` that would also swallow real config errors."""
+
+
+def _default_order(sharding, shape, dtype):
+    """Axis order, major to minor, of the layout the devices of
+    ``sharding`` give an array of this shape and dtype by default."""
+    from jax.experimental.layout import Layout
+
+    dev = min(sharding.device_set, key=lambda d: d.id)
+    return tuple(Layout.from_pjrt_layout(dev.client.get_default_layout(
+        np.dtype(dtype), sharding.shard_shape(tuple(shape)),
+        dev)).major_to_minor)
+
+
+class _Rows:
+    """The resident twin inside a traced program (``FusedTrainer.
+    _resident``): ``take(idx)`` gives the rows as the loader's own array
+    would give them — the pad cut off, the axes back in the loader's
+    order.  Both are free where the rows' consumer wants the twin's
+    physical order, which is how that order was chosen."""
+
+    def __init__(self, twin, order, sample_shape):
+        self.twin, self.order, self.sample_shape = twin, order, sample_shape
+
+    def take(self, idx):
+        import jax.numpy as jnp
+
+        rows = jnp.take(self.twin, idx, axis=0)
+        kept = tuple(self.sample_shape[a - 1] for a in self.order[1:])
+        if rows.shape[1:] != kept:
+            rows = rows[(slice(None),) + tuple(slice(n) for n in kept)]
+        return jnp.transpose(rows, np.argsort(self.order))
+
+
+class _ResidentJit:
+    """A jitted step/scan/epoch that gathers from the resident set.
+
+    Callers hand it the loader's own device array (or a
+    ``jax.ShapeDtypeStruct`` of it, to ``lower``); the program takes that
+    array's prepared twin (``FusedTrainer._resident``) and reads its rows
+    through ``_Rows``.  One ``jax.jit`` per way the rows are laid out —
+    one, for a trainer that keeps its set."""
+
+    def __init__(self, trainer, fn, argnum, in_specs, out_specs, **jit_kw):
+        self._trainer, self._fn, self._argnum = trainer, fn, argnum
+        self._jit_kw = dict(jit_kw, **trainer._jit_shardings(in_specs,
+                                                             out_specs))
+        self._jitted = {}               # twin's (order, sample shape) -> jit
+
+    def _resolve(self, args):
+        import jax
+
+        fn, n = self._fn, self._argnum
+        twin, plan = self._trainer._resident(args[n])
+        jitted = self._jitted.get(plan)
+        if jitted is None:
+            @functools.wraps(fn)        # the program keeps its name
+            def on_twin(*args):
+                return fn(*args[:n], _Rows(args[n], *plan), *args[n + 1:])
+
+            jitted = self._jitted[plan] = jax.jit(
+                fn if plan is None else on_twin, **self._jit_kw)
+        return jitted, args[:n] + (twin,) + args[n + 1:]
+
+    def __call__(self, *args):
+        jitted, on_twin = self._resolve(args)
+        out = jitted(*on_twin)
+        n = self._argnum
+        if on_twin[n] is not args[n] and not self._trainer._twins:
+            # a twin made for this call (no run holds one): wait, so that
+            # its HBM is free again when the caller goes on
+            import jax
+
+            jax.block_until_ready(out)
+        return out
+
+    def lower(self, *args):
+        jitted, args = self._resolve(args)
+        return jitted.lower(*args)
+
+    def _cache_size(self) -> int:
+        return sum(j._cache_size() for j in self._jitted.values())
 
 
 class FusedTrainer:
@@ -150,6 +232,9 @@ class FusedTrainer:
         self._train_scan = None
         self._eval_step = None
         self._eval_scan = None
+        #: (loader's array, its prepared twin, how ``_Rows`` reads it) of
+        #: the run in progress — see ``_resident``
+        self._twins = []
         #: the live DeviceStager while a staged run is inside
         #: _run_segmented with async staging on (tests/bench observe it)
         self._stager = None
@@ -173,7 +258,10 @@ class FusedTrainer:
                       # epoch-end hook: what the device waits for
                       "dispatches": 0, "warm_dispatches": 0,
                       "sync_wait_s": 0.0, "decide_s": 0.0,
-                      "epoch_hook_s": 0.0}
+                      "epoch_hook_s": 0.0,
+                      # twins made of a resident set (``_resident``): one
+                      # a run where the set needs one, none in steady state
+                      "resident_prepares": 0}
         workflow.fused_stats = self.stats
         # telemetry (ISSUE 5): hot-loop metrics + spans.  The histogram
         # observes and the spans record only while telemetry is enabled
@@ -185,6 +273,9 @@ class FusedTrainer:
         self._m_train_steps = _sc.counter("train_steps",
                                           "fused train steps dispatched")
         self._m_images = _sc.counter("images", "training images consumed")
+        self._m_resident_prepares = _sc.counter(
+            "resident_prepares", "resident sets laid out for their gather "
+            "(a whole-set pass each: once per set, never per dispatch)")
         self._m_step_seconds = _sc.histogram(
             "step_seconds", "per-step wall time (pipelined intervals)",
             size=4096)
@@ -673,13 +764,16 @@ class FusedTrainer:
 
     def _state_shardings(self):
         """(params tree shardings, velocities tree shardings, replicated)
-        for the live mesh — the explicit ``in_shardings``/``out_shardings``
-        every mesh-jitted step/scan declares.  Params replicate or
+        for the live mesh (three ``None``: nothing declared, off a mesh) —
+        the explicit ``in_shardings``/``out_shardings`` every mesh-jitted
+        step/scan declares.  Params replicate or
         column-shard per ``param_sharding``; with the batch split over
         ``data``, jax.grad's gradients demand replication, so GSPMD
         inserts the ``lax.psum`` over the ``data`` axis INSIDE the
         executable — the intra-slice (ICI) tier of the two-tier
         reduction.  The host-side wire-v3 delta tier never sees it."""
+        if self.mesh is None:
+            return None, None, None
         from znicz_tpu.parallel.mesh import replicated, tree_shardings
 
         psh = tree_shardings(
@@ -702,6 +796,123 @@ class FusedTrainer:
             return {}
         return {"in_shardings": in_specs, "out_shardings": out_specs}
 
+    def _resident(self, raw, place=None, keep=False):
+        """``(twin, plan)`` for the loader's resident set ``raw``: the
+        array every program gathers from, and how ``_Rows`` reads it
+        (None: like ``raw``).
+
+        The device's default layout for a set of images puts the SAMPLE
+        axis minor-most (v5e: ``f32[N,227,227,3]{0,2,3,1}`` — N fills the
+        lanes), which no gather of rows can read in place, so XLA rewrote
+        the whole set sample-major in front of every program's gather and
+        fused the cast to the compute dtype into that pass: 7.8 GB moved
+        per dispatch for 40 MB of use a step (PERF.md, PR 26).  The twin
+        is that pass's result, made ONCE per array: sample axis major-
+        most, the other axes in the order the compiler chooses for the
+        gather (``_gather_layout``), already in the dtype
+        ``loss_and_metrics`` casts to (f32 -> bf16 under bf16 compute; u8
+        stays u8 and decodes in-graph) — a cast commutes with a gather
+        bit for bit.
+
+        The order is carried by the twin's SHAPE, not by a declared
+        ``jax.experimental.layout.Format``: its axes are transposed into
+        that order and the minor ones padded to whole tiles, which makes
+        the device's default layout for the new shape the wanted one
+        (checked; a device that answers otherwise gets no twin).  A
+        program with a declared entry layout ran, but the chip's runtime
+        refused its executable once it came back from jax's persistent
+        compile cache (it expected the default layout's size; PERF.md,
+        PR 26).  A set that is sample-major in its final dtype already
+        (every set on the CPU in f32 compute) is its own twin: no copy,
+        no second buffer.
+
+        A run makes its set's twin in set-up (``_device_state``,
+        ``keep``), finds it again by the IDENTITY of ``raw`` or of the
+        twin itself (jax arrays are immutable), and lets it go when it
+        ends: a trainer that is not running pins no second copy of the
+        set (the benchmark's float32 reference needs those gigabytes
+        after the run).  ``raw`` itself is left alone (the unit engine
+        reads it).  A call between runs (a caller of ``make_train_step``
+        with the loader's array) gets a twin for that call.  ``place``
+        puts ``raw`` where the programs expect it (``global_put`` on a
+        mesh; the twin is then written by each chip for itself).  A
+        ``jax.ShapeDtypeStruct`` (``lower`` for a described chip) maps to
+        the twin's shape; a host array goes as it is."""
+        import jax
+        import jax.numpy as jnp
+
+        for known, twin, plan in self._twins:
+            if raw is known or raw is twin:
+                return twin, plan
+        placed = raw if place is None else place(raw)
+        is_spec = isinstance(placed, jax.ShapeDtypeStruct)
+        if not is_spec and not isinstance(placed, jax.Array):
+            return placed, None
+        shape, sharding = tuple(placed.shape), placed.sharding
+        dtype = placed.dtype
+        if self.compute_dtype != np.dtype("float32") \
+                and dtype == np.float32:
+            dtype = jnp.bfloat16            # as loss_and_metrics casts
+        rows_first = tuple(range(len(shape)))
+        twin, plan = placed, None
+        # the order the device holds ``raw`` in: an array's own layout, a
+        # described device's default
+        held = None if is_spec else placed.format.layout
+        if held is not None:
+            held = tuple(held.major_to_minor)
+        elif sharding is not None:
+            held = _default_order(sharding, shape, placed.dtype)
+        if dtype != placed.dtype or (held and held[0] != 0):
+            layout = self._gather_layout(shape, dtype, sharding)
+            order = (0,) + tuple(a for a in layout.major_to_minor if a)
+            # whole tiles on the minor axes (the outer level: T(8,128))
+            outer = tuple((layout.tiling or ((),))[0])
+            tile = (1,) * (len(shape) - len(outer)) + outer
+            padded = tuple(
+                shape[a] if a == 0 else -(-shape[a] // t) * t
+                for a, t in zip(order, tile))
+            if sharding is None or _default_order(
+                    sharding, padded, dtype) == rows_first:
+                if order != rows_first or padded != shape:
+                    plan = (order, shape[1:])
+                if is_spec:
+                    twin = jax.ShapeDtypeStruct(padded, dtype,
+                                                sharding=sharding)
+                else:
+                    pads = [(0, p - shape[a]) for a, p in zip(order, padded)]
+                    with self._tracer.span("train", "resident_prepare",
+                                           bytes=int(placed.nbytes)):
+                        twin = jax.block_until_ready(jax.jit(
+                            lambda a: jnp.pad(jnp.transpose(
+                                a.astype(dtype), order), pads),
+                            out_shardings=sharding)(placed))
+                    self.stats["resident_prepares"] += 1
+                    self._m_resident_prepares.inc()
+        if keep:
+            self._twins.append(
+                (raw if isinstance(raw, jax.Array) else placed, twin, plan))
+        return twin, plan
+
+    def _gather_layout(self, shape, dtype, sharding):
+        """The layout the compiler itself chooses (``Layout.AUTO``) for
+        the operand of the programs' gather of one minibatch — a program
+        of one instruction, compiled for the devices of ``sharding`` and
+        never run.  (For AlexNet's set the whole train scan, compiled
+        with ``AUTO`` on that operand, chooses the same ``major_to_minor``
+        (0, 3, 1, 2): the rows' only consumers are the gather and the
+        first unit.)"""
+        import jax
+        from jax.experimental.layout import Format, Layout
+
+        rows = jax.ShapeDtypeStruct(
+            (int(self.loader.max_minibatch_size),), np.int32,
+            sharding=sharding)
+        return jax.jit(
+            lambda dataset, idx: jax.numpy.take(dataset, idx, axis=0),
+            in_shardings=(Format(Layout.AUTO, sharding), sharding)).lower(
+                jax.ShapeDtypeStruct(shape, dtype, sharding=sharding),
+                rows).compile().input_formats[0][0].layout
+
     def _decode(self, data):
         """Storage decode IN-GRAPH: u8 data (HBM u8-residency or a
         host-staged u8 segment — loader/streaming.py) decodes
@@ -718,15 +929,17 @@ class FusedTrainer:
         return data
 
     def _gather(self, dataset, targets, idx):
-        """Rows ``idx`` of the resident set and of its targets, in their
-        storage dtype.  Everything between the resident set and the
+        """Rows ``idx`` of the resident set (its prepared twin:
+        ``_resident``) and of its targets, in the twin's dtype and the
+        targets' storage dtype.  Everything between the resident set and the
         first unit's operand — this gather, ``_decode``, the cast to the
         compute dtype — is traced under the scope ``input``."""
         import jax
         import jax.numpy as jnp
 
         with jax.named_scope("input"):
-            return (jnp.take(dataset, idx, axis=0),
+            return (dataset.take(idx) if isinstance(dataset, _Rows)
+                    else jnp.take(dataset, idx, axis=0),
                     jnp.take(targets, idx, axis=0))
 
     def _gather_decode(self, dataset, targets, idx):
@@ -802,16 +1015,11 @@ class FusedTrainer:
         adjustment (LearningRateAdjust) never recompiles.  On a mesh the
         jit declares explicit shardings (``_state_shardings``): params
         pinned to their placements, batch operands replicated (the
-        in-step gather + constraint shard the minibatch over ``data``)."""
-        import jax
-
+        in-step gather + constraint shard the minibatch over ``data``).
+        ``dataset`` is the loader's resident array or its twin: the
+        program gathers from the twin (``_ResidentJit``)."""
         compiles = self._m_compiles
-        kw = {}
-        if self.mesh is not None:
-            psh, vsh, repl = self._state_shardings()
-            kw = self._jit_shardings(
-                (psh, vsh, repl, repl, repl, repl, repl, repl),
-                (psh, vsh, repl))
+        psh, vsh, repl = self._state_shardings()
 
         def step(params, velocities, hypers, dataset, targets, idx,
                  batch_size, key):
@@ -819,7 +1027,9 @@ class FusedTrainer:
             return self._step_core(params, velocities, hypers, dataset,
                                    targets, idx, batch_size, key)
 
-        return jax.jit(step, donate_argnums=(0, 1), **kw)
+        return _ResidentJit(
+            self, step, 3, (psh, vsh, repl, repl, repl, repl, repl, repl),
+            (psh, vsh, repl), donate_argnums=(0, 1))
 
     def jit_cache_sizes(self) -> Dict[str, int]:
         """jax's own executable-cache entry counts for the live jitted
@@ -913,12 +1123,7 @@ class FusedTrainer:
 
         nc = self._n_confusion()
         compiles = self._m_compiles
-        kw = {}
-        if self.mesh is not None:
-            psh, vsh, repl = self._state_shardings()
-            kw = self._jit_shardings(
-                (psh, vsh, repl, repl, repl, repl, repl, repl, repl),
-                (psh, vsh, repl, repl))
+        psh, vsh, repl = self._state_shardings()
 
         def chunk(params, velocities, hypers_mat, dataset, targets,
                   idx_mat, bs_vec, base_key, step_nums):
@@ -929,7 +1134,10 @@ class FusedTrainer:
                 (idx_mat, bs_vec, step_nums, hypers_mat))
             return p, v, ms, conf_sum
 
-        return jax.jit(chunk, donate_argnums=(0, 1), **kw)
+        return _ResidentJit(
+            self, chunk, 3,
+            (psh, vsh, repl, repl, repl, repl, repl, repl, repl),
+            (psh, vsh, repl, repl), donate_argnums=(0, 1))
 
     def make_eval_scan(self):
         """Metrics for K eval minibatches (TEST/VALID) in one dispatch —
@@ -941,11 +1149,7 @@ class FusedTrainer:
 
         nc = self._n_confusion()
         compiles = self._m_compiles
-        kw = {}
-        if self.mesh is not None:
-            psh, _, repl = self._state_shardings()
-            kw = self._jit_shardings((psh, repl, repl, repl, repl),
-                                     (repl, repl))
+        psh, _, repl = self._state_shardings()
 
         def chunk(params, dataset, targets, idx_mat, bs_vec):
             compiles.inc()
@@ -954,7 +1158,8 @@ class FusedTrainer:
                 jnp.zeros((nc, nc), jnp.int32), (idx_mat, bs_vec))
             return ms, conf_sum
 
-        return jax.jit(chunk, **kw)
+        return _ResidentJit(self, chunk, 1,
+                            (psh, repl, repl, repl, repl), (repl, repl))
 
     def make_eval_step(self):
         """Metrics-only step.  ``train`` is static: True replays the exact
@@ -962,16 +1167,8 @@ class FusedTrainer:
         used at epoch tails to let the Decision rule on this minibatch's
         metrics BEFORE the update is adopted, matching the unit path where
         gd_skip gates the final update off once ``complete`` flips."""
-        import jax
-
         compiles = self._m_compiles
-        kw = {}
-        if self.mesh is not None:
-            # in_shardings entries cover the DYNAMIC args only (the
-            # static ``train`` flag is excluded)
-            psh, _, repl = self._state_shardings()
-            kw = self._jit_shardings((psh, repl, repl, repl, repl, repl),
-                                     repl)
+        psh, _, repl = self._state_shardings()
 
         def step(params, dataset, targets, idx, batch_size, key, train):
             compiles.inc()
@@ -980,7 +1177,11 @@ class FusedTrainer:
                 params, data, tgt, batch_size, key, train=train)
             return metrics
 
-        return jax.jit(step, static_argnums=(6,), **kw)
+        # in_shardings entries cover the DYNAMIC args only (the static
+        # ``train`` flag is excluded)
+        return _ResidentJit(self, step, 1,
+                            (psh, repl, repl, repl, repl, repl), repl,
+                            static_argnums=(6,))
 
     # -- the epoch driver ------------------------------------------------------
 
@@ -1101,7 +1302,8 @@ class FusedTrainer:
 
     def _device_state(self):
         """Params/velocities/dataset/targets as device values (mesh
-        placement applied) plus ``put`` for per-dispatch host operands.
+        placement applied; the dataset as its prepared twin —
+        ``_resident``) plus ``put`` for per-dispatch host operands.
         In staging mode dataset/targets are None — every dispatch ships
         its own staged segment instead."""
         loader = self.loader
@@ -1123,14 +1325,17 @@ class FusedTrainer:
                 import jax
 
                 return params, velocities, None, None, jax.device_put
-            return params, velocities, dataset, targets, lambda x: x
+            return (params, velocities,
+                    self._resident(dataset, keep=True)[0], targets,
+                    lambda x: x)
         from znicz_tpu.parallel.mesh import global_put, replicated
 
         repl = replicated(self.mesh)
         params = self.place_state(params)
         velocities = self.place_state(velocities)
         if dataset is not None:
-            dataset = global_put(dataset, repl)
+            dataset = self._resident(
+                dataset, lambda a: global_put(a, repl), keep=True)[0]
             targets = global_put(targets, repl)
         return (params, velocities, dataset, targets,
                 lambda x: global_put(x, repl))
@@ -1351,10 +1556,14 @@ class FusedTrainer:
                 "loss needs regression targets — build the StreamingLoader "
                 "source with targets= (ADVICE r4: this used to surface as "
                 "an opaque error deep inside the staging/operand path)")
-        if self.pipeline_depth > 1 and self._deep_eligible():
-            self._run_deep()
-        else:
-            self._run_segmented()
+        try:
+            if self.pipeline_depth > 1 and self._deep_eligible():
+                self._run_deep()
+            else:
+                self._run_segmented()
+        finally:
+            # the twin is the run's: its HBM goes back with the run
+            self._twins.clear()
         # the zero-recompile proof, where print_stats / status.json /
         # chip_smoke.py can read it without a handle on the trainer
         self.stats["compiles"] = int(self._m_compiles.value)
@@ -1960,7 +2169,7 @@ class FusedTrainer:
             confs.append(conf_tr + tconf)
             return p, v, jnp.concatenate(scalars), jnp.stack(confs)
 
-        return jax.jit(epoch)
+        return _ResidentJit(self, epoch, 3, (None,) * 12, None)
 
     def _run_deep(self) -> None:
         """Whole-epoch dispatches with metric pulls deferred by up to
