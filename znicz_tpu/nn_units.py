@@ -181,6 +181,25 @@ def sgd_update(w, g, v, *, lr, weights_decay, l1_vs_l2, momentum, clip):
     return w + v_new, v_new.astype(v.dtype)
 
 
+def adamw_update(w, g, m, v, *, lr, beta1, beta2, eps, weight_decay, step):
+    """AdamW (Loshchilov & Hutter, 2019, algorithm 2) beside ``sgd_update``:
+    ``m <- b1 m + (1 - b1) g``, ``v <- b2 v + (1 - b2) g^2``, bias
+    correction by ``1 - b^step`` (``step`` counts from 1), and
+    ``w <- w - lr (m^ / (sqrt(v^) + eps) + weight_decay w)`` — the decay
+    is decoupled from the gradient.  Arithmetic in the weights' dtype
+    (float32); the moments are stored back in their own dtype (see
+    ``_state_dtype``).  Returns ``(w_new, m_new, v_new)``."""
+    import jax.numpy as jnp
+
+    g = g.astype(w.dtype)
+    m_new = beta1 * m.astype(w.dtype) + (1.0 - beta1) * g
+    v_new = beta2 * v.astype(w.dtype) + (1.0 - beta2) * jnp.square(g)
+    m_hat = m_new / (1.0 - beta1 ** step)
+    v_hat = v_new / (1.0 - beta2 ** step)
+    w_new = w - lr * (m_hat / (jnp.sqrt(v_hat) + eps) + weight_decay * w)
+    return w_new, m_new.astype(m.dtype), v_new.astype(v.dtype)
+
+
 class GradientDescentBase(Unit, Distributable):
     """Backward twin of a ``ForwardBase``: consumes ``err_output``, produces
     ``err_input`` and updates the forward's params in place (on device).
@@ -264,11 +283,15 @@ class GradientDescentBase(Unit, Distributable):
         assert self.forward is not None, f"{self.name}: no forward twin"
         if self.initial_hypers is None:
             self.initial_hypers = tuple(float(v) for v in self._hypers())
+        self._make_state(device)
+        self.err_input.initialize(device)
+
+    def _make_state(self, device) -> None:
+        """The optimizer's accumulators, one velocity a tensor."""
         for k, arr in self.forward.params().items():
             vel = Array(np.zeros(arr.shape, _state_dtype()))
             vel.initialize(device)
             self._velocities[k] = vel
-        self.err_input.initialize(device)
 
     # -- Distributable: a GD unit's serializable state is its optimizer
     # -- accumulators (the forward owns the weights) --------------------------
@@ -309,3 +332,80 @@ class GradientDescentBase(Unit, Distributable):
                 arr.devmem = new_params[k]
             for k, arr in self._velocities.items():
                 arr.devmem = new_vels[k]
+
+
+class GradientDescentAdamW(GradientDescentBase):
+    """Backward twin whose update rule is AdamW (``adamw_update``).
+
+    Hyperparameters: ``learning_rate``, ``weights_decay`` (decoupled; the
+    forward's ``decay_exempt`` tensors take none), ``beta1`` (0.9),
+    ``beta2`` (0.95), ``epsilon`` (1e-8).  State, under the names the
+    snapshotter and the fused trainer's ``velocities`` tree carry: the two
+    moments of every tensor (``m_<tensor>``, ``v_<tensor>``, stored in
+    ``root.common.engine.state_dtype``, float32 by default) and the
+    ``step`` count.  The state is made on the device: two moments of a
+    large model never cross the host link.
+
+    The fused trainer does not ask what kind of GD unit this is: it calls
+    ``apply_update`` where a unit has one and ``sgd_update`` where not."""
+
+    def __init__(self, workflow=None, name=None, forward=None, **kwargs):
+        super().__init__(workflow=workflow, name=name, forward=forward,
+                         **kwargs)
+        self.beta1 = kwargs.get("beta1", 0.9)
+        self.beta2 = kwargs.get("beta2", 0.95)
+        self.epsilon = kwargs.get("epsilon", 1e-8)
+
+    def _hypers(self):
+        return tuple(np.float32(v) for v in (
+            self.learning_rate, self.weights_decay, self.beta1, self.beta2,
+            self.epsilon))
+
+    def _make_state(self, device) -> None:
+        import jax
+        import jax.numpy as jnp
+
+        dtype = _state_dtype()
+        shapes = {f"{moment}_{k}": (tuple(arr.shape), dtype)
+                  for k, arr in self.forward.params().items()
+                  for moment in ("m", "v")}
+        shapes["step"] = ((), jnp.int32)
+        # one program for the whole state, not one a tensor
+        zeros = jax.jit(lambda: {key: jnp.zeros(shape, kind) for key,
+                                 (shape, kind) in shapes.items()})()
+        for key, value in zeros.items():
+            arr = Array()
+            arr.devmem = value
+            arr.initialize(device)
+            self._velocities[key] = arr
+
+    def backward_apply(self, params, x):
+        fn = getattr(self.forward, "apply_logits", self.forward.apply)
+        return fn(params, x)
+
+    def apply_update(self, params, grads, state, hypers):
+        """Pure: ``(new_params, new_state)`` of one step."""
+        lr, decay, beta1, beta2, eps = hypers
+        exempt = getattr(self.forward, "decay_exempt", ())
+        step = state["step"] + 1
+        new_p, new_s = {}, dict(state, step=step)
+        for k, w in params.items():
+            w32 = w.astype("float32")       # bf16 masters update in f32
+            w_new, new_s[f"m_{k}"], new_s[f"v_{k}"] = adamw_update(
+                w32, grads[k], state[f"m_{k}"], state[f"v_{k}"], lr=lr,
+                beta1=beta1, beta2=beta2, eps=eps,
+                weight_decay=(0.0 if k in exempt else decay),
+                step=step.astype("float32"))
+            new_p[k] = w_new.astype(w.dtype)
+        return new_p, new_s
+
+    def _step(self, params, state, x, err_output, hypers):
+        import jax
+
+        if self.need_err_input:
+            _, vjp = jax.vjp(self.backward_apply, params, x)
+            grads, err_input = vjp(err_output)
+        else:                               # integer ids take no cotangent
+            _, vjp = jax.vjp(lambda p: self.backward_apply(p, x), params)
+            (grads,), err_input = vjp(err_output), None
+        return (err_input,) + self.apply_update(params, grads, state, hypers)

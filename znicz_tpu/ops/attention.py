@@ -210,3 +210,233 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False):
     m, l, acc, _, _ = lax.fori_loop(0, n, step, (m0, l0, acc0, k, v))
     out = acc / jnp.maximum(l, 1e-30)[..., None]       # (b, h, q, d)
     return out.transpose(0, 2, 1, 3)                   # (b, q, h, d)
+
+
+# -- the decoder's attention core -------------------------------------------------
+
+
+def rope_tables(length: int, rotary_dim: int, theta: float, yarn=None):
+    """``(cos, sin)``, each ``(length, rotary_dim)`` float32, of rotary
+    positions ``0 .. length - 1`` in the rotate-half pairing (dimension
+    ``i`` pairs with ``i + rotary_dim / 2``; both take frequency ``i``).
+
+    ``yarn`` (``factor``, ``original_max_position_embeddings``,
+    ``beta_fast``, ``beta_slow``, ``attention_factor``) blends each
+    frequency between the trained one (dimensions that turn more than
+    ``beta_fast`` times over the original length) and the same divided by
+    ``factor`` (fewer than ``beta_slow`` turns), linearly in between, and
+    multiplies cos and sin by ``attention_factor`` (Peng et al., YaRN,
+    2023, section 3.2-3.4).  Host numpy: the tables are constants of the
+    traced program."""
+    import numpy as np
+
+    half = rotary_dim // 2
+    inv = 1.0 / theta ** (np.arange(half, dtype=np.float64) * 2 / rotary_dim)
+    scale = 1.0
+    if yarn:
+        span = float(yarn["original_max_position_embeddings"])
+
+        def turns_dim(turns):       # the dimension that makes ``turns``
+            return (rotary_dim * math.log(span / (turns * 2 * math.pi))
+                    / (2 * math.log(theta)))
+
+        low = max(math.floor(turns_dim(yarn["beta_fast"])), 0)
+        high = min(math.ceil(turns_dim(yarn["beta_slow"])), rotary_dim - 1)
+        ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0, 1)
+        inv = inv / yarn["factor"] * ramp + inv * (1 - ramp)
+        scale = float(yarn["attention_factor"])
+    angle = np.arange(length, dtype=np.float64)[:, None] * inv[None, :]
+    angle = np.concatenate([angle, angle], axis=-1)
+    return ((np.cos(angle) * scale).astype(np.float32),
+            (np.sin(angle) * scale).astype(np.float32))
+
+
+def apply_rope(x, cos, sin):
+    """Rotate the first ``cos.shape[-1]`` dimensions of ``x`` ``(batch,
+    seq, heads, dim)``; the rest pass through.  Arithmetic in float32, the
+    result in ``x``'s dtype."""
+    import jax.numpy as jnp
+
+    r = cos.shape[-1]
+    rot = x[..., :r].astype(jnp.float32)
+    a, b = rot[..., :r // 2], rot[..., r // 2:]
+    turned = jnp.concatenate([-b, a], axis=-1)
+    out = rot * cos[None, :, None, :] + turned * sin[None, :, None, :]
+    out = out.astype(x.dtype)
+    return out if r == x.shape[-1] else jnp.concatenate(
+        [out, x[..., r:]], axis=-1)
+
+
+def _key_blocks(i, block: int, window):
+    """Key blocks ``[lo, hi)`` that hold a key query block ``i`` admits."""
+    import jax.numpy as jnp
+
+    if window is None:
+        return 0, i + 1
+    return jnp.maximum((i * block - (window - 1)) // block, 0), i + 1
+
+
+def _dead(i, j, block: int, window):
+    """``(query, key)`` pairs of blocks ``(i, j)`` the mask excludes."""
+    import jax.numpy as jnp
+
+    qpos = i * block + jnp.arange(block)[:, None]
+    kpos = j * block + jnp.arange(block)[None, :]
+    dead = kpos > qpos
+    if window is not None:
+        dead |= qpos - kpos >= window
+    return dead
+
+
+def _blocks(x, block: int):
+    """``(batch, seq, kv, ..., dim)`` -> ``(seq blocks, batch, kv, ...,
+    block, dim)``."""
+    b, t = x.shape[:2]
+    x = x.reshape((b, t // block, block) + x.shape[2:])
+    nd = x.ndim
+    return x.transpose((1, 0) + tuple(range(3, nd - 1)) + (2, nd - 1))
+
+
+def _unblocks(x):
+    """Inverse of ``_blocks``."""
+    nd = x.ndim
+    x = x.transpose((1, 0, nd - 2) + tuple(range(2, nd - 2)) + (nd - 1,))
+    return x.reshape((x.shape[0], x.shape[1] * x.shape[2]) + x.shape[3:])
+
+
+def _add_at(acc, j, part):
+    """``acc[j] += part`` for a traced ``j``, as a slice update."""
+    from jax import lax
+
+    return lax.dynamic_update_index_in_dim(
+        acc, lax.dynamic_index_in_dim(acc, j, 0, keepdims=False) + part,
+        j, 0)
+
+
+def _blocked_forward(q, k, v, window, block):
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    b, t, heads, d = q.shape
+    kv = k.shape[2]
+    qb = _blocks(q.reshape(b, t, kv, heads // kv, d), block)
+    kb, vb = _blocks(k, block), _blocks(v, block)
+    scale = 1.0 / math.sqrt(d)
+    neg = jnp.finfo(jnp.float32).min
+
+    def query_block(args):
+        i, qi = args                            # qi: (b, kv, g, block, d)
+
+        def key_block(j, carry):
+            m, l, acc = carry
+            kj = lax.dynamic_index_in_dim(kb, j, 0, keepdims=False)
+            vj = lax.dynamic_index_in_dim(vb, j, 0, keepdims=False)
+            s = jnp.einsum("bkgqd,bkjd->bkgqj", qi, kj,
+                           preferred_element_type=jnp.float32) * scale
+            dead = _dead(i, j, block, window)
+            s = jnp.where(dead, neg, s)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            p = jnp.where(dead, 0.0, jnp.exp(s - m_new[..., None]))
+            corr = jnp.exp(m - m_new)
+            l = l * corr + jnp.sum(p, axis=-1)
+            acc = acc * corr[..., None] + jnp.einsum(
+                "bkgqj,bkjd->bkgqd", p.astype(v.dtype), vj,
+                preferred_element_type=jnp.float32)
+            return m_new, l, acc
+
+        shape = qi.shape[:-1]
+        lo, hi = _key_blocks(i, block, window)
+        m, l, acc = lax.fori_loop(lo, hi, key_block, (
+            jnp.full(shape, neg, jnp.float32), jnp.zeros(shape, jnp.float32),
+            jnp.zeros(shape + (d,), jnp.float32)))
+        # every query admits itself, so l > 0
+        return (acc / l[..., None]).astype(q.dtype), m + jnp.log(l)
+
+    out, lse = lax.map(query_block, (jnp.arange(t // block), qb))
+    return _unblocks(out).reshape(b, t, heads, d), lse
+
+
+def _blocked_backward(window, block, res, dout):
+    import jax.numpy as jnp
+    from jax import lax
+
+    q, k, v, out, lse = res
+    b, t, heads, d = q.shape
+    kv = k.shape[2]
+    grouped = (b, t, kv, heads // kv, d)
+    qb = _blocks(q.reshape(grouped), block)
+    dob = _blocks(dout.reshape(grouped), block)
+    kb, vb = _blocks(k, block), _blocks(v, block)
+    delta = jnp.sum(dob.astype(jnp.float32)
+                    * _blocks(out.reshape(grouped), block), axis=-1)
+    scale = 1.0 / math.sqrt(d)
+
+    def query_block(carry, args):
+        i, qi, doi, lsei, di = args
+
+        def key_block(j, carry):
+            dqi, dk, dv = carry
+            kj = lax.dynamic_index_in_dim(kb, j, 0, keepdims=False)
+            vj = lax.dynamic_index_in_dim(vb, j, 0, keepdims=False)
+            s = jnp.einsum("bkgqd,bkjd->bkgqj", qi, kj,
+                           preferred_element_type=jnp.float32) * scale
+            p = jnp.where(_dead(i, j, block, window), 0.0,
+                          jnp.exp(s - lsei[..., None]))
+            dp = jnp.einsum("bkgqd,bkjd->bkgqj", doi, vj,
+                            preferred_element_type=jnp.float32)
+            ds = (p * (dp - di[..., None]) * scale).astype(q.dtype)
+            dqi = dqi + jnp.einsum("bkgqj,bkjd->bkgqd", ds, kj,
+                                   preferred_element_type=jnp.float32)
+            dkj = jnp.einsum("bkgqj,bkgqd->bkjd", ds, qi,
+                             preferred_element_type=jnp.float32)
+            dvj = jnp.einsum("bkgqj,bkgqd->bkjd", p.astype(q.dtype), doi,
+                             preferred_element_type=jnp.float32)
+            return (dqi, _add_at(dk, j, dkj), _add_at(dv, j, dvj))
+
+        lo, hi = _key_blocks(i, block, window)
+        dqi, dk, dv = lax.fori_loop(
+            lo, hi, key_block,
+            (jnp.zeros(qi.shape, jnp.float32),) + carry)
+        return (dk, dv), dqi.astype(q.dtype)
+
+    zeros = jnp.zeros(kb.shape, jnp.float32)
+    (dk, dv), dq = lax.scan(query_block, (zeros, zeros), (
+        jnp.arange(t // block), qb, dob, lse, delta))
+    return (_unblocks(dq).reshape(q.shape), _unblocks(dk).astype(k.dtype),
+            _unblocks(dv).astype(v.dtype))
+
+
+def blocked_attention(q, k, v, window=None, block: int = 512):
+    """Causal grouped-query attention that never holds more than one
+    block of scores: ``q`` is ``(batch, seq, heads, dim)``, ``k`` and
+    ``v`` ``(batch, seq, kv_heads, dim)``; query head ``h`` reads KV head
+    ``h // (heads // kv_heads)``.  Key ``j`` is admitted for query ``i``
+    iff ``j <= i`` and, with ``window``, ``i - j < window``.
+
+    One function for full and window layers: queries go block by block,
+    each over the key blocks that hold an admitted key only (a window of
+    512 at block 512 reads two, whatever the sequence), with the running
+    max and sum ``ring_attention`` carries around its ring, here on one
+    device; scores and softmax in float32, the products in the operands'
+    dtype.  The backward pass recomputes each block's probabilities from
+    the saved log-sum-exp (Dao et al., FlashAttention, 2022, algorithm
+    4), so what a layer keeps is its output and one float a query and
+    head.  ``block`` falls back to the whole sequence where it does not
+    divide it."""
+    import jax
+
+    if q.shape[1] % block:
+        block = q.shape[1]
+
+    @jax.custom_vjp
+    def core(q, k, v):
+        return _blocked_forward(q, k, v, window, block)[0]
+
+    def fwd(q, k, v):
+        out, lse = _blocked_forward(q, k, v, window, block)
+        return out, (q, k, v, out, lse)
+
+    core.defvjp(fwd, lambda res, dout: _blocked_backward(window, block,
+                                                         res, dout))
+    return core(q, k, v)

@@ -248,6 +248,8 @@ class FusedTrainer:
         #: jit compilation) — the steady-state numbers; ``wall_s`` etc.
         #: are totals including compiles
         self.stats = {"train_steps": 0, "eval_steps": 0, "images": 0,
+                      # ids consumed, where a sample is a row of ids
+                      "tokens": 0,
                       "wall_s": 0.0, "steps_per_sec": 0.0,
                       "img_per_sec": 0.0, "last_step_ms": 0.0,
                       "warm_steps": 0, "warm_images": 0, "warm_wall_s": 0.0,
@@ -269,10 +271,19 @@ class FusedTrainer:
         from znicz_tpu import telemetry
 
         self._tracer = telemetry.tracer()
-        _sc = telemetry.scope("trainer")
+        _sc = self._scope = telemetry.scope("trainer")
         self._m_train_steps = _sc.counter("train_steps",
                                           "fused train steps dispatched")
         self._m_images = _sc.counter("images", "training images consumed")
+        self._m_tokens = _sc.counter(
+            "tokens", "training tokens consumed (samples that are rows of "
+            "integer ids)")
+        #: ids a sample holds (0: samples are not rows of ids), read off
+        #: the loader's array when a run starts
+        self._tokens_per_sample = 0
+        #: registry gauges of what the counting units counted
+        #: (``_book_counted``), made when the first count arrives
+        self._counter_gauges = {}
         self._m_resident_prepares = _sc.counter(
             "resident_prepares", "resident sets laid out for their gather "
             "(a whole-set pass each: once per set, never per dispatch)")
@@ -406,11 +417,9 @@ class FusedTrainer:
         for f in self.forwards:
             gd = self.gd_of.get(f.name)
             if gd is not None and f.has_weights:
-                out[f.name] = tuple(np.float32(v) for v in (
-                    gd.learning_rate, gd.learning_rate_bias,
-                    gd.weights_decay, gd.weights_decay_bias, gd.l1_vs_l2,
-                    gd.gradient_moment, gd.gradient_moment_bias,
-                    gd.gradient_clip))
+                # the GD unit's own row: the eight of ``sgd_update``, or
+                # what the unit's ``apply_update`` takes
+                out[f.name] = gd._hypers()
         return out
 
     def tiled_hypers(self, k: int):
@@ -502,6 +511,25 @@ class FusedTrainer:
         return (snap is not None and snap.format != "orbax"
                 and bool(root.common.engine.get("async_snapshot", True)))
 
+    @staticmethod
+    def _copy_fits(*trees) -> bool:
+        """Whether the device has room for a second copy of ``trees``
+        beside the first — what an async save takes (the copy is what
+        makes it safe against donation).  A state that fills most of the
+        chip (two moments of a large model) is saved synchronously
+        instead: pulled leaf by leaf, no second copy.  True where the
+        device does not say (the CPU)."""
+        import jax
+
+        need = sum(int(leaf.nbytes)
+                   for leaf in jax.tree_util.tree_leaves(trees))
+        for dev in jax.local_devices():
+            stats = dev.memory_stats() or {}
+            limit = stats.get("bytes_limit")
+            if limit and stats.get("bytes_in_use", 0) + need > 0.95 * limit:
+                return False
+        return True
+
     def _drain_snapshots(self, suppress: bool) -> None:
         """Block until queued async saves are durably written.  With
         ``suppress`` (an exception already in flight) a writer error is
@@ -529,7 +557,8 @@ class FusedTrainer:
 
     # -- the pure step ---------------------------------------------------------
 
-    def forward_pass(self, params, x, key, train: bool, cast=None):
+    def forward_pass(self, params, x, key, train: bool, cast=None,
+                     counted=None):
         """Compose the units' pure applies; returns the last unit's output
         (LOGITS for a softmax last layer — loss and probs both derive from
         them, matching the evaluator's math).  ``cast`` re-casts activations
@@ -550,7 +579,17 @@ class FusedTrainer:
         recomputes the masks from (input, bias, key) instead of loading
         them from HBM (``fused_fc_epilogue`` — the dropout key is the
         absorbed unit's own ``fold_in(key, i)`` draw, so masks are
-        bit-identical to the unit path's)."""
+        bit-identical to the unit path's).
+
+        Two things are observed from the units.  A unit with
+        ``apply_counted`` returns ``(y, counters)``; the counters go into
+        the dict ``counted`` under the unit's name where the caller hands
+        one in (``loss_and_metrics`` does, and returns them with the
+        step's metrics).  In training a unit is rematerialised
+        (``jax.checkpoint`` around that unit alone: its input is all the
+        backward pass keeps of it) where it asks for that (``remat``) or
+        the engine's ``remat`` is on — per unit, so that what is live in
+        the backward pass is one unit's activations, not the network's."""
         import jax
 
         from znicz_tpu.ops.linear import linear
@@ -567,24 +606,21 @@ class FusedTrainer:
         i = 0
         while i < len(self.forwards):
             f = self.forwards[i]
-            span = 1
-            # the device trace speaks the model's names: one scope per
-            # forward unit (a fused block or tail span takes its first
-            # unit's); jax names the backward ``transpose(jvp(<unit>))``
-            with jax.named_scope(f.name):
-                if cast is not None:
-                    h = cast(h)
-                p = params.get(f.name, {})
-                blk = plan.get(i)
-                tl = tail_plan.get(i) if blk is None else None
+            p = params.get(f.name, {})
+            blk = plan.get(i)
+            tl = tail_plan.get(i) if blk is None else None
+            span = blk.span if blk is not None else (
+                tl.span if tl is not None else 1)
+
+            def unit(p, h, i=i, f=f, blk=blk, tl=tl):
+                counters = {}
                 if blk is not None:
                     h = f.apply_linear(p, h)
-                    h = fused_block(h, p["bias"], blk.n, blk.alpha,
-                                    blk.beta, blk.k, blk.pool)
                     # dropout/stochpool never sit inside a fused block,
                     # so later units keep their own fold_in(key, i)
                     # indices
-                    span = blk.span
+                    h = fused_block(h, p["bias"], blk.n, blk.alpha,
+                                    blk.beta, blk.k, blk.pool)
                 elif tl is not None:
                     if tl.kind == "conv_bias_relu":
                         h = f.apply_linear(p, h)
@@ -611,7 +647,6 @@ class FusedTrainer:
                                               masked)
                         h = y.reshape((x.shape[0],)
                                       + f.output_sample_shape)
-                    span = tl.span
                 elif isinstance(f, self._dropout_cls):
                     if train:
                         k = jax.random.fold_in(key, i)
@@ -637,21 +672,44 @@ class FusedTrainer:
 
                     h = seq_linear(h, p["weights"], p.get("bias"),
                                    weights_transposed=f.weights_transposed)
+                elif f is last and hasattr(f, "apply_logits"):
+                    h = f.apply_logits(p, h)
+                elif hasattr(f, "apply_counted"):
+                    h, counters = f.apply_counted(p, h)
                 else:
                     h = f.apply(p, h)
+                return h, counters
+
+            if train and (self.remat or getattr(f, "remat", False)):
+                unit = jax.checkpoint(unit)
+            # the device trace speaks the model's names: one scope per
+            # forward unit (a fused block or tail span takes its first
+            # unit's); jax names the backward ``transpose(jvp(<unit>))``
+            with jax.named_scope(f.name):
+                if cast is not None:
+                    h = cast(h)
+                h, counters = unit(p, h)
+            if counted is not None and counters:
+                counted[f.name] = counters
             i += span
         return h
 
     def loss_and_metrics(self, params, data, target, batch_size, key,
                          train: bool):
+        """``(loss, metrics)`` of one minibatch: ``metrics`` is ``(loss,
+        n_err, confusion)`` and, where units counted something in this
+        pass (``apply_counted``), a fourth entry ``{unit: counters}`` —
+        it leaves the device with the loss, in the same pull."""
         import jax.numpy as jnp
 
         import jax
 
+        counted = {}
         if self.compute_dtype == np.dtype("float32"):
             cast = None
             cparams = params
-            out = self.forward_pass(cparams, data, key, train)
+            out = self.forward_pass(cparams, data, key, train,
+                                    counted=counted)
         else:
             def cast(t):
                 return t.astype("bfloat16") if t.dtype == jnp.float32 else t
@@ -666,9 +724,11 @@ class FusedTrainer:
                                                            params[name])
             with jax.named_scope("input"):
                 data = cast(data)
-            out = self.forward_pass(cparams, data, key, train, cast=cast)
+            out = self.forward_pass(cparams, data, key, train, cast=cast,
+                                    counted=counted)
         with jax.named_scope("loss"):
-            return self._loss_head(out, target, batch_size)
+            loss, metrics = self._loss_head(out, target, batch_size)
+        return loss, metrics + ((counted,) if counted else ())
 
     def _loss_head(self, out, target, batch_size):
         """The evaluator's math on the last unit's output (traced under
@@ -728,10 +788,12 @@ class FusedTrainer:
     #: the mesh has a ``model`` axis (AlexNet's 4096-wide fc6/fc7)
     tp_threshold = 1024
 
-    #: rematerialize the forward during backward (``jax.checkpoint``) —
+    #: rematerialize every unit's forward during backward (one
+    #: ``jax.checkpoint`` a unit: what stays live is each unit's input) —
     #: trades ~1/3 more FLOPs for not keeping activations live, the
     #: standard HBM lever for big batches/models
-    #: (root.common.engine.remat or FusedTrainer(..., remat=True))
+    #: (root.common.engine.remat or FusedTrainer(..., remat=True); a unit
+    #: asks for itself with ``remat = True``)
     remat = False
 
     def param_sharding(self, name, k, arr):
@@ -980,14 +1042,19 @@ class FusedTrainer:
             return self.loss_and_metrics(p, data, tgt, batch_size, key,
                                          train=True)
 
-        if self.remat:
-            # recompute the forward during the backward instead of keeping
-            # activations live (SURVEY hot-path note: remat is the HBM
-            # lever; ~1/3 extra FLOPs)
-            lf = jax.checkpoint(lf)
+        # rematerialisation is per unit, inside ``forward_pass``
         grads, metrics = jax.grad(lf, has_aux=True)(params)
         new_p, new_v = {}, {}
         for name, layer_p in params.items():
+            rule = getattr(self.gd_of[name], "apply_update", None)
+            if rule is not None:
+                # the GD unit's own rule (AdamW): its tensors, moments
+                # and step count in one call
+                with jax.named_scope(f"update/{name}"):
+                    new_p[name], new_v[name] = rule(
+                        layer_p, grads[name], velocities[name],
+                        hypers[name])
+                continue
             lr, lrb, wd, wdb, l1l2, mom, momb, clip = hypers[name]
             new_p[name], new_v[name] = {}, {}
             for k, w in layer_p.items():
@@ -1072,9 +1139,9 @@ class FusedTrainer:
             p, v, conf_acc = carry
             data, tgt, bs, step, hypers = unpack(xs)
             key = jax.random.fold_in(base_key, step)
-            p, v, (loss, n_err, conf) = self._update_core(
+            p, v, (loss, n_err, conf, *counted) = self._update_core(
                 p, v, hypers, data, tgt, bs, key)
-            return (p, v, conf_acc + conf), (loss, n_err)
+            return (p, v, conf_acc + conf), (loss, n_err, *counted)
 
         return body
 
@@ -1095,9 +1162,9 @@ class FusedTrainer:
 
         def body(conf_acc, xs):
             data, tgt, bs = unpack(xs)
-            _, (loss, n_err, conf) = self.loss_and_metrics(
+            _, (loss, n_err, conf, *counted) = self.loss_and_metrics(
                 params, data, tgt, bs, self._key0, train=False)
-            return conf_acc + conf, (loss, n_err)
+            return conf_acc + conf, (loss, n_err, *counted)
 
         return body
 
@@ -1227,8 +1294,10 @@ class FusedTrainer:
     def _sync(self, *values):
         """The blocking pull: device values as host arrays, under the
         ``sync`` span — where the host waits for the device."""
+        import jax
+
         with self._timed("sync_wait_s", "sync"):
-            return tuple(np.asarray(v) for v in values)
+            return jax.tree_util.tree_map(np.asarray, values)
 
     def _feed_decision(self, mb, metrics):
         """One minibatch's HOST-side metrics (``_sync`` pulled them; the
@@ -1251,6 +1320,29 @@ class FusedTrainer:
             # (C,C) transfer happens only when a consumer reads it
             decision.confusion_matrix = conf
         decision.run()
+
+    def _book_counted(self, counted) -> None:
+        """What the counting units counted in the steps just pulled into
+        ``stats`` and the registry.  ``counted`` is the tail of a step's
+        metrics: empty, or one ``{unit: counters}`` of host arrays
+        (stacked over the steps where a scan ran them).  Each unit class
+        says what its counts mean (``book_counters(stats, layers)``
+        returns the stats it wrote)."""
+        for by_unit in counted:
+            by_class = {}
+            for f in self.forwards:
+                if f.name in by_unit:
+                    by_class.setdefault(type(f), []).append(by_unit[f.name])
+            for cls, layers in by_class.items():
+                for name in cls.book_counters(self.stats, layers):
+                    value = self.stats[name]
+                    flat = ({f"{name}_{k}": v for k, v in value.items()}
+                            if isinstance(value, dict) else {name: value})
+                    for key, v in flat.items():
+                        if key not in self._counter_gauges:
+                            self._counter_gauges[key] = self._scope.gauge(
+                                key, "counted on the device by the units")
+                        self._counter_gauges[key].set(float(v))
 
     def _reset_accounting(self):
         self._acct_seen = set()
@@ -1277,11 +1369,14 @@ class FusedTrainer:
             # watching train_steps must never read a live run as stalled)
             self._m_train_steps.inc(n_steps)
             self._m_images.inc(n_images)
+            if self._tokens_per_sample:
+                self._m_tokens.inc(n_images * self._tokens_per_sample)
         stats["wall_s"] += dt
         stats["last_step_ms"] = round(dt / (n_steps + n_eval) * 1e3, 3)
         if is_train:
             stats["train_steps"] += n_steps
             stats["images"] += n_images
+            stats["tokens"] += n_images * self._tokens_per_sample
             stats["eval_steps"] += n_eval
         else:
             stats["eval_steps"] += n_steps + n_eval
@@ -1590,6 +1685,10 @@ class FusedTrainer:
                 self._eval_scan = self.make_eval_scan()
         self._reset_accounting()
         params, velocities, dataset, targets, put = self._device_state()
+        rows = getattr(loader, "original_data", None)
+        self._tokens_per_sample = (
+            int(rows.shape[1]) if rows and len(rows.shape) == 2
+            and np.issubdtype(rows.dtype, np.integer) else 0)
         feed_decision = self._feed_decision
         account = self._account
         advance_lr = self._advance_lr
@@ -1609,7 +1708,8 @@ class FusedTrainer:
             snap_open = snap is not None and not bool(snap.gate_skip)
             snap_due = snap_open and snap.due(decision.epoch_number,
                                               decision.improved)
-            snap_async = snap_due and self._async_snapshot_enabled(snap)
+            snap_async = (snap_due and self._async_snapshot_enabled(snap)
+                          and self._copy_fits(params, velocities))
             plotters = list(getattr(wf, "plotters", None) or [])
             if (snap_due and not snap_async) or plotters:
                 self.writeback(params, velocities)
@@ -1812,18 +1912,20 @@ class FusedTrainer:
             with span("train", "flush", steps=len(seg), kind=kind,
                       step0=step0):
                 if kind == "single":
-                    loss, n_err, conf = res
+                    loss, n_err, conf, *counted = res
                     epoch_conf = conf if epoch_conf is None \
                         else epoch_conf + conf
-                    losses, n_errs = self._sync(loss, n_err)
+                    losses, n_errs, *counted = self._sync(loss, n_err,
+                                                          *counted)
                     stacked = [(losses, n_errs, None)]
                 else:
                     ms, conf_sum = res
                     epoch_conf = conf_sum if epoch_conf is None \
                         else epoch_conf + conf_sum
-                    losses, n_errs = self._sync(*ms)
+                    losses, n_errs, *counted = self._sync(*ms)
                     stacked = [(losses[i], n_errs[i], None)
                                for i in range(len(seg))]
+                self._book_counted(counted)
                 with self._timed("decide_s", "decide"):
                     for s, m in zip(seg, stacked):
                         feed_decision(s, m)
@@ -1934,17 +2036,23 @@ class FusedTrainer:
                                 self.steps_done)
                             if staging:
                                 dseg, tseg = stage_segment([mb])
-                                loss, n_err, conf = self._eval_step(
-                                    params, dseg, tseg, bs, key, True)
+                                loss, n_err, conf, *counted = \
+                                    self._eval_step(params, dseg, tseg, bs,
+                                                    key, True)
                             else:
                                 idx = put(mb["idx"])
-                                loss, n_err, conf = self._eval_step(
-                                    params, dataset, targets, idx, bs, key,
-                                    True)
+                                loss, n_err, conf, *counted = \
+                                    self._eval_step(params, dataset,
+                                                    targets, idx, bs, key,
+                                                    True)
                             if epoch_conf is not None:
                                 conf = epoch_conf + conf
                                 epoch_conf = None
-                        loss, n_err = self._sync(loss, n_err)
+                        # what the units counted rides in this one pull
+                        # (the update that follows runs the same forward)
+                        loss, n_err, *counted = self._sync(loss, n_err,
+                                                           *counted)
+                        self._book_counted(counted)
                         with self._timed("decide_s", "decide"):
                             feed_decision(mb, (loss, n_err, conf))
                         applied = not bool(decision.gd_skip)
@@ -1999,10 +2107,10 @@ class FusedTrainer:
                             ms, conf = self._eval_scan(
                                 params, dseg, tseg, bs_vec)
                         elif len(seg) == 1:
-                            loss, n_err, conf = self._eval_step(
+                            loss, n_err, conf, *counted = self._eval_step(
                                 params, dataset, targets, put(mb["idx"]),
                                 np.int32(mb["size"]), self._key0, False)
-                            ms = (loss, n_err)
+                            ms = (loss, n_err, *counted)
                         else:
                             idx_op = put(np.stack([s["idx"] for s in seg]))
                             bs_vec = put(np.array([s["size"] for s in seg],
@@ -2010,8 +2118,10 @@ class FusedTrainer:
                             ms, conf = self._eval_scan(
                                 params, dataset, targets, idx_op, bs_vec)
                         # a lone step's scalars feed like a scan's stack
-                        losses, n_errs = (np.atleast_1d(v)
-                                          for v in self._sync(*ms))
+                        losses, n_errs, *counted = self._sync(*ms)
+                        losses, n_errs = (np.atleast_1d(losses),
+                                          np.atleast_1d(n_errs))
+                        self._book_counted(counted)
                         with self._timed("decide_s", "decide"):
                             for i, s in enumerate(seg):
                                 feed_decision(s, (losses[i], n_errs[i],
@@ -2067,7 +2177,9 @@ class FusedTrainer:
         selects segmented mode.  Decision semantics are preserved
         exactly either way — metrics are fed in order, just later in
         wall time, and stops are rolled back to the exact stopping
-        state."""
+        state.  What counting units count (``apply_counted``) is not
+        booked on this path: its packed scalar vector holds loss and
+        error counts only."""
         from znicz_tpu.core.mutable import Bool
 
         wf = self.workflow
@@ -2158,7 +2270,7 @@ class FusedTrainer:
                 (train_idx[:k], train_bs[:k], step_nums[:k], head))
             key_t = jax.random.fold_in(base_key, step_nums[k])
             hyp_t = jax.tree_util.tree_map(lambda h: h[k], hypers_mat)
-            p2, v2, (tl, tn, tconf) = self._step_core(
+            p2, v2, (tl, tn, tconf, *_) = self._step_core(
                 p, v, hyp_t, dataset, targets, train_idx[k], train_bs[k],
                 key_t)
             p, v = jax.lax.cond(apply_tail,

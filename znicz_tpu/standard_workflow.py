@@ -78,6 +78,13 @@ def _registry() -> Dict[str, Tuple[Type, Optional[Type]]]:
     reg["depooling"] = (depooling.Depooling, depooling.GDDepooling)
     reg["attention"] = (attention.MultiHeadAttention,
                         attention.GDMultiHeadAttention)
+    from znicz_tpu import decoder
+    from znicz_tpu.nn_units import GradientDescentAdamW
+
+    for kind, cls in (("token_embedding", decoder.TokenEmbedding),
+                      ("decoder_layer", decoder.DecoderLayer),
+                      ("lm_head", decoder.LMHead)):
+        reg[kind] = (cls, GradientDescentAdamW)
     try:
         from znicz_tpu import resizable_all2all
 
