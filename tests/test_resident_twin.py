@@ -2,9 +2,11 @@
 program of the fused trainer gathers from a prepared twin of the loader's
 array (``FusedTrainer._resident``) — sample axis major-most, already in
 the dtype the first unit consumes — and the results are the bits the
-same steps give when fed ``jnp.take(raw, idx)`` directly.  The last test
-compiles for the chip without the chip and is the only one in ``tests/``
-that describes a topology; nothing here reads a clock."""
+same steps give when fed ``jnp.take(raw, idx)`` directly.  The last tests
+compile for the chip without the chip and are the only ones in ``tests/``
+that describe a topology (one file: the TPU's library is one process's),
+so the attention core's kernels (ISSUE 28) meet the chip's compiler here
+too; nothing here reads a clock."""
 
 import re
 
@@ -392,3 +394,58 @@ def test_no_program_rewrites_the_whole_set(storage, chips, topo,
         assert not whole_set_ops(text, samples), (
             name, whole_set_ops(text, samples))
     assert not trainer._twins and not trainer.stats["resident_prepares"]
+
+
+@pytest.mark.parametrize("heads,window", [(48, None), (64, 512)],
+                         ids=["full-layer", "window-layer"])
+def test_the_attention_kernels_compile_at_the_cells_shapes(
+        heads, window, topo, no_compile_cache, monkeypatch):
+    """Mosaic takes the forward, dq and dk/dv kernels at Laguna's widths
+    with the tiles the predicate chooses (interpret mode cannot show a
+    refused slice or too much VMEM).  Two layers of one kind share one
+    lowering of each kernel, and every call still carries ITS layer's
+    scope in the compiled text: the benchmark's reader
+    (``reduce/inner.py`` ``tag_of``) files each under ``attn_core`` by
+    layer and direction, the recomputed forward pass apart."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from benchmark import spec
+    from znicz_tpu import backends
+    from znicz_tpu.ops import attention
+
+    monkeypatch.setattr(backends, "pallas_interpret", lambda: False)
+    place = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((2, 8192, heads, 128), jnp.bfloat16,
+                             sharding=place)
+    k = jax.ShapeDtypeStruct((2, 8192, 8, 128), jnp.bfloat16, sharding=place)
+    tiles = attention.core_tiles("tpu", q.shape, 8, q.dtype, window)
+    assert tiles == ((512, 512) if window is None else (256, 256))
+
+    def core(q, k, v):                  # scopes as ``forward_pass`` and
+        with jax.named_scope("attn_core"):      # the decoder layer open them
+            return attention._core(q, k, v, window, 512, tiles)
+
+    def value(q, k, v):
+        for layer in ("layer1", "layer2"):
+            with jax.named_scope(layer):
+                q = q + jax.checkpoint(core)(q, k, v)
+        return jnp.sum(q.astype(jnp.float32))
+
+    before = attention.kernel_counts()["attn_kernel_lowerings"]
+    text = jax.jit(jax.value_and_grad(value, (0, 1, 2))).lower(
+        q, k, k).compile().as_text()
+    assert attention.kernel_counts()["attn_kernel_lowerings"] - before == 3
+    calls = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    inner = spec.load_module("reduce", "inner")
+    assert sorted((name.split("/")[-2], inner.tag_of(name))
+                  for name in calls) == sorted(
+        (kernel, (layer, "attn_core", direction))
+        for layer in ("layer1", "layer2")
+        for kernel, direction in (("attn_core_forward", "forward"),
+                                  ("attn_core_forward", "recompute"),
+                                  ("attn_core_dq", "backward"),
+                                  ("attn_core_dkv", "backward")))

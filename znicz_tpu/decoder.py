@@ -7,7 +7,7 @@ second dictionary (``samples/laguna.py`` holds the first).
 They follow the framework's unit contract (pure ``apply(params, x)``,
 ``params()`` a dict of ``Array``s, a GD twin, registry types
 ``token_embedding`` / ``decoder_layer`` / ``lm_head`` for
-``StandardWorkflow``) with three things the older units do not have, all
+``StandardWorkflow``) with four things the older units do not have, all
 of which the fused trainer OBSERVES rather than is told:
 
   - a unit with many tensors: ``DecoderLayer.params()`` holds its dozen,
@@ -16,6 +16,11 @@ of which the fused trainer OBSERVES rather than is told:
   - a unit that counts: ``apply_counted`` returns ``(y, counters)``, small
     int32 arrays that leave the device with the step's loss, in the same
     pull (``FusedTrainer.loss_and_metrics`` / ``_book_counted``);
+  - a unit that notes how it was traced: ``run_stats(units)`` turns what
+    the layers of a run noted on the host (here which way each attention
+    core runs, ``ops.attention.core_tiles``, and how often the process
+    traced and lowered the core's kernels) into ``FusedTrainer.stats``
+    when the run ends — no device work, nothing in a step;
   - a unit that asks for rematerialisation (``remat = True``): training
     keeps a decoder layer's input only, 67 MB a layer at 16,384 tokens of
     width 2,048, and recomputes the rest on the way back.
@@ -41,7 +46,7 @@ from znicz_tpu.memory import Array
 from znicz_tpu.nn_units import ForwardBase
 from znicz_tpu.ops import moe
 from znicz_tpu.ops.attention import (apply_rope, blocked_attention,
-                                     rope_tables)
+                                     core_tiles, kernel_counts, rope_tables)
 
 
 def rms_norm(x, gain, eps: float):
@@ -187,6 +192,7 @@ class DecoderLayer(_DeviceInitialised):
                 f"{self.first_expert + self.experts_held} of "
                 f"{self.experts_total}, {self.experts_per_token} a token")
         self.hidden = 0             # the input's width, at initialize
+        self.core_in_kernels = None     # noted when ``apply_counted`` traces
 
     @property
     def sparse(self) -> bool:
@@ -254,6 +260,19 @@ class DecoderLayer(_DeviceInitialised):
         return ("moe_rows_by_expert", "moe_rows_routed", "moe_rows_dropped",
                 "moe_counted_steps")
 
+    @staticmethod
+    def run_stats(layers: list) -> dict:
+        """What the decoder layers of a run noted while they were traced:
+        how many run their attention core in the Pallas kernels and how
+        many composed of XLA operations (``core_in_kernels``, set by the
+        last trace of ``apply_counted``), and the process's count of
+        kernel traces and lowerings (``ops.attention.kernel_counts``)."""
+        ways = [f.core_in_kernels for f in layers
+                if f.core_in_kernels is not None]
+        return {"attn_cores_kernel": sum(ways),
+                "attn_cores_composed": len(ways) - sum(ways),
+                **kernel_counts()}
+
     # -- pure compute ----------------------------------------------------------
 
     def apply(self, params, x):
@@ -278,6 +297,9 @@ class DecoderLayer(_DeviceInitialised):
                     else None)
         with jax.named_scope("attn_core"):
             o = blocked_attention(q, k, v, self.window)
+        self.core_in_kernels = core_tiles(
+            jax.default_backend(), q.shape, kv, q.dtype,
+            self.window) is not None
         with jax.named_scope("attn_out"):
             if gate is not None:
                 o = o * gate[..., None]

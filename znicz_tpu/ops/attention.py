@@ -4,8 +4,18 @@ The reference has no attention anywhere (SURVEY.md §5 "long-context:
 absent") — this is a beyond-reference, TPU-first capability so the
 framework handles long sequences at the scale the task demands:
 
-  - ``attention(q, k, v, causal)`` — standard scaled-dot-product MHA core,
-    one fused jit (XLA flash-fuses the softmax chain on TPU);
+  - ``attention(q, k, v, causal)`` — standard scaled-dot-product MHA core
+    that materialises the ``(batch, heads, q, k)`` scores: serving's
+    prefill chunks and decode, where they are small.  XLA does NOT keep a
+    softmax chain on the chip by itself: composed of XLA operations, even
+    the blocked core below wrote every block of scores to HBM and read it
+    back several times (13.6 % of its roofline; ledger, PR 27);
+  - ``blocked_attention(q, k, v, window)`` — the decoder's training core:
+    causal or windowed, grouped-query, block by block with a running
+    softmax and its own backward pass.  On a TPU, where the shapes tile,
+    a block pair runs in the Pallas kernels of ``attention_pallas.py``
+    (the block of scores stays in VMEM); elsewhere composed of XLA
+    operations — ``core_tiles`` decides, nothing is set;
   - ``cache_append(cache, row, t)`` / ``decode_attention(q1, k, v, t)`` —
     the KV-cache decode step (ISSUE 16): append this step's key/value row
     at per-row position ``t``, then attend a length-1 query over the
@@ -258,13 +268,19 @@ def apply_rope(x, cos, sin):
     import jax.numpy as jnp
 
     r = cos.shape[-1]
-    rot = x[..., :r].astype(jnp.float32)
-    a, b = rot[..., :r // 2], rot[..., r // 2:]
-    turned = jnp.concatenate([-b, a], axis=-1)
-    out = rot * cos[None, :, None, :] + turned * sin[None, :, None, :]
-    out = out.astype(x.dtype)
-    return out if r == x.shape[-1] else jnp.concatenate(
-        [out, x[..., r:]], axis=-1)
+    half = r // 2
+    a = x[..., :half].astype(jnp.float32)
+    b = x[..., half:r].astype(jnp.float32)
+    cos, sin = cos[None, :, None, :], sin[None, :, None, :]
+    # the halves are rotated apart and joined once, in ``x``'s dtype: a
+    # float32 ``[-b, a]`` joined first is a tensor of its own on the chip
+    # wherever the consumer cannot take it into its fusion (PERF.md
+    # section 6, PR 28)
+    parts = [(a * cos[..., :half] - b * sin[..., :half]).astype(x.dtype),
+             (b * cos[..., half:] + a * sin[..., half:]).astype(x.dtype)]
+    if r < x.shape[-1]:
+        parts.append(x[..., r:])
+    return jnp.concatenate(parts, axis=-1)
 
 
 def _key_blocks(i, block: int, window):
@@ -407,6 +423,78 @@ def _blocked_backward(window, block, res, dout):
             _unblocks(dv).astype(v.dtype))
 
 
+def core_tiles(backend: str, q_shape, kv_heads: int, dtype, window=None):
+    """The one decision of which way ``blocked_attention`` runs a block
+    pair, from what it observes: the kernel's ``(query tile, key tile)``
+    where the backend is a TPU and the shapes tile (head size a multiple
+    of the 128 lanes, rows a multiple of a tile, bfloat16 operands: the
+    dtype the kernel was measured and compared in on the chip), else
+    ``None``: the composed path."""
+    import numpy as np
+
+    _, seq, heads, dim = q_shape
+    if (backend != "tpu" or dim % 128 or heads % kv_heads
+            or np.dtype(dtype).name != "bfloat16"):
+        return None
+    from znicz_tpu.ops import attention_pallas
+
+    return attention_pallas.pick_tiles(seq, window)
+
+
+def kernel_counts() -> dict:
+    """How often this process traced one of the core's kernels and
+    lowered one to a Mosaic body (``attention_pallas.COUNTS``; zeros where
+    no core ever ran in the kernels): kinds of core x 4 traces a process
+    at most (forward, forward again under ``jax.checkpoint``, dq, dk/dv)
+    however many layers, passes and programs there are, and kinds x 3
+    lowerings a training program.  A regression of set-up shows here as a
+    number."""
+    import sys
+
+    kernels = sys.modules.get("znicz_tpu.ops.attention_pallas")
+    counts = kernels.COUNTS if kernels else {"traces": 0, "lowerings": 0}
+    return {"attn_kernel_traces": counts["traces"],
+            "attn_kernel_lowerings": counts["lowerings"]}
+
+
+def _core(q, k, v, window, block: int, tiles):
+    """The attention core behind one ``custom_vjp`` whose residuals are
+    q, k, v, the output and one float32 log-sum-exp a query and head:
+    a block pair runs in the Pallas kernels with ``tiles``, composed of
+    XLA operations in blocks of ``block`` without."""
+    import jax
+
+    if tiles is None:
+        def forward(q, k, v):
+            return _blocked_forward(q, k, v, window, block)
+
+        def backward(res, dout):
+            return _blocked_backward(window, block, res, dout)
+    else:
+        from znicz_tpu.backends import pallas_interpret
+        from znicz_tpu.ops import attention_pallas
+
+        static = dict(window=window, tiles=tiles,
+                      interpret=pallas_interpret())
+
+        def forward(q, k, v):
+            return attention_pallas.forward(q, k, v, **static)
+
+        def backward(res, dout):
+            return attention_pallas.backward(*res, dout, **static)
+
+    @jax.custom_vjp
+    def core(q, k, v):
+        return forward(q, k, v)[0]
+
+    def fwd(q, k, v):
+        out, lse = forward(q, k, v)
+        return out, (q, k, v, out, lse)
+
+    core.defvjp(fwd, backward)
+    return core(q, k, v)
+
+
 def blocked_attention(q, k, v, window=None, block: int = 512):
     """Causal grouped-query attention that never holds more than one
     block of scores: ``q`` is ``(batch, seq, heads, dim)``, ``k`` and
@@ -416,27 +504,27 @@ def blocked_attention(q, k, v, window=None, block: int = 512):
 
     One function for full and window layers: queries go block by block,
     each over the key blocks that hold an admitted key only (a window of
-    512 at block 512 reads two, whatever the sequence), with the running
+    512 reads what overlaps it, whatever the sequence), with the running
     max and sum ``ring_attention`` carries around its ring, here on one
-    device; scores and softmax in float32, the products in the operands'
-    dtype.  The backward pass recomputes each block's probabilities from
-    the saved log-sum-exp (Dao et al., FlashAttention, 2022, algorithm
-    4), so what a layer keeps is its output and one float a query and
-    head.  ``block`` falls back to the whole sequence where it does not
-    divide it."""
+    device; scores, mask and softmax in float32, both products in the
+    operands' dtype with float32 accumulation, the normalisation by the
+    exact sum.  The backward pass recomputes each block's probabilities
+    from the saved log-sum-exp (Dao et al., FlashAttention, 2022,
+    algorithm 4), so what a layer keeps is its output and one float a
+    query and head.
+
+    Two ways to run a block pair, chosen by ``core_tiles`` from the
+    backend and the shapes, with nothing to set: on a TPU, with a head
+    size that is a multiple of 128, rows a tile divides and bfloat16
+    operands, the Pallas kernels of ``ops/attention_pallas.py``, where the
+    block of scores stays in VMEM; everywhere else (the CPU backend, the
+    ``tiny`` preset's head size 16, float32 operands) the same algorithm
+    composed of XLA operations in blocks of ``block``, which falls back
+    to the whole sequence where it does not divide it."""
     import jax
 
     if q.shape[1] % block:
         block = q.shape[1]
-
-    @jax.custom_vjp
-    def core(q, k, v):
-        return _blocked_forward(q, k, v, window, block)[0]
-
-    def fwd(q, k, v):
-        out, lse = _blocked_forward(q, k, v, window, block)
-        return out, (q, k, v, out, lse)
-
-    core.defvjp(fwd, lambda res, dout: _blocked_backward(window, block,
-                                                         res, dout))
-    return core(q, k, v)
+    tiles = core_tiles(jax.default_backend(), q.shape, k.shape[2], q.dtype,
+                       window)
+    return _core(q, k, v, window, block, tiles)

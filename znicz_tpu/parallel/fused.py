@@ -1663,6 +1663,14 @@ class FusedTrainer:
         # chip_smoke.py can read it without a handle on the trainer
         self.stats["compiles"] = int(self._m_compiles.value)
         self.stats["jit_cache_sizes"] = self.jit_cache_sizes()
+        # what the units noted on the host while the run's programs were
+        # traced and lowered; each class says what its notes mean
+        # (``run_stats(units)``: a decoder layer's is which way its
+        # attention core ran)
+        for cls in dict.fromkeys(type(f) for f in self.forwards):
+            if hasattr(cls, "run_stats"):
+                self.stats.update(cls.run_stats(
+                    [f for f in self.forwards if type(f) is cls]))
 
     def _run_segmented(self) -> None:
         from znicz_tpu.loader.base import TRAIN
