@@ -9,7 +9,7 @@ drives the main path once through the entry points a user would call:
   train    ``launcher.main(["alexnet", "--backend", "tpu", ...])`` at
            AlexNet's full width (227x227x3, the five convs, fc 4096/4096,
            1000 classes, batch 128, bf16 compute and optimizer state —
-           bench.py's configuration), 3 epochs of 4 scanned train steps
+           the benchmark's ``alexnet`` configuration), 3 epochs of 4 scanned train steps
            plus validation through ``FusedTrainer.run``, Decision and the
            snapshotter gate;
   serve    ``launcher.main(["charlm", "--serve", ..., "--generate"])`` on
@@ -317,8 +317,6 @@ def kernels_phase(batch: int = 128):
     import jax.numpy as jnp
 
     from znicz_tpu.backends import pallas_interpret
-    from znicz_tpu.lrn import lrn_ref
-    from znicz_tpu.ops.lrn_pallas import lrn
     from znicz_tpu.pallas_fused_block import fused_bias_relu, fused_block
 
     check(not pallas_interpret(),
@@ -332,8 +330,6 @@ def kernels_phase(batch: int = 128):
          lambda x, b: jnp.maximum(x + b, 0), (batch, 13, 13, 384)),
         ("fused_bias_relu", fused_bias_relu,
          lambda x, b: jnp.maximum(x + b, 0), (batch, 13, 13, 256)),
-        ("lrn", lambda x, b: lrn(x, *LRN), lambda x, b: lrn_ref(x, *LRN),
-         (batch, 55, 55, 96)),
     ]
 
     def both_ways(fn):

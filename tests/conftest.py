@@ -13,15 +13,6 @@ import time  # noqa: E402
 
 import pytest  # noqa: E402
 
-#: tier-1 time-budget guard (ISSUE 7 satellite): the suite's hard cap is
-#: 870s (ROADMAP tier-1 command `timeout -k 10 870`); it has been running
-#: ~805-835s — one slow new test from a timeout kill.  Past this SOFT
-#: budget the terminal summary shouts; the 10-slowest table below it
-#: names where the seconds went so the next PR knows what to trim or
-#: `slow`-mark.  Informational only — never fails a run.
-SOFT_BUDGET_S = 820.0
-
-
 def pytest_configure(config):
     config.addinivalue_line(
         "markers",
@@ -30,7 +21,10 @@ def pytest_configure(config):
 
 
 def pytest_terminal_summary(terminalreporter, exitstatus, config):
-    """Ten slowest tests + a soft-budget warning (see SOFT_BUDGET_S)."""
+    """The ten slowest tests and the session's wall time: where the
+    seconds went, for whoever has to trim or ``slow``-mark next.  The
+    limit is the runner's (the driver's command carries its own
+    ``timeout``); none is stated here."""
     durations = []
     for reports in terminalreporter.stats.values():
         for rep in reports:
@@ -41,19 +35,27 @@ def pytest_terminal_summary(terminalreporter, exitstatus, config):
     tr = terminalreporter
     wall = time.perf_counter() - getattr(config, "_znicz_session_t0",
                                          time.perf_counter())
-    tr.write_sep("-", "tier-1 time budget")
+    tr.write_sep("-", "slowest tests")
     for dur, nodeid in sorted(durations, reverse=True)[:10]:
         tr.write_line(f"  {dur:7.2f}s  {nodeid}")
     tr.write_line(f"  session wall {wall:.1f}s over {len(durations)} "
-                  f"test calls (soft budget {SOFT_BUDGET_S:.0f}s, "
-                  f"hard cap 870s)")
-    if wall > SOFT_BUDGET_S and len(durations) > 50:
-        # len() gate: a single-file run that happens to be long must not
-        # shout about the SUITE budget
-        tr.write_line(
-            f"  WARNING: tier-1 wall time {wall:.1f}s exceeds the "
-            f"{SOFT_BUDGET_S:.0f}s soft budget — the 870s hard cap is "
-            "close; slow-mark or trim before adding more (ISSUE 7)")
+                  f"test calls")
+
+
+@pytest.fixture
+def restore_root():
+    """The config tree as it was, after a test that ran a whole job's
+    overrides through it: the saved values come back (``Config.update``
+    merges) and the engine knobs the job ADDED go — a ``compute_dtype``
+    left behind trains every later test of the worker in bf16."""
+    from znicz_tpu.core.config import root
+
+    saved = root.to_dict()
+    yield
+    root.update(saved)
+    for key in set(root.common.engine.to_dict()) - set(
+            saved["common"]["engine"]):
+        delattr(root.common.engine, key)
 
 
 @pytest.fixture(autouse=True)

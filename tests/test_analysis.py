@@ -215,9 +215,9 @@ def test_jit_purity_discovery_forms():
             return pl.pallas_call(kernel, out_shape=None)(x)
     """)
     assert len(found) == 1
-    # public wrapper named like the inner traced def (ops/lrn_pallas
-    # shape): the int()/float() hyper normalization in the WRAPPER is
-    # trace-free and must stay quiet
+    # public wrapper named like the inner traced def (a memoised
+    # custom-vjp factory): the int()/float() hyper normalization in the
+    # WRAPPER is trace-free and must stay quiet
     assert not _check(checker, """
         import functools, jax
 

@@ -25,6 +25,25 @@ from znicz_tpu.serving import aot_cache
 VOCAB = 32
 
 
+@pytest.fixture(autouse=True)
+def _no_persistent_compile_cache():
+    """An executable that jax FETCHED from its persistent compile cache
+    does not survive ``serialize_executable`` on the CPU ("Function ...
+    not found" when the copy runs), and the launcher turns that cache on
+    for the whole process: a test of another file on this worker, or a
+    warm ``.znicz_cache/jax``, failed four to six tests here (PERF.md
+    section 7, since PR 22).  These tests compile their own."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    saved = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", saved)
+    compilation_cache.reset_cache()
+
+
 def _tiny_mnist_wf(n_train=120):
     from znicz_tpu.samples import mnist
 

@@ -1,5 +1,5 @@
-"""Async / deep-pipeline checkpointing (VERDICT r4 item 4): the fast path
-must snapshot WITHOUT stalling training — and the snapshot must be the
+"""Async / deep-pipeline checkpointing: the fast path must snapshot
+WITHOUT stalling training — and the snapshot must be the
 same checkpoint the synchronous writeback path would have produced, at
 every level (weights, velocities, loader order, prng streams), so resume
 trajectories are indistinguishable."""

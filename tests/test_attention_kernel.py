@@ -335,25 +335,21 @@ def test_the_kernels_calls_carry_the_scope(monkeypatch):
         assert inner.tag_of(name) == ("layer1", "attn_core", want)
 
 
-def test_the_trainer_counts_the_cores_by_path(tmp_path):
+def test_the_trainer_counts_the_cores_by_path(tmp_path, restore_root):
     """``FusedTrainer.stats`` after a run of the tiny preset: head size 16
     on the CPU, so no core in the kernels and as many composed as the
     model has decoder layers, no kernel traced or lowered — noted while
     the programs were traced, booked when the run ends."""
-    saved = root.to_dict()
     before = attention.kernel_counts()
-    try:
-        cell = spec.Cell(spec.load(), "laguna-train-8k")
-        root.common.dirs.snapshots = str(tmp_path)
-        built = driver.build(cell, 11, True)
-        trainer = built.trainer
-        layers = [f for f in built.wf.forwards
-                  if isinstance(f, decoder.DecoderLayer)]
-        assert "attn_cores_composed" not in trainer.stats
-        built.wf.decision.max_epochs = 1
-        trainer.run()
-    finally:
-        root.update(saved)
+    cell = spec.Cell(spec.load(), "laguna-train-8k")
+    root.common.dirs.snapshots = str(tmp_path)
+    built = driver.build(cell, 11, True)
+    trainer = built.trainer
+    layers = [f for f in built.wf.forwards
+              if isinstance(f, decoder.DecoderLayer)]
+    assert "attn_cores_composed" not in trainer.stats
+    built.wf.decision.max_epochs = 1
+    trainer.run()
     assert len(layers) == 5
     assert trainer.stats["attn_cores_kernel"] == 0
     assert trainer.stats["attn_cores_composed"] == len(layers)

@@ -489,6 +489,9 @@ def test_autoscaler_spawns_to_cap_and_drains_back_to_quorum():
     for r in reps:
         scaler.adopt(r)
     cli = _client(bal)
+    from znicz_tpu import telemetry
+
+    seq0 = telemetry.journal().last_seq
     try:
         # high_load < 0 forces every eval 'high' — a deterministic ramp
         bal.enable_autoscale(
@@ -502,6 +505,15 @@ def test_autoscaler_spawns_to_cap_and_drains_back_to_quorum():
             assert time.time() - t0 < 15, "never scaled to the cap"
             time.sleep(0.02)
         assert bal.scale_ups >= 2
+        # the journal says why: every scale-up is an event that carries
+        # the load numbers that drove it, seqs strictly increasing
+        events = telemetry.journal().since(seq0)
+        ups = [e for e in events if e["kind"] == "autoscale_up"]
+        assert len(ups) >= 2, events
+        assert all({"load", "parked", "members", "pending"} <= set(e)
+                   for e in ups), ups
+        seqs = [e["seq"] for e in events]
+        assert seqs == sorted(set(seqs)) and seqs[0] > seq0
         st = bal.stats()["autoscale"]
         assert st["enabled"] and st["max"] == 4
         # at the cap: no spawns pile up past it

@@ -38,6 +38,37 @@ class TestConfig:
         key, value = parse_override("root.m.lr=0.01")
         assert key == "m.lr" and value == 0.01
 
+    # the six knobs PR 30 removed with their experiments, and ``fuse``,
+    # which was declared and never read
+    @pytest.mark.parametrize("knob", [
+        "precision", "remat", "lrn_pow", "lrn_autodiff", "pallas_lrn",
+        "pool_bwd", "fuse"])
+    def test_undeclared_engine_knob_is_refused_at_the_door(self, knob):
+        """The tree autovivifies, so an engine knob that ENGINE_DEFAULTS
+        does not declare (removed, or a typo) would silently train on the
+        default: ``set_by_path`` — the launcher's, ``run_with.py``'s and
+        the benchmark drivers' door — refuses it by name, from the root
+        or from a subtree, and leaves the tree as it was."""
+        from znicz_tpu.core.config import ENGINE_DEFAULTS
+
+        assert knob not in ENGINE_DEFAULTS
+        cfg = Config("root")
+        with pytest.raises(KeyError) as err:
+            apply_overrides(cfg, [f"root.common.engine.{knob}=2"])
+        assert f"root.common.engine.{knob}" in str(err.value)
+        assert "ENGINE_DEFAULTS" in str(err.value)
+        with pytest.raises(KeyError, match=knob):
+            cfg.common.engine.set_by_path(knob, 2)
+        assert knob not in cfg.common.engine
+        # declared knobs, nested ones too, and other trees pass
+        apply_overrides(cfg, ["root.common.engine.scan_chunk=4",
+                              "root.common.engine.mesh.data=4",
+                              f"root.alexnet.{knob}=2"])
+        assert cfg.common.engine.scan_chunk == 4
+        assert cfg.common.engine.mesh.data == 4
+        with pytest.raises(KeyError, match="mesh.dat"):
+            cfg.set_by_path("common.engine.mesh.dat", 4)
+
 
 class TestBool:
     def test_plain(self):

@@ -380,13 +380,26 @@ def test_stitched_trace_e2e_balancer_two_replicas(tmp_path):
             time.sleep(0.05)
         x = np.zeros((1, 28 * 28), np.float32)
         store = telemetry.fleet_trace()
+        # the store is the process's: traces an earlier test of this
+        # worker left (a master/slave job crosses three origins too) are
+        # not this fleet's
+        before = set(store.traces())
+
+        def best_stitched():
+            best = (None, [])
+            for tid, members in store.traces().items():
+                origins = list(dict.fromkeys(o for o, _ in members))
+                if tid not in before and len(origins) > len(best[1]):
+                    best = (tid, origins)
+            return best
+
         deadline = time.time() + 30
         stitched = (None, [])
         while time.time() < deadline:
             rep = cli.result(cli.submit(x))
             assert rep["lb"] and rep["ok"]
             time.sleep(0.05)
-            stitched = store.best_stitched()
+            stitched = best_stitched()
             if len(stitched[1]) >= 3:
                 break
         tid, origins = stitched
@@ -432,4 +445,95 @@ def test_stitched_trace_e2e_balancer_two_replicas(tmp_path):
         cli.close()
         for s in srvs:
             s.stop()
+        bal.stop()
+
+
+#: a real OS process running one tiny charlm generation replica that
+#: announces to the parent's balancer; spans ride its heartbeats
+_GEN_REPLICA = """
+import sys
+from znicz_tpu.core import prng
+from znicz_tpu.core.config import root
+root.charlm.loader.update({"n_train": 64, "n_valid": 16, "n_test": 0,
+                           "seq_len": 32, "minibatch_size": 16})
+root.charlm.model.update({"vocab": 32, "embed": 32, "heads": 2,
+                          "ffn": 64})
+root.common.serving.seq.rungs = [8, 32]
+root.common.serving.generate.update({"enabled": True, "page_size": 8,
+                                     "slots": 4})
+prng.reset(1013)
+from znicz_tpu.samples.charlm import CharLMWorkflow
+from znicz_tpu.serving import InferenceServer
+wf = CharLMWorkflow()
+wf.initialize(device=None)
+srv = InferenceServer(wf, max_batch=4, max_delay_ms=1.0,
+                      announce=sys.argv[1],
+                      replica_id=sys.argv[2]).start()
+sys.stdin.read()        # parent closes stdin -> clean exit
+srv.stop()
+"""
+
+
+@pytest.mark.slow
+def test_generation_trace_stitched_across_os_processes():
+    """One generation request under one ``trace_id`` crosses >= 3 fleet
+    origins of which at least one lives in ANOTHER OS process: client
+    and balancer here, the replica a child that exports its spans on its
+    heartbeats."""
+    import os
+    import subprocess
+    import sys
+
+    from znicz_tpu.serving import InferenceClient, ReplicaBalancer
+
+    telemetry.set_enabled(True)
+    bal = ReplicaBalancer(replica_ttl_s=2.5, heartbeat_s=0.25).start()
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=repo + os.pathsep + os.environ.get(
+                   "PYTHONPATH", ""))
+    child = subprocess.Popen(
+        [sys.executable, "-c", _GEN_REPLICA, bal.endpoint, "gen-child"],
+        stdin=subprocess.PIPE, stdout=subprocess.DEVNULL,
+        stderr=subprocess.DEVNULL, env=env, cwd=repo)
+    my_pid = str(os.getpid())
+
+    def stitched():
+        for tid, members in telemetry.fleet_trace().traces().items():
+            origins = list(dict.fromkeys(o for o, _ in members))
+            pids = {o.rsplit("@", 1)[-1] for o in origins}
+            if len(origins) >= 3 and pids - {my_pid} and all(
+                    s.get("args", {}).get("trace_id") == tid
+                    for _, s in members):
+                return tid, origins, pids
+        return None
+
+    cli = None
+    try:
+        t0 = time.time()
+        while bal.ready_count() < 1:
+            assert child.poll() is None, child.returncode
+            assert time.time() - t0 < 300, "replica never announced"
+            time.sleep(0.2)
+        cli = InferenceClient(bal.endpoint, timeout=90.0,
+                              breaker_failures=0)
+        rng = np.random.default_rng(2008)
+        found, deadline = None, time.time() + 60
+        while found is None and time.time() < deadline:
+            prompt = rng.integers(1, 32, size=6).astype(np.uint8)
+            rep = cli.generate(prompt, max_new_tokens=8, timeout=90)
+            assert len(rep["tokens"]) >= 1
+            time.sleep(0.05)
+            found = stitched()
+        assert found is not None, "no trace stitched across processes"
+        tid, origins, pids = found
+        assert len(origins) >= 3 and len(pids) >= 2, (origins, pids)
+    finally:
+        if cli is not None:
+            cli.close()
+        child.stdin.close()
+        try:
+            child.wait(timeout=20)
+        except subprocess.TimeoutExpired:
+            child.kill()
         bal.stop()

@@ -110,13 +110,13 @@ def test_fused_tp_hybrid_mesh_matches_single_bf16(tmp_path):
     mesh vs bf16 single-device (looser tolerances — bf16 collective
     reduction order differs)."""
     root.common.dirs.snapshots = str(tmp_path)
-    root.common.engine.precision = "bfloat16"
+    root.common.engine.compute_dtype = "bfloat16"
     try:
         l1, w1 = run_fused(fresh_mnist())
         lt, wt = run_fused(fresh_mnist(), mesh=hybrid_mesh(),
                            tp_threshold=64)
     finally:
-        root.common.engine.precision = "float32"
+        root.common.engine.compute_dtype = "float32"
     np.testing.assert_allclose(l1, lt, rtol=5e-2)
     assert lt[-1] < lt[0] * 0.9, lt             # and it actually trains
     for name in w1:
@@ -376,8 +376,9 @@ def test_fused_stats_observability(tmp_path):
 
 
 def test_fused_remat_matches(tmp_path):
-    """jax.checkpoint rematerialization changes memory, not math: loss
-    curves and final weights match the non-remat fused run."""
+    """Units that ask for rematerialisation (``remat = True``, one
+    ``jax.checkpoint`` a unit) change memory, not math: loss curves and
+    final weights match the run that keeps its activations."""
     root.common.dirs.snapshots = str(tmp_path)
     lf, wf_ = run_fused(fresh_mnist())
 
@@ -387,9 +388,9 @@ def test_fused_remat_matches(tmp_path):
     losses2 = []
     wf2.decision.on_epoch_end.append(
         lambda d: losses2.append(d.epoch_metrics[2]["loss"]))
-    trainer = FusedTrainer(wf2, remat=True)
-    assert trainer.remat is True
-    trainer.run()
+    for f in wf2.forwards:
+        f.remat = True
+    FusedTrainer(wf2).run()
     np.testing.assert_allclose(lf, losses2, rtol=1e-5)
     for f in wf2.forwards:
         np.testing.assert_allclose(np.array(f.weights.map_read()),
@@ -739,6 +740,43 @@ def test_fused_deep_pipeline_respects_consumers(tmp_path):
     t4 = FusedTrainer(wf4)
     t4.pipeline_depth = 4
     assert not t4._deep_eligible()
+
+
+def test_fused_failstop_stops_at_the_stopping_state(tmp_path):
+    """A ``fail_iterations`` stop lands at an epoch's tail: the stopping
+    epoch's tail update is not adopted and the loader stands where the
+    unit engine's stands — losses, weights, steps and loader state at the
+    stop equal the unit path's."""
+    from znicz_tpu.parallel.fused import FusedTrainer
+
+    root.common.dirs.snapshots = str(tmp_path)
+    root.mnist.learning_rate = 1e-4        # barely moves -> fails-stop
+    try:
+        def build():
+            wf = fresh_mnist(max_epochs=50)
+            wf.decision.fail_iterations = 2
+            return wf
+
+        wfu = build()
+        lu, wu = run_unit(wfu)
+        assert len(lu) < 50, "did not stop early"
+        wff = build()
+        losses = []
+        wff.decision.on_epoch_end.append(
+            lambda d: losses.append(d.epoch_metrics[2]["loss"]))
+        trainer = FusedTrainer(wff)
+        trainer.run()
+        np.testing.assert_allclose(lu, losses, rtol=1e-4)
+        for f in wff.forwards:
+            np.testing.assert_allclose(
+                np.array(f.weights.map_read()), wu[f.name], rtol=2e-3,
+                atol=2e-5, err_msg=f.name)
+        assert trainer.steps_done == len(lu) * 5
+        assert wfu.loader.epoch_number == wff.loader.epoch_number
+        assert wfu.loader.samples_served == wff.loader.samples_served
+        assert wfu.decision.epoch_number == wff.decision.epoch_number
+    finally:
+        root.mnist.learning_rate = 0.1
 
 
 def test_fused_lr_schedule_matches_unit_path(tmp_path):

@@ -195,12 +195,6 @@ def test_plan_matches_conv_block_and_respects_flag():
         assert list(plan) == [0]
         spec = plan[0]
         assert (spec.span, spec.n, spec.pool) == (3, 5, (3, 3, 2, 2))
-        # the LRN-formulation experiment knobs keep their re-runs pure
-        root.common.engine.lrn_autodiff = True
-        try:
-            assert plan_fused_blocks(wf.forwards) == {}
-        finally:
-            root.common.engine.lrn_autodiff = False
     finally:
         root.common.engine.fused_elementwise = False
 
@@ -256,7 +250,7 @@ def test_trainer_fused_block_bf16_trains(tmp_path):
     f32 internal math — the loss trajectory stays in band with the
     composed bf16 path."""
     root.common.dirs.snapshots = str(tmp_path)
-    root.common.engine.precision = "bfloat16"
+    root.common.engine.compute_dtype = "bfloat16"
     try:
         l_off, _ = _run_fused(_tiny_alexstyle_workflow())
         root.common.engine.fused_elementwise = True
@@ -265,7 +259,7 @@ def test_trainer_fused_block_bf16_trains(tmp_path):
         finally:
             root.common.engine.fused_elementwise = False
     finally:
-        root.common.engine.precision = "float32"
+        root.common.engine.compute_dtype = "float32"
     np.testing.assert_allclose(l_off, l_on, rtol=5e-2)
     assert l_on[-1] < l_on[0], l_on
 

@@ -361,13 +361,12 @@ def test_trainer_fused_tail_bf16_compute_dtype(tmp_path):
         with pytest.raises(ValueError, match="compute_dtype"):
             FusedTrainer(wf)
     finally:
-        root.common.engine.compute_dtype = None
+        root.common.engine.compute_dtype = "float32"
 
 
 def test_compute_dtype_bf16_mnist_convergence_band(tmp_path):
     """ISSUE 7 satellite: e2e f32 vs bf16-activations/f32-master parity
-    band on the MNIST MLP (CPU, lean) under the canonical knob; the
-    legacy ``precision`` spelling maps to the same path."""
+    band on the MNIST MLP (CPU, lean)."""
     from znicz_tpu.parallel.fused import FusedTrainer
 
     root.common.dirs.snapshots = str(tmp_path)
@@ -378,16 +377,9 @@ def test_compute_dtype_bf16_mnist_convergence_band(tmp_path):
         assert FusedTrainer(wf).compute_dtype == "bfloat16"
         l_bf16, _ = _run_fused(wf)               # same wf: build once
     finally:
-        root.common.engine.compute_dtype = None
+        root.common.engine.compute_dtype = "float32"
     np.testing.assert_allclose(l_f32, l_bf16, rtol=5e-2)
     assert l_bf16[-1] < l_bf16[0], l_bf16
-    # legacy alias resolves identically (compute_dtype unset); reading
-    # the dtype off a fresh trainer on the already-run wf is free
-    root.common.engine.precision = "bfloat16"
-    try:
-        assert FusedTrainer(wf).compute_dtype == "bfloat16"
-    finally:
-        root.common.engine.precision = "float32"
 
 
 # -- bf16 wire deltas vs the quarantine guard ----------------------------------
@@ -463,7 +455,7 @@ def test_staging_bf16_zero_recompiles(tmp_path):
         assert int(trainer._m_compiles.value) == compiles0
         assert trainer.jit_cache_sizes() == sizes0
     finally:
-        root.common.engine.compute_dtype = None
+        root.common.engine.compute_dtype = "float32"
 
 
 # -- XLA latency-hiding flags --------------------------------------------------

@@ -1,4 +1,4 @@
-"""Host ingest engine (loader/ingest.py — VERDICT r4 item 1): parallel
+"""Host ingest engine (loader/ingest.py): parallel
 decode must be BIT-IDENTICAL to serial decode, the prefetch cache must be
 bounded and actually hit (the staging queue stays non-empty in steady
 state), and the fused streaming run over an image-file source must train
@@ -220,66 +220,163 @@ def test_device_stager_contract():
     assert st.outstanding == 0
 
 
-def test_ingest_overlap_gate_lean():
-    """ISSUE 7 structural overlap gate, lean tier-1 version (the soak
-    below and ``bench.py --ingest`` run the full protocol): a fixed delay
+#: The injected decode delay is calibrated to the measured warm segment
+#: time (so the check is structural, not an absolute speed bet a shared
+#: host can lose), clamped to [floor, cap]; the training thread's
+#: staged-segment wait must stay under GATE_FRAC of the injected delay.
+INGEST_DELAY_FLOOR_S = 0.02
+INGEST_DELAY_CAP_S = 0.5
+INGEST_GATE_FRAC = 0.5
+
+
+def _build_ingest_workflow(delay_s, hidden, n_train, n_valid, mb,
+                           max_epochs):
+    """A host-staged streaming run (regime 3) whose decode path sleeps
+    ``delay_s`` per segment gather — the injected stall the double buffer
+    must absorb."""
+    import time
+
+    from znicz_tpu.core.mutable import Bool
+    from znicz_tpu.loader.streaming import HostArraySource
+    from znicz_tpu.standard_workflow import StandardWorkflow
+
+    class DelayedSource(HostArraySource):
+        """HostArraySource with a fixed sleep in the gather (decode)
+        path — sleep, not spin: the injected stall must be absorbable by
+        a thread that overlaps it, exactly like real PIL decode/IO."""
+
+        delay_s = 0.0
+
+        def gather(self, idx):
+            if self.delay_s:
+                time.sleep(self.delay_s)
+            return super().gather(idx)
+
+    prng.reset(1013)
+    rng = np.random.default_rng(7)
+    n = n_train + n_valid
+    data = (rng.random((n, 28, 28)) * 255).astype(np.uint8)
+    labels = rng.integers(0, 10, n).astype(np.int32)
+    src = DelayedSource(data, labels)
+    src.delay_s = float(delay_s)
+    gd = {"learning_rate": 0.01, "gradient_moment": 0.9}
+    layers = [
+        {"type": "all2all_strict_relu",
+         "->": {"output_sample_shape": hidden}, "<-": dict(gd)},
+        {"type": "softmax", "->": {"output_sample_shape": 10},
+         "<-": dict(gd)},
+    ]
+    wf = StandardWorkflow(
+        name="IngestOverlap",
+        loader=StreamingLoader(name="loader", source=src,
+                               minibatch_size=mb,
+                               class_lengths=[0, n_valid, n_train],
+                               device_budget_bytes=0),
+        layers=layers, loss_function="softmax",
+        decision_config={"max_epochs": max_epochs, "fail_iterations": 0})
+    wf.initialize(device=None)
+    wf.snapshotter.gate_skip = Bool(True)   # measure ingest, not IO
+    return wf
+
+
+def run_ingest_overlap(hidden, n_train, n_valid, mb, max_epochs):
+    """Calibrate the warm segment time with no delay, inject half of it
+    (clamped) into the decode path, and return the injected delay in ms
+    and the stager's statistics of the delayed run: the double buffer
+    absorbs the delay, so the training thread's per-segment staged wait
+    must stay well under it even though EVERY segment's assembly slept
+    that long on the stager's worker."""
+    from znicz_tpu.parallel.fused import FusedTrainer
+
+    tr = FusedTrainer(_build_ingest_workflow(0.0, hidden, n_train, n_valid,
+                                             mb, max_epochs=1))
+    tr.run()
+    warm_steps = max(tr.stats["warm_steps"], 1)
+    step_s = (tr.stats["warm_wall_s"] / warm_steps
+              if tr.stats["warm_wall_s"] > 0
+              else tr.stats["wall_s"] / max(tr.stats["train_steps"], 1))
+    delay_s = min(max(0.5 * step_s * max(tr.scan_chunk, 1),
+                      INGEST_DELAY_FLOOR_S), INGEST_DELAY_CAP_S)
+    tr2 = FusedTrainer(_build_ingest_workflow(delay_s, hidden, n_train,
+                                              n_valid, mb, max_epochs))
+    tr2.run()
+    assert tr2._stager is not None, "async staging did not engage"
+    return delay_s * 1e3, tr2._stager.stats()
+
+
+def check_ingest_overlap(delay_ms, st, max_epochs):
+    """The structural findings for one overlap run (empty = it holds):
+
+      - beyond the run's cold-start group, no dispatch group missed the
+        double buffer;
+      - the MEDIAN staged wait sits well under the injected delay — the
+        hot loop (train segments following train segments) absorbed it;
+      - waits near the delay are CONFINED to the per-epoch boundary
+        groups: each epoch's first assembly cannot start before the tail
+        is consumed (the lookahead must not advance past a tail — the
+        snapshot at an epoch boundary must record tail state; resume
+        parity), so one un-absorbed wait per epoch + the cold start is
+        the structural floor, and MORE than that means the overlap broke.
+    """
+    bad = []
+    if st["stage_hits"] < 1 or st["stage_misses"] > 1:
+        bad.append(f"dispatch groups missed the double buffer: "
+                   f"hits={st['stage_hits']} misses={st['stage_misses']}")
+    p50 = st["wait_ms_p50"]
+    if p50 is None or p50 > INGEST_GATE_FRAC * delay_ms:
+        bad.append(f"median staged wait {p50}ms is not well under the "
+                   f"injected {delay_ms}ms decode delay — the hot loop "
+                   "is not absorbing it")
+    big = [w for w in st["wait_ms_window"]
+           if w > INGEST_GATE_FRAC * delay_ms]
+    if len(big) > max_epochs + 1:
+        bad.append(f"{len(big)} staged waits exceeded "
+                   f"{INGEST_GATE_FRAC} x the delay ({big}) — more than "
+                   f"the {max_epochs} epoch-boundary groups + cold "
+                   "start; steady-state segments are stalling")
+    return bad
+
+
+@pytest.mark.parametrize("size", [
+    dict(hidden=128, n_train=160, n_valid=32, mb=32, max_epochs=2),
+    pytest.param(dict(hidden=2048, n_train=1024, n_valid=128, mb=64,
+                      max_epochs=3), marks=pytest.mark.slow),
+], ids=["lean", "soak"])
+def test_ingest_overlap_gate(size):
+    """The structural ingest/compute overlap check: a fixed delay
     injected into the decode path is absorbed by the double buffer — the
     training thread's staged-segment waits stay well under it except at
     the structurally-unhidable epoch boundaries (see
-    bench.check_ingest_overlap)."""
-    from bench import check_ingest_overlap, run_ingest_overlap
-
-    vals = run_ingest_overlap(hidden=128, n_train=160, n_valid=32,
-                              mb=32, max_epochs=2, with_off=False)
-    bad = check_ingest_overlap(vals, max_epochs=2)
-    assert not bad, (bad, vals)
+    ``check_ingest_overlap``)."""
+    delay_ms, st = run_ingest_overlap(**size)
+    bad = check_ingest_overlap(delay_ms, st, size["max_epochs"])
+    assert not bad, (bad, delay_ms, st)
     # the injected delay really was paid by SOMEONE (the stager worker):
     # every staged segment's assembly slept it
-    assert vals["stager"]["h2d_ms_p50"] >= vals["delay_ms"]
-
-
-@pytest.mark.slow
-def test_ingest_overlap_gate_soak():
-    """The full --ingest protocol (bench-sized model, three epochs, the
-    async-off context run included): gate must hold and async-on must
-    not be slower than async-off."""
-    from bench import check_ingest_overlap, run_ingest_overlap
-
-    vals = run_ingest_overlap(max_epochs=3)
-    bad = check_ingest_overlap(vals, max_epochs=3)
-    assert not bad, (bad, vals)
-    assert vals["on_vs_off"] is not None and vals["on_vs_off"] > 0.9, vals
+    assert st["h2d_ms_p50"] >= delay_ms
 
 
 def test_measure_decode_rate(tmp_path):
-    """The roofline's third term: measured, finite, and the pool is not
-    CATASTROPHICALLY slower than serial (the bench records both).
-
-    DE-FLAKE + CALIBRATION (r10): the old single-shot ``pooled >= 0.6 *
-    serial`` band assumed parallel headroom this host does not reliably
-    have — on a 2-cpu box whose cgroup share swings minute to minute, a
-    4-worker pool legitimately measures down to ~0.3x serial under an
-    external load burst (oversubscription, not a pool bug), and wall
-    time cannot distinguish that from a real regression, so the band
-    flaked in-suite.  Now: workers match the host's cpu count, pairs
-    are interleaved best-of with early exit (PR-4/PR-5 doctrine), and
-    the band is 0.25x — wide enough to sit above the oversubscription
-    floor, while the regression CLASS this guard exists for (the pool
-    deadlocking, or rebuilding per item — 10x-100x collapses) still
-    fails every round.  The pool's true speedup on capable hosts is
-    recorded by ``bench.py --stream``'s decode term."""
-    import os
-
+    """The roofline's third term is measured and finite, serial and
+    pooled, and the pooled measurement is the pool's work: every row of
+    both passes decoded exactly once, by one executor.  What this guards
+    is the pool rebuilding per item or decoding rows twice; how much
+    faster a pool is than a loop is the host's business (on a shared
+    two-to-eight-core box it read 0.14x to 0.9x of serial within one
+    day), so no ratio of the two rates is asserted."""
     base = _tree(tmp_path, n_per_class=16, size=(32, 32))
     src = class_dir_source(base, target_shape=(24, 24), workers=0)
-    n_workers = max(2, min(4, os.cpu_count() or 1))
-    serial = pooled = 0.0
-    for _ in range(3):
-        serial = max(serial, measure_decode_rate(src, n=32))
-        pooled = max(pooled, measure_decode_rate(src, n=32,
-                                                 workers=n_workers))
-        assert np.isfinite(serial) and serial > 0
-        assert np.isfinite(pooled) and pooled > 0
-        if pooled >= 0.25 * serial:
-            break
-    assert pooled >= 0.25 * serial, (serial, pooled)
+    serial = measure_decode_rate(src, n=32)
+    assert np.isfinite(serial) and serial > 0
+    assert src.ingest_stats is None             # no pool was built
+    pooled_src = src.with_workers(4)
+    pooled = measure_decode_rate(pooled_src, n=32)
+    assert np.isfinite(pooled) and pooled > 0
+    executor = pooled_src.pool()._ex
+    assert executor is not None
+    assert pooled_src.ingest_stats == {
+        "prefetch_hits": 0, "decode_misses": 64, "rows_decoded": 64,
+        "rows_prefetched": 0}
+    measure_decode_rate(pooled_src, n=32)
+    assert pooled_src.pool()._ex is executor
+    assert pooled_src.ingest_stats["rows_decoded"] == 128

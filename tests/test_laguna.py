@@ -238,17 +238,15 @@ def test_adamw_update_is_the_reference_rule(step, decay):
 
 
 @pytest.fixture()
-def tiny_job(tmp_path):
+def tiny_job(tmp_path, restore_root):
     """The tiny preset built as the benchmark's driver builds the cell."""
-    saved = root.to_dict()
     cell = spec.Cell(spec.load(), "laguna-train-8k")
 
     def build(seed=11):
         return driver.build(cell, seed, True)
 
     root.common.dirs.snapshots = str(tmp_path)
-    yield cell, build
-    root.update(saved)
+    return cell, build
 
 
 def test_system_matches_reference_logits_loss_gradient_and_adamw(tiny_job):
@@ -336,23 +334,20 @@ def test_decay_skips_norms_gates_and_the_router(tiny_job):
     assert int(new_s["step"]) == 1
 
 
-def test_the_sample_trains_through_the_launcher_and_counts(tmp_path):
+def test_the_sample_trains_through_the_launcher_and_counts(tmp_path,
+                                                           restore_root):
     """``python -m znicz_tpu <sample>``'s path: StandardWorkflow ->
     FusedTrainer.run with loader, Decision and snapshotter; tokens and the
     expert layers' counters land in ``fused_stats``; nothing recompiles."""
     from znicz_tpu.launcher import Launcher
 
-    saved = root.to_dict()
-    try:
-        launcher = Launcher([
-            os.path.join(REPO, "znicz_tpu", "samples", "laguna.py"),
-            "--backend", "cpu", "root.laguna.preset=tiny",
-            "root.laguna.decision.max_epochs=3",
-            f"root.common.dirs.snapshots={tmp_path}"])
-        assert launcher.run() == 0
-        wf = launcher.workflow
-    finally:
-        root.update(saved)
+    launcher = Launcher([
+        os.path.join(REPO, "znicz_tpu", "samples", "laguna.py"),
+        "--backend", "cpu", "root.laguna.preset=tiny",
+        "root.laguna.decision.max_epochs=3",
+        f"root.common.dirs.snapshots={tmp_path}"])
+    assert launcher.run() == 0
+    wf = launcher.workflow
     stats = wf.fused_stats
     history = wf.decision.epoch_history
     assert len(history) == 3

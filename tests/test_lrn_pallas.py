@@ -1,9 +1,9 @@
-"""Pallas LRN kernel vs the jnp oracle (znicz_tpu/lrn.py): forward and
-gradient agreement (interpreter mode on the CPU test platform)."""
+"""The LRN arithmetic of ops/lrn_pallas.py (the windowed channel sum and
+``s ** -beta`` shared with znicz_tpu/lrn.py) inside the fused conv-block
+kernel vs the jnp oracle: forward and gradient agreement (interpreter mode
+on the CPU test platform)."""
 
 import numpy as np
-
-from znicz_tpu.core.config import root
 
 
 def _jnp_lrn(x, n=5, alpha=1e-4, beta=0.75, k=2.0):
@@ -18,52 +18,11 @@ def _jnp_lrn(x, n=5, alpha=1e-4, beta=0.75, k=2.0):
     return x / jnp.power(k + alpha * acc, beta)
 
 
-def test_pallas_lrn_forward_and_grad_match_oracle():
-    import jax
-    import jax.numpy as jnp
-
-    from znicz_tpu.ops.lrn_pallas import lrn
-
-    rng = np.random.default_rng(3)
-    x = jnp.asarray(rng.normal(size=(4, 9, 9, 96)).astype(np.float32) * 2)
-
-    y = lrn(x)
-    y_ref = _jnp_lrn(x)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
-                               rtol=1e-5, atol=1e-6)
-
-    # gradient: custom_vjp vs autodiff through the oracle
-    cot = jnp.asarray(rng.normal(size=x.shape).astype(np.float32))
-    g = jax.grad(lambda t: jnp.sum(lrn(t) * cot))(x)
-    g_ref = jax.grad(lambda t: jnp.sum(_jnp_lrn(t) * cot))(x)
-    np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref),
-                               rtol=2e-4, atol=2e-6)
-
-
-def test_pallas_lrn_flag_routes_unit(tmp_path):
-    """root.common.engine.pallas_lrn routes LRNormalizerForward.apply
-    through the kernel; output matches the default path."""
-    import jax.numpy as jnp
-
-    from znicz_tpu.lrn import LRNormalizerForward
-
-    rng = np.random.default_rng(5)
-    x = jnp.asarray(rng.normal(size=(2, 5, 5, 32)).astype(np.float32))
-    u = LRNormalizerForward(name="lrn")
-    base = np.asarray(u.apply({}, x))
-    root.common.engine.pallas_lrn = True
-    try:
-        fast = np.asarray(u.apply({}, x))
-    finally:
-        root.common.engine.pallas_lrn = False
-    np.testing.assert_allclose(fast, base, rtol=1e-5, atol=1e-6)
-
-
 def test_fused_block_lrn_stage_matches_oracle():
     """The single-pass conv-block kernel (pallas_fused_block) degenerates
     to relu -> LRN under a 1x1/s1 identity pool — its LRN stage must match
-    the same oracle the standalone Pallas LRN kernel is held to, forward
-    AND gradient (the fused bwd's closed-form LRN term)."""
+    the shifted-slices oracle, forward AND gradient (the fused bwd's
+    closed-form LRN term)."""
     import jax
     import jax.numpy as jnp
 
@@ -86,17 +45,3 @@ def test_fused_block_lrn_stage_matches_oracle():
     g_ref = jax.grad(lambda t: jnp.sum(oracle(t) * cot))(x)
     np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref),
                                rtol=2e-4, atol=2e-6)
-
-
-def test_pallas_lrn_odd_channel_and_row_counts():
-    """Row padding (rows not a multiple of TILE_R) and non-128 channel
-    widths round-trip correctly."""
-    import jax.numpy as jnp
-
-    from znicz_tpu.ops.lrn_pallas import lrn
-
-    rng = np.random.default_rng(7)
-    x = jnp.asarray(rng.normal(size=(3, 7, 96)).astype(np.float32))
-    np.testing.assert_allclose(np.asarray(lrn(x)),
-                               np.asarray(_jnp_lrn(x)),
-                               rtol=1e-5, atol=1e-6)

@@ -37,6 +37,36 @@ def test_master_slave_trains(tmp_path):
     slaves = [Client(_make_workflow(tmp_path / f"s{i}"), endpoint=endpoint,
                      slave_id=f"slave{i}") for i in range(2)]
 
+    # Which slave's delta lands on which weights is otherwise the thread
+    # scheduler's choice, and the 60-sample validation error this test
+    # ends on moved with it (61.7 % on a quiet host, 70.0 % under six
+    # workers).  So the master hands out jobs in turns, decided by what
+    # it has SEEN — a job goes out only when none is out and it is that
+    # slave's turn, the turn passes when the job's update arrives — and
+    # every delta is computed on the weights the previous one left: one
+    # trajectory, whatever the host's load.
+    order, turn, gone = ["slave0", "slave1"], {"next": 0, "out": None}, set()
+    handle = server._handle
+
+    def in_turn(req):
+        cmd, sid = req.get("cmd"), req.get("id")
+        if cmd == "job" and sid in order:
+            other = order[1 - order.index(sid)]
+            if turn["out"] is not None or (
+                    sid != order[turn["next"]] and other not in gone):
+                return {"wait": True}
+        rep = handle(req)
+        if cmd == "job" and sid in order:
+            if rep.get("done"):
+                gone.add(sid)
+            elif "job" in rep:
+                turn["out"] = sid
+        elif cmd == "update" and sid == turn["out"]:
+            turn["out"], turn["next"] = None, 1 - order.index(sid)
+        return rep
+
+    server._handle = in_turn
+
     errors = []
 
     def worker(s):
@@ -58,8 +88,8 @@ def test_master_slave_trains(tmp_path):
 
     dec = master_wf.decision
     assert bool(dec.complete)
-    # async mode: updates arrive out of order, so epoch attribution is
-    # best-effort (reference semantics) — account by job counts instead
+    # epoch attribution is best-effort in this mode (reference
+    # semantics) — account by job counts instead
     assert server.jobs_done >= 3 * 6 - len(slaves)   # 3 epochs x 6 batches
     assert server.jobs_by_slave.get("slave0", 0) > 0
     assert server.jobs_by_slave.get("slave1", 0) > 0

@@ -182,6 +182,39 @@ def test_every_engine_config_read_is_declared_in_defaults():
     assert tables["serving"][0] | tables["serving"][1] == flat(DEFAULTS)
 
 
+def test_every_declared_engine_knob_has_a_reader():
+    """The converse of the check above: a knob ENGINE_DEFAULTS declares
+    and nothing in the package touches is an option that selects
+    nothing.  Run the same analyzer with an EMPTY engine table, so that
+    every access it resolves is reported by name, over every module but
+    the one that holds the table."""
+    import re
+
+    from znicz_tpu.core.config import ENGINE_DEFAULTS
+
+    tables = dict(load_declared_tables(PKG))
+    tables["engine"] = (set(), set())
+    checker = ConfigKnobChecker(PKG, tables=tables)
+    touched = set()
+    for path in sorted(PKG.rglob("*.py")):
+        rel = str(path.relative_to(PKG))
+        if rel == "core/config.py":
+            continue
+        for f in checker.check(Module(path, rel, path.read_text())):
+            m = re.search(r"'root\.common\.engine\.([\w.]+)'", f.message)
+            if m:
+                touched.add(m.group(1))
+
+    def leaves(d, prefix=""):
+        return {leaf for k, v in d.items() for leaf in (
+            leaves(v, prefix + k + ".") if isinstance(v, dict)
+            else {prefix + k})}
+
+    declared = leaves(ENGINE_DEFAULTS)
+    assert len(declared) == 54
+    assert not declared - touched, sorted(declared - touched)
+
+
 def test_engine_config_lint_catches_the_regression_class():
     checker = ConfigKnobChecker(PKG)
     assert _check(checker, """

@@ -1,33 +1,30 @@
-"""Throughput regression guards that run on the CPU backend (VERDICT r4
-items 7 and 8): the staging MACHINERY must be compute-bound where the
-link is a memcpy, and always-on confusion must stay effectively free.
-Timing-based, so every assertion uses median-of-windows and a margin far
-wider than the effect a real regression would produce."""
+"""Regression guards of the training hot path that run on the CPU
+backend.  What a CPU can show is counted, not timed: the staging
+machinery ships each dispatch's samples exactly once, and always-on
+confusion crosses to the host once a class and epoch (what either costs
+on the chip: PERF.md)."""
 
 import time
 
 import numpy as np
-import pytest
 
 from znicz_tpu.core import prng
 from znicz_tpu.core.config import root
 
 
-def _warm_rate(budget):
+def _u8_mnist(budget):
+    """A u8 MNIST workflow whose set is resident (``budget`` large) or
+    host-staged a segment at a time (``budget`` 0)."""
     from tests.test_streaming import _StreamingMnistLoader
-    from znicz_tpu.parallel.fused import FusedTrainer
     from znicz_tpu.samples import mnist
 
     prng.reset(1013)
-    root.mnist.loader.n_train = 2048
+    root.mnist.loader.n_train = 1024
     root.mnist.loader.n_valid = 256
     root.mnist.loader.n_test = 0
-    root.mnist.loader.minibatch_size = 256
-    root.mnist.decision.max_epochs = 4
-    # wide enough that compute dominates: the guard measures the staging
-    # MACHINERY's share at a realistic compute:transfer ratio (AlexNet's
-    # is far higher still), not a degenerate all-overhead microbenchmark
-    root.mnist.layers = [512, 10]
+    root.mnist.loader.minibatch_size = 128
+    root.mnist.decision.max_epochs = 3
+    root.mnist.layers = [64, 10]
     _StreamingMnistLoader.u8 = True
     _StreamingMnistLoader.budget = budget
     orig = mnist.MnistLoader
@@ -38,141 +35,149 @@ def _warm_rate(budget):
         mnist.MnistLoader = orig
         root.mnist.layers = [100, 10]
     wf.initialize(device=None)
-    trainer = FusedTrainer(wf)
+    return wf
+
+
+def test_staged_run_ships_each_segment_once_and_matches_resident():
+    """The staging machinery (host row gather, per-segment device_put,
+    the staged-direct scan) moves where the samples live and nothing
+    else: the staged run ends on the resident run's weights bit for bit,
+    every dispatch's minibatches are staged exactly once, and a segment
+    crosses to the device in one ``device_put`` a tensor (samples,
+    labels)."""
+    from znicz_tpu.parallel.fused import FusedTrainer
+
+    def weights(wf):
+        return {f.name: np.array(f.weights.map_read()) for f in wf.forwards}
+
+    wf_r = _u8_mnist(budget=1 << 30)
+    FusedTrainer(wf_r).run()
+    assert wf_r.loader.device_resident
+
+    wf_s = _u8_mnist(budget=0)
+    trainer = FusedTrainer(wf_s)
+    assert trainer.staging and not wf_s.loader.device_resident
+    segments, puts = [], []
+    stage = trainer._stage_direct
+
+    def counting_stage(idx_rows, put):
+        segments.append(len(idx_rows))
+
+        def counting_put(x):
+            puts.append(np.shape(x))
+            return put(x)
+
+        return stage(idx_rows, counting_put)
+
+    trainer._stage_direct = counting_stage
     trainer.run()
-    assert bool(wf.decision.complete)
-    return trainer.stats["warm_img_per_sec"], wf
+    assert bool(wf_s.decision.complete)
+    want, got = weights(wf_r), weights(wf_s)
+    for name in want:
+        np.testing.assert_array_equal(got[name], want[name], err_msg=name)
+    # an epoch is a 7-step train scan, its tail step and a 2-step
+    # validation scan: each staged exactly once
+    assert sorted(segments) == [1] * 3 + [2] * 3 + [7] * 3, segments
+    assert len(puts) == 2 * len(segments), (len(puts), len(segments))
 
 
-def test_staging_machinery_compute_bound_on_cpu():
-    """VERDICT r4 item 7: where H2D is a memcpy (the CPU backend), the
-    staging machinery itself — host row gather, per-segment device_put,
-    the staged-direct scan — must not cost more than a sliver of the
-    step rate: staged throughput >= 80% of u8-resident throughput (the
-    true overhead measures ~<10%; the margin absorbs CI timer noise).
-    The bit-parity half of the contract is tests/test_streaming.py."""
-    _warm_rate(budget=1 << 30)                    # compile warm
-    _warm_rate(budget=0)
-    resident_rate = max(_warm_rate(budget=1 << 30)[0] for _ in range(2))
-    staged_rate = 0.0
-    for _ in range(2):
-        r, wf = _warm_rate(budget=0)
-        assert not wf.loader.device_resident      # really staged
-        staged_rate = max(staged_rate, r)
-    assert staged_rate >= 0.8 * resident_rate, \
-        (staged_rate, resident_rate)
+def test_confusion_is_summed_on_device_and_fed_once_an_epoch():
+    """The fused path's always-on confusion is a device-side scan-carry
+    accumulator.  The regression class this guards against is a per-step
+    or per-segment host transfer of the (C, C) matrix (28 MB a segment at
+    1,000 classes).  Counted: nothing the steps pull has a C-sized axis,
+    and the Decision is handed ONE (C, C) device array a class and epoch,
+    which holds every sample of that epoch — the segments' sums were
+    added on the device."""
+    import jax
 
-
-def test_confusion_always_on_costs_under_margin():
-    """VERDICT r4 item 8: the fused path's always-on confusion is a
-    device-side scan-carry accumulator with a once-per-epoch transfer.
-    CALIBRATION of this CPU guard: on a 1-core CPU backend the wide
-    (1000,1000) accumulator adds a real 15-30% to a small-MLP step —
-    unlike on TPU, where the r4/r5 headline carries it at per-mille cost
-    (the bench's job to watch).  What this guard exists to catch is the
-    REGRESSION CLASS: re-introducing a per-step host transfer of the
-    (C,C) matrix, which costs MULTIPLES (the r3 measurement: 28 MB per
-    segment).  So the assertion is a 2x band, robustly above the
-    platform-noise floor and far below any real regression."""
+    from znicz_tpu.loader.base import TRAIN, VALID
     from znicz_tpu.parallel.fused import FusedTrainer
     from znicz_tpu.samples import mnist
 
-    n_classes = 1000   # wide head: the (C,C) accumulator is 1M int32s
+    n_classes, epochs, n_train, n_valid = 1000, 3, 1024, 128
+    prng.reset(1013)
+    root.mnist.loader.n_train = n_train
+    root.mnist.loader.n_valid = n_valid
+    root.mnist.loader.n_test = 0
+    root.mnist.loader.minibatch_size = 128
+    root.mnist.decision.max_epochs = epochs
+    root.mnist.layers = [64, n_classes]     # 10-class labels, WIDER head
+    try:
+        wf = mnist.MnistWorkflow()
+    finally:
+        root.mnist.layers = [100, 10]
+    wf.initialize(device=None)
+    trainer = FusedTrainer(wf)
+    assert trainer.compute_confusion and trainer._n_confusion() == n_classes
 
-    def run_once(confusion_on):
-        prng.reset(1013)
-        root.mnist.loader.n_train = 1024
-        root.mnist.loader.n_valid = 128
-        root.mnist.loader.n_test = 0
-        root.mnist.loader.minibatch_size = 128
-        root.mnist.decision.max_epochs = 3
-        # hidden width sized so compute dominates the way it does on any
-        # real model: the guard asserts the accumulator's RELATIVE cost
-        # (a 1000^2 int32 add per step is ~fixed work; against a
-        # 100-wide MLP on CPU it is ~30% — against this one, percents,
-        # and against the AlexNet bench head, per-mille)
-        root.mnist.layers = [512, n_classes]
-        try:
-            wf = mnist.MnistWorkflow()
-        finally:
-            root.mnist.layers = [100, 10]
-        # the sample draws 10-class labels; the head is just WIDER
-        wf.initialize(device=None)
-        if not confusion_on:
-            wf.evaluator.compute_confusion = False
-            wf.evaluator.confusion_explicit = True
-        trainer = FusedTrainer(wf)
-        trainer.run()
-        return trainer.stats["warm_img_per_sec"], trainer
+    pulled, fed = [], []
+    sync, feed = trainer._sync, trainer._feed_decision
 
-    # compile + cache warm for both variants, then measured runs.
-    # BEST-of-3 warm rates: suite-context load spikes only ever slow a
-    # run down, so the max approximates each variant's clean capability —
-    # exactly the question (a regression re-introducing a per-step
-    # transfer suppresses the best case too, by multiples).
-    run_once(True)
-    run_once(False)
-    on = max(run_once(True)[0] for _ in range(3))
-    off = max(run_once(False)[0] for _ in range(3))
-    # sanity: the on-variant really collected a wide confusion
-    _, tr = run_once(True)
-    assert tr.compute_confusion and tr._n_confusion() == n_classes
-    assert on >= off * 0.5, (on, off)
+    def recording_sync(*values):
+        out = sync(*values)
+        pulled.extend(np.shape(v) for v in jax.tree_util.tree_leaves(out))
+        return out
+
+    def recording_feed(mb, metrics):
+        conf = metrics[2]
+        if conf is not None:
+            assert isinstance(conf, jax.Array), type(conf)
+            fed.append((mb["class"], mb["epoch_number"], conf.shape,
+                        int(conf.sum())))
+        return feed(mb, metrics)
+
+    trainer._sync, trainer._feed_decision = recording_sync, recording_feed
+    trainer.run()
+    assert pulled and not [s for s in pulled if n_classes in s], pulled
+    cc = (n_classes, n_classes)
+    assert [f for f in fed if f[0] == TRAIN] == [
+        (TRAIN, e, cc, n_train) for e in range(epochs)], fed
+    assert [f for f in fed if f[0] == VALID] == [
+        (VALID, e, cc, n_valid) for e in range(epochs)], fed
 
 
 def test_anchor_bands_enforced():
-    """VERDICT r4 item 6: the seeded sample anchors are tolerance BANDS a
-    math change cannot silently cross.  Unit half: check_anchor flags
-    out-of-band finals (e.g. the r3 pow-LRN CIFAR error, 41.25%, is
-    outside the r4 rsqrt band 44.0 +/- 1.5 — re-running the old math
-    FAILS --samples until BASELINE.md justifies a re-center).  E2e half:
-    the cheapest real anchor (config 0, MNIST) still lands in band."""
-    import bench
+    """The seeded sample anchors are tolerance BANDS a math change cannot
+    silently cross.  Unit half: check_anchor flags out-of-band finals
+    (the CIFAR error an older LRN formulation ended at, 41.25%, is
+    outside the band 44.0 +/- 1.5).  E2e half: the cheapest real anchor
+    (config 0, MNIST) still lands in band."""
+    from znicz_tpu.samples import anchors
 
     # the unit half
-    assert bench.check_anchor(1, {"final_train_loss": 0.9501,
-                                  "valid_err_pct": 44.0}) == []
-    bad = bench.check_anchor(1, {"final_train_loss": 0.9499,
-                                 "valid_err_pct": 41.25})
+    assert anchors.check_anchor(1, {"final_train_loss": 0.9501,
+                                    "valid_err_pct": 44.0}) == []
+    bad = anchors.check_anchor(1, {"final_train_loss": 0.9499,
+                                   "valid_err_pct": 41.25})
     assert [f["metric"] for f in bad] == ["valid_err_pct"]
 
-    # the e2e half: run BASELINE config 0 exactly like --samples does
-    # (restore the sample's defaults first — sibling tests shrink them)
+    # the e2e half: BASELINE config 0 at the sample's defaults (restore
+    # them first — sibling tests shrink them)
     root.mnist.loader.n_train = 4000
     root.mnist.loader.n_valid = 800
     root.mnist.loader.n_test = 0
     root.mnist.loader.minibatch_size = 60
     root.mnist.decision.max_epochs = 5
     root.mnist.layers = [100, 10]
-    prng.reset(1013)
-    from znicz_tpu.samples import mnist
-
-    wf = mnist.run()
-    vals = bench._gd_finals(wf.decision)
-    assert bench.check_anchor(0, vals) == [], vals
+    vals, bad = anchors.measure(0)
+    assert bad == [], vals
 
 
 def test_async_snapshot_does_not_stall_training_cpu():
-    """VERDICT r4 item 4 gate: every-epoch snapshots (interval=1) must
-    bill their cost to the background writer, not the training thread.
+    """Every-epoch snapshots (interval=1) must bill their cost to the
+    background writer, not the training thread.
 
-    RESTRUCTURED (VERDICT r5 next-item 6; the old form compared two
-    wall-clock throughputs, gated vs active, and flaked in-suite
-    because this box's cgroup CPU share swings 4x minute-to-minute —
-    any band wide enough to absorb that swing was too wide to mean
-    anything).  The property is WHERE the save cost lands, so test it
-    structurally: inject a deliberate DELAY into the disk-write path
-    and assert each ``save_async`` call made by the training loop
-    returns in a small fraction of it.  A regression of the guarded
-    class — the per-epoch writeback+pickle made synchronous again —
-    bills >= DELAY to every call and fails by multiples, while host
-    load cannot fake a 0.6 s stall inside a lock-append-notify.  The
-    writes still really happen (async_saves_written through the slowed
-    writer), so the worker handoff is exercised end to end, and the
-    run's decision loop overlaps compute with the artificially slow
-    writer exactly as on the TPU host, where the device->host pull is
-    ~60 s of shared-link occupancy (BASELINE.md carries that measured
-    analysis)."""
+    The property is WHERE the save cost lands, so it is tested
+    structurally (two wall-clock throughputs, gated against active,
+    flaked with the host's load): inject a deliberate DELAY into the
+    disk-write path and assert each ``save_async`` call made by the
+    training loop returns in a small fraction of it.  A regression of
+    the guarded class — the per-epoch writeback+pickle made synchronous
+    again — bills >= DELAY to every call and fails by multiples, while
+    host load cannot fake a 0.6 s stall inside a lock-append-notify.
+    The writes still really happen (async_saves_written through the
+    slowed writer), so the worker handoff is exercised end to end."""
     import tempfile
 
     from znicz_tpu.parallel.fused import FusedTrainer
@@ -226,8 +231,8 @@ def test_async_snapshot_does_not_stall_training_cpu():
 
 def test_bf16_master_weights_variant_trains():
     """The opt-in bf16-MASTER-weights traffic lever
-    (root.common.engine.master_dtype — a labeled bench variant, never
-    the headline/anchors): params are stored bf16, update math stays
+    (root.common.engine.master_dtype — an undecided lever, never a
+    cell's default or the anchors'): params are stored bf16, update math stays
     f32, and training still converges to the f32 run's neighborhood."""
     from znicz_tpu.parallel.fused import FusedTrainer
 

@@ -149,50 +149,3 @@ def test_stochastic_pooling_mask_reuse_and_eval_mode():
     wsum = win.sum()
     np.testing.assert_allclose(a[0, 0, 0, 0], float((win * win).sum() / wsum),
                                rtol=1e-5)
-
-
-def test_masked_maxpool_bwd_matches_sas_when_unique():
-    """The scatter-free masked max-pool backward (opt-in pool_bwd="mask")
-    must equal XLA's select_and_scatter gradient EXACTLY whenever window
-    maxima are unique, and conserve gradient mass under ties (dy split
-    among tied maxima — documented semantic difference)."""
-    import jax
-    import jax.numpy as jnp
-
-    from znicz_tpu.pooling import _masked_maxpool, pool_output_hw
-
-    rng = np.random.default_rng(5)
-    ky = kx = 3
-    sy = sx = 2
-    # unique maxima: continuous random values, ties have measure zero
-    x = jnp.asarray(rng.standard_normal((2, 13, 13, 4)), jnp.float32)
-    f_mask = _masked_maxpool(ky, kx, sy, sx)
-
-    def f_sas(x):
-        oh, ow = pool_output_hw(x.shape[1], x.shape[2], ky, kx, (sy, sx))
-        ph, pw = (oh - 1) * sy + ky, (ow - 1) * sx + kx
-        return jax.lax.reduce_window(
-            x, x.dtype.type(-np.inf), jax.lax.max,
-            window_dimensions=(1, ky, kx, 1),
-            window_strides=(1, sy, sx, 1),
-            padding=((0, 0), (0, ph - x.shape[1]), (0, pw - x.shape[2]),
-                     (0, 0)))
-
-    np.testing.assert_array_equal(np.asarray(f_mask(x)),
-                                  np.asarray(f_sas(x)))
-    dy = jnp.asarray(rng.standard_normal(f_sas(x).shape), jnp.float32)
-
-    def loss(f):
-        return lambda x: jnp.vdot(f(x), dy)
-
-    g_mask = np.asarray(jax.grad(loss(f_mask))(x))
-    g_sas = np.asarray(jax.grad(loss(f_sas))(x))
-    np.testing.assert_allclose(g_mask, g_sas, rtol=1e-6, atol=1e-6)
-
-    # ties (ReLU-like zeros): mass conserved per window even when split
-    xt = jnp.zeros((1, 5, 5, 1), jnp.float32)
-    dyt = jnp.asarray(rng.standard_normal(f_mask(xt).shape), jnp.float32)
-    g_t = np.asarray(jax.grad(lambda x: jnp.vdot(f_mask(x), dyt))(xt))
-    # every window's dy mass lands somewhere in dx
-    np.testing.assert_allclose(g_t.sum(), float(np.asarray(dyt).sum()),
-                               rtol=1e-5)

@@ -710,6 +710,62 @@ def test_fused_slaves_through_relay_with_lr_schedule(tmp_path):
 
 
 @pytest.mark.slow
+def test_tree_cuts_bytes_and_decodes_into_the_master(tmp_path):
+    """The O(slaves) -> O(fanout) flip at its smallest size: the same
+    seeded job with four slaves on the master (a star) and with the four
+    behind ONE relay.  The relay sums its children's deltas and forwards
+    one, and fetches their jobs in batches under one params broadcast, so
+    the master takes in fewer bytes and decodes fewer messages."""
+    from znicz_tpu.client import Client
+    from znicz_tpu.parallel.relay import Relay
+    from znicz_tpu.server import Server
+
+    def fleet(tag, master_ep, slave_ep):
+        wf = _make_workflow(tmp_path / f"{tag}-m")
+        server = Server(wf, endpoint=master_ep, job_timeout=60.0)
+        slaves = [Client(_make_workflow(tmp_path / f"{tag}-s{i}"),
+                         endpoint=slave_ep, slave_id=f"{tag}{i}")
+                  for i in range(4)]
+        errors = []
+
+        def worker(s):
+            try:
+                s.run()
+            except BaseException as e:
+                errors.append((s.slave_id, repr(e)))
+                raise
+
+        threads = [threading.Thread(target=worker, args=(s,), daemon=True)
+                   for s in slaves]
+        for t in threads:
+            t.start()
+        server.serve()
+        for t in threads:
+            t.join(timeout=60)
+        assert not errors, errors
+        assert not any(t.is_alive() for t in threads)
+        assert bool(wf.decision.complete)
+        return server
+
+    star = fleet("star", "tcp://127.0.0.1:17670", "tcp://127.0.0.1:17670")
+    relay = Relay("tcp://127.0.0.1:17671", "tcp://127.0.0.1:17672",
+                  relay_id="cut-relay").start()
+    try:
+        tree = fleet("tree", "tcp://127.0.0.1:17671",
+                     "tcp://127.0.0.1:17672")
+    finally:
+        relay.stop()
+    assert star.aggregated_updates == 0 and tree.aggregated_updates >= 1
+    assert tree.jobs_done == sum(tree.jobs_by_slave.values())
+    per_job = [(int(s.bytes_in) / s.jobs_done,
+                int(s.codec.messages_in) / s.jobs_done)
+               for s in (star, tree)]
+    (star_bytes, star_msgs), (tree_bytes, tree_msgs) = per_job
+    assert tree_bytes < 0.8 * star_bytes, per_job
+    assert tree_msgs < 0.8 * star_msgs, per_job
+
+
+@pytest.mark.slow
 def test_two_level_tree_chaos_soak(tmp_path):
     """Everything at once on a 2-level tree: seeded ChaosProxy
     drop/corrupt/dup/delay on the mid-relay -> master link (the relay's

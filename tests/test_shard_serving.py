@@ -24,9 +24,9 @@ import pytest
 from znicz_tpu.core import prng
 from znicz_tpu.core.config import root
 
-#: cross-layout parity band, relative to max|y| per rung (see
-#: bench.py SHARD_PARITY_REL: measured ~1e-6 reduction-order noise on
-#: this stack; a real math divergence lands orders of magnitude higher)
+#: cross-layout parity band, relative to max|y| per rung (measured
+#: ~1e-6 reduction-order noise on this stack; a real math divergence
+#: lands orders of magnitude higher)
 PARITY_REL = 1e-5
 
 

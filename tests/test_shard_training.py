@@ -421,6 +421,24 @@ def test_meshed_slave_e2e_piggyback_and_web_status(engine_mesh):
 
 
 @pytest.mark.slow
+def test_sharded_slave_sends_the_master_the_bytes_of_a_single_device_one(
+        engine_mesh):
+    """Two-tier reduction: the gradient psum inside a slice is free on
+    the wire, so the master takes in the same bytes from a {data: 2,
+    model: 2} slave as from a single-device one — the difference is the
+    register piggyback key and a few polls, under 1 % of a run."""
+    engine_mesh(1, 1, shard=False)
+    single, _, slave = _fleet("tcp://127.0.0.1:18932")
+    assert slave.mesh_shape is None
+    engine_mesh(2, 2)
+    sharded, _, slave = _fleet("tcp://127.0.0.1:18933")
+    assert slave.mesh_shape == {"data": 2, "model": 2}
+    assert single.jobs_done == sharded.jobs_done
+    want, got = int(single.bytes_in), int(sharded.bytes_in)
+    assert abs(got - want) <= 0.01 * want, (want, got)
+
+
+@pytest.mark.slow
 def test_meshed_slave_through_relay_soak(engine_mesh):
     """The pod slice composes with the tree (ISSUE 10): a meshed leaf
     behind a relay trains to completion, and the relay's contributor

@@ -272,66 +272,6 @@ def test_image_file_source_streams(tmp_path):
     assert np.isfinite(wf.decision.epoch_metrics[2]["loss"])
 
 
-@pytest.mark.slow
-def test_bench_stream_protocol_smoke(capsys):
-    """bench --stream at tiny shapes: the whole protocol (resident
-    reference, u8-tiled window, staged segments, link probe) runs and the
-    JSON line carries the self-explaining roofline fields.
-
-    Slow-marked (ISSUE 7 budget discipline, the r24 precedent): this is
-    a smoke of the ``bench.py --stream`` protocol, whose real gates run
-    as the bench itself — tier-1 keeps the streaming-loader unit tests
-    above, and at ~98s this was the single heaviest tier-1 entry."""
-    import json
-
-    import bench
-
-    saved = {k: getattr(bench, k) for k in (
-        "BATCH", "STEPS", "N_TRAIN", "N_VALID", "N_CLASSES",
-        "N_STREAM_TILE", "N_HOST_TILE", "STAGE_SEGMENTS", "CHECK_LOSS",
-        "N_DECODE_JPG", "N_DECODE_MEASURE")}
-    # _build_bench_workflow mutates process-wide config from the patched
-    # bench globals — snapshot and restore everything it touches
-    cfg_saved = {k: root.alexnet.loader.get(k) for k in (
-        "minibatch_size", "n_train", "n_valid", "n_classes", "image_size")}
-    saved_epochs = root.alexnet.decision.get("max_epochs")
-    saved_precision = root.common.engine.get("precision", "float32")
-    saved_state = root.common.engine.get("state_dtype", "float32")
-    root.alexnet.loader.image_size = 64
-    try:
-        bench.BATCH, bench.STEPS = 8, 4
-        bench.N_TRAIN, bench.N_VALID, bench.N_CLASSES = 64, 16, 10
-        bench.N_STREAM_TILE, bench.N_HOST_TILE = 2, 2
-        bench.STAGE_SEGMENTS = 2
-        bench.CHECK_LOSS = False
-        bench.N_DECODE_JPG, bench.N_DECODE_MEASURE = 24, 16
-        bench.stream_main()
-    finally:
-        for k, v in saved.items():
-            setattr(bench, k, v)
-        for k, v in cfg_saved.items():
-            setattr(root.alexnet.loader, k, v)
-        root.alexnet.decision.max_epochs = saved_epochs
-        root.common.engine.precision = saved_precision
-        root.common.engine.state_dtype = saved_state
-    out = capsys.readouterr().out.strip().splitlines()[-1]
-    rec = json.loads(out)
-    assert rec["metric"] == "alexnet_stream_train_throughput_u8_resident"
-    assert rec["dataset_images"] == 128
-    assert np.isfinite(rec["value"]) and rec["value"] > 0
-    st = rec["staged"]
-    assert st["img_s"] > 0 and st["h2d_gbps_measured"] > 0
-    assert st["roofline_img_s_at_measured_bw"] <= rec["value"] + 1e-6
-    dec = rec["decode"]
-    assert dec["img_s_serial"] > 0 and dec["img_s_pooled"] > 0
-    assert dec["workers"] >= 1
-    # three-term roofline never exceeds any single term
-    assert dec["roofline_img_s_3term"] <= rec["value"] + 1e-6
-    assert dec["roofline_img_s_3term"] <= \
-        st["roofline_img_s_at_measured_bw"] + 1e-6
-    assert dec["roofline_img_s_3term"] <= dec["img_s_pooled"] + 1e-6
-
-
 def test_streaming_rejects_nonlinear_normalizer():
     from znicz_tpu.normalization import MeanDispNormalizer
 

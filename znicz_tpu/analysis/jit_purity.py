@@ -163,7 +163,7 @@ class JitPurityChecker(Checker):
         # pallas_call(kernel)) are matched by name module-wide; defs
         # carrying the decorator themselves are marked by NODE, so a
         # public wrapper that shares its name with an inner decorated
-        # def (ops/lrn_pallas.lrn) is not swept in by the collision
+        # def is not swept in by the collision
         marked: Dict[str, str] = {}        # referenced name -> kind
         marked_nodes: List[Tuple[ast.AST, str, str]] = []  # (fn, name, kind)
         statics: Dict[str, Tuple[Set[str], Set[int]]] = {}  # callee name

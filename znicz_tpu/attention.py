@@ -11,9 +11,8 @@ for callers already inside a shard_map) or via the
 = N > 1`` the unit builds an ``("sp",)`` mesh of N devices at initialize
 and ``apply`` shard_maps the attention core over it — ring attention
 leaves the dryrun on the EXISTING mesh plumbing, CPU-testable with
-virtual devices exactly like ``bench.py --shard`` (default 0 = off, the
-single-device path, bit-exact; BASELINE.md r20 records the TPU
-engagement protocol).
+virtual devices (default 0 = off, the single-device path, bit-exact;
+tests/test_attention.py).
 
 The variable-length serving/training units live here too (ISSUE 15):
 
@@ -43,7 +42,7 @@ from znicz_tpu.ops.attention import (attention, cache_append,
 def seq_parallel_size() -> int:
     """The ``root.common.engine.seq_parallel`` knob: sequence-parallel
     mesh size for MultiHeadAttention (0/1 = off — the single-device
-    path).  Gated OFF by default; engage per BASELINE.md r20."""
+    path).  Gated OFF by default."""
     from znicz_tpu.core.config import root
 
     return int(root.common.engine.get("seq_parallel", 0))

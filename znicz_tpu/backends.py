@@ -32,9 +32,8 @@ LATENCY_HIDING_XLA_FLAGS = (
 
 def configure_xla_flags(environ=None) -> Tuple[str, ...]:
     """Append the latency-hiding-scheduler flags to ``XLA_FLAGS`` when
-    ``root.common.engine.xla_latency_hiding`` is on (default OFF — a
-    labeled bench variant until the BASELINE.md r12 protocol records the
-    with/without numbers).  MUST run before the first jax backend
+    ``root.common.engine.xla_latency_hiding`` is on (default OFF — an
+    undecided lever, ROADMAP.md "Undecided levers").  MUST run before the first jax backend
     initialization — the launcher calls it right after config/overrides
     are applied; if a backend already exists the env change is inert, so
     this warns instead of silently lying.  Idempotent (flags already

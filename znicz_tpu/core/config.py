@@ -114,7 +114,25 @@ class Config:
     # -- dotted-path access (CLI overrides) ----------------------------------
 
     def set_by_path(self, dotted: str, value: Any) -> None:
+        """Set a leaf from dotted input that comes from outside the
+        program (the launcher's overrides, ``benchmark/tools/run_with.py``,
+        the benchmark's drivers).  The tree autovivifies, so this is where
+        an engine knob that ``ENGINE_DEFAULTS`` does not declare — a typo,
+        or a knob that was removed — is refused instead of silently
+        training on the default."""
         parts = dotted.split(".")
+        full = self._join(dotted).split(".")
+        if full[:3] == ["root", "common", "engine"]:
+            table: Any = ENGINE_DEFAULTS
+            for part in full[3:]:
+                if not isinstance(table, dict):
+                    break
+                if part not in table:
+                    raise KeyError(
+                        f"{'.'.join(full)}: no engine knob "
+                        f"{'.'.join(full[3:])!r} is declared in "
+                        "znicz_tpu.core.config.ENGINE_DEFAULTS")
+                table = table[part]
         node: Config = self
         for part in parts[:-1]:
             node = getattr(node, part)
@@ -162,8 +180,6 @@ root = Config("root")
 # Engine-wide defaults (the reference kept these under root.common.*).
 root.common.engine.seed = 1013
 root.common.engine.backend = "auto"      # "tpu" | "cpu" | "auto"
-root.common.engine.fuse = True           # compile fused train steps
-root.common.engine.precision = "float32"  # "float32" | "bfloat16" activations
 root.common.dirs.snapshots = "snapshots"
 root.common.dirs.cache = ".znicz_cache"
 root.common.dirs.datasets = "datasets"
@@ -180,25 +196,18 @@ ENGINE_DEFAULTS = {
     # core
     "seed": 1013,
     "backend": "auto",            # "tpu" | "cpu" | "auto"
-    "fuse": True,                 # compile fused train steps
     "fused": False,               # launcher --fused (fast-path engine)
-    # precision (ISSUE 7: compute_dtype is canonical; precision legacy)
-    "precision": "float32",       # legacy alias of compute_dtype
-    "compute_dtype": None,        # "float32" | "bf16"/"bfloat16"
+    # precision
+    "compute_dtype": "float32",   # "float32" | "bf16"/"bfloat16"
     "master_dtype": "float32",    # bf16-STORED master weights (variant)
     "state_dtype": "float32",     # optimizer-state (velocity) storage
     # fused-trainer shape
-    "remat": False,
     "scan_chunk": 8,
-    "pipeline_depth": 1,
+    "pipeline_depth": 1,          # >1: whole-epoch dispatches (fused.py)
     "async_snapshot": True,
     # fusion experiments / kernels
     "fused_elementwise": False,   # conv1/conv2 single-pass Pallas block
     "fused_tail": False,          # ISSUE 7: conv3-5 + FC + loss epilogues
-    "lrn_pow": False,
-    "lrn_autodiff": False,
-    "pallas_lrn": False,
-    "pool_bwd": "sas",            # "sas" | "mask"
     # ingest / staging (ISSUE 7)
     "prefetch_segments": 2,
     "decode_workers": None,

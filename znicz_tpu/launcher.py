@@ -381,7 +381,7 @@ class Launcher:
         if "snapshot" in sig.parameters and args.snapshot:
             kwargs["snapshot"] = args.snapshot
         if args.profile_dir:
-            # programmatic capture (TPU hand-off protocol, BASELINE.md):
+            # programmatic capture:
             # the trace holds the program's own spans as ``znicz:*``
             # annotations (telemetry/trace.py) — one StepTraceAnnotation
             # per fused dispatch — with nothing to arm here

@@ -32,8 +32,7 @@ Steady-state throughput becomes the three-term roofline
 
     img/s = min(compute rate, link_bw / bytes_per_sample, decode rate)
 
-which ``bench.py --stream`` measures term by term (``measure_decode_rate``
-below provides the decode term).
+(``measure_decode_rate`` below measures the decode term).
 """
 
 from __future__ import annotations
@@ -203,11 +202,12 @@ class DeviceStager:
         _sc = telemetry.scope("ingest")
         self._tracer = telemetry.tracer()
         #: the training thread's blocking time per take() — the overlap
-        #: gate's subject (bench.py --ingest): with the double buffer
-        #: absorbing an injected decode delay this stays well under it
+        #: check's subject (tests/test_ingest.py::test_ingest_overlap_gate):
+        #: with the double buffer absorbing an injected decode delay this
+        #: stays well under it
         self._m_wait_ms = _sc.histogram(
             "ingest_wait_ms", "training-thread wait per staged segment "
-            "(ms); the --ingest overlap gate bounds this", size=2048)
+            "(ms); the ingest overlap check bounds this", size=2048)
         #: worker-side assemble+put time (host gather through device_put
         #: dispatch) — where a decode/link stall actually shows up
         self._m_h2d_ms = _sc.histogram(
@@ -327,7 +327,7 @@ class DeviceStager:
 def measure_decode_rate(source, n: int = 256,
                         workers: Optional[int] = None) -> float:
     """Measured decode throughput (img/s) of a file-backed source — the
-    third roofline term for ``bench.py --stream``.  Decodes ``n`` rows
+    roofline's third term.  Decodes ``n`` rows
     through the source's own gather path (pooled when the source has a
     pool, serial otherwise) and times it cold-cache-fair: the same rows
     are decoded twice and the SECOND pass is timed, so the OS page cache

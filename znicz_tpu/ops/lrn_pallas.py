@@ -1,44 +1,20 @@
-"""Pallas TPU kernel for across-channel LRN (AlexNet-style; the hot
-normalization in SURVEY §2.2 "LRN" — reference shipped hand-written OCL/CU
-kernels for it; this is the TPU-native equivalent, see
-/opt/skills/guides/pallas_guide.md).
+"""The two pieces of across-channel LRN arithmetic that the composed unit
+(``znicz_tpu/lrn.py``) and the fused conv-block kernel
+(``znicz_tpu/pallas_fused_block.py``) share, so that both compute
 
-Forward:  y = x * (k + alpha * sum_{j in win(c)} x_j^2) ** (-beta)
-Backward: dx = dy * s^(-beta) - 2*alpha*beta * x * W(dy * x * s^(-beta-1))
-where s = k + alpha * W(x^2) and W is the same n-channel windowed sum.
+    y = x * (k + alpha * sum_{j in win(c)} x_j^2) ** (-beta)
 
-The tensor is processed as (rows, C) tiles resident in VMEM: one pass for
-the forward, one for the backward, with the windowed channel sum unrolled
-(n is tiny and static).  The XLA fallback (`znicz_tpu/lrn.py`) remains the
-oracle; `lrn(x, ...)` is exactly substitutable and carries a custom_vjp.
-On the cpu backend the kernel runs in interpreter mode (tests), or
-callers just use the jnp path.
-
-Measured honestly (bench.py, 1x v5e, 2026-07-30): the AlexNet step runs
-8.1k img/s with this kernel vs 10.8k with the XLA path — XLA fuses its
-LRN into neighboring ops and needs none of the flatten/pad reshapes, so
-the jnp path stays the DEFAULT (`root.common.engine.pallas_lrn` opts in).
-Kept as the Pallas example/capability with an exact custom-vjp, and as
-the starting point if a future model makes LRN the actual bottleneck.
+with one summation order and one ``s ** -beta``.
 """
 
 from __future__ import annotations
-
-import functools
-
-import numpy as np
-
-from znicz_tpu.backends import pallas_interpret
-
-TILE_R = 1024          # rows per grid step (multiple of 8 for f32 tiling)
 
 
 def windowed_channel_sum(t, n):
     """Sum over the n-channel window centered on the LAST axis (zero-padded
     ends), unrolled with static shifts — identical summation order to the
     jnp oracle in znicz_tpu/lrn.py.  Rank-general; the ONE home of the
-    shift-unrolled window sum, shared by this kernel and the fused
-    conv-block kernel (znicz_tpu/pallas_fused_block.py) whose parity
+    shift-unrolled window sum: the fused conv-block kernel's parity
     guarantees depend on this exact order."""
     import jax.numpy as jnp
 
@@ -60,14 +36,11 @@ def windowed_channel_sum(t, n):
     return acc
 
 
-_windowed = windowed_channel_sum
-
-
 def inv_pow_rsqrt(s, beta: float):
     """``s ** -beta`` via ``rsqrt(s)*sqrt(rsqrt(s))`` for the reference
     default beta=0.75 (two pipelined VPU ops instead of the exp/log
     ``pow`` expansion); plain ``pow`` otherwise.  Shared by lrn.py's jnp
-    path (its config-gated wrapper) and the fused conv-block kernel."""
+    path and the fused conv-block kernel."""
     import jax
     import jax.numpy as jnp
 
@@ -75,93 +48,3 @@ def inv_pow_rsqrt(s, beta: float):
         r = jax.lax.rsqrt(s)
         return r * jnp.sqrt(r)
     return jnp.power(s, -beta)
-
-
-def _fwd_kernel(n, alpha, beta, k, x_ref, y_ref):
-    import jax.numpy as jnp
-
-    x = x_ref[:]
-    s = k + alpha * _windowed(x * x, n)
-    y_ref[:] = x * jnp.power(s, -beta)
-
-
-def _bwd_kernel(n, alpha, beta, k, x_ref, dy_ref, dx_ref):
-    import jax.numpy as jnp
-
-    x = x_ref[:]
-    dy = dy_ref[:]
-    s = k + alpha * _windowed(x * x, n)
-    sb = jnp.power(s, -beta)
-    t = dy * x * sb / s                 # dy * x * s^(-beta-1)
-    dx_ref[:] = dy * sb - (2.0 * alpha * beta) * x * _windowed(t, n)
-
-
-def _pallas_2d(kernel, rows_c_arrays, interpret):
-    """Run a rows x C kernel tiled over TILE_R-row blocks."""
-    import jax
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    R, C = rows_c_arrays[0].shape
-    spec = pl.BlockSpec((TILE_R, C), lambda i: (i, 0),
-                        memory_space=pltpu.VMEM)
-    return pl.pallas_call(
-        kernel,
-        grid=(R // TILE_R,),
-        in_specs=[spec] * len(rows_c_arrays),
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((R, C), rows_c_arrays[0].dtype),
-        interpret=interpret,
-    )(*rows_c_arrays)
-
-
-def _as_rows(x):
-    """(..., C) -> (rows_padded, C), plus the original row count."""
-    import jax.numpy as jnp
-
-    C = x.shape[-1]
-    flat = x.reshape(-1, C)
-    R = flat.shape[0]
-    pad = (-R) % TILE_R
-    if pad:
-        flat = jnp.concatenate(
-            [flat, jnp.zeros((pad, C), flat.dtype)], axis=0)
-    return flat, R
-
-
-def _make():
-    import jax
-
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
-    def lrn(x, n, alpha, beta, k):
-        flat, R = _as_rows(x)
-        y = _pallas_2d(functools.partial(_fwd_kernel, n, alpha, beta, k),
-                       [flat], pallas_interpret())
-        return y[:R].reshape(x.shape)
-
-    def fwd(x, n, alpha, beta, k):
-        return lrn(x, n, alpha, beta, k), x
-
-    def bwd(n, alpha, beta, k, x, dy):
-        import jax.numpy as jnp
-
-        flat_x, R = _as_rows(x)
-        flat_dy, _ = _as_rows(dy)
-        dx = _pallas_2d(functools.partial(_bwd_kernel, n, alpha, beta, k),
-                        [flat_x, flat_dy], pallas_interpret())
-        return (dx[:R].reshape(x.shape).astype(x.dtype),)
-
-    lrn.defvjp(fwd, bwd)
-    return lrn
-
-
-_lrn = None
-
-
-def lrn(x, n=5, alpha=1e-4, beta=0.75, k=2.0):
-    """Pallas LRN with custom vjp; drop-in for the jnp forward in
-    znicz_tpu/lrn.py (tested for forward and gradient agreement)."""
-    global _lrn
-    if _lrn is None:
-        _lrn = _make()
-    return _lrn(x, int(n), float(alpha), float(beta), float(k))

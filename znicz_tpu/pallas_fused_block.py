@@ -2,7 +2,7 @@
 bias-add -> StrictRELU -> cross-channel LRN -> overlapping maxpool, forward
 AND backward, each as ONE VMEM-resident pass over the activation planes.
 
-Why (BASELINE.md r4 profile / VERDICT r5 weak #1): the composed ops lower to
+Why (a profile of the old installation's chip): the composed ops lower to
 several XLA fusions that each stream the 55x55x96-class conv1/conv2 tensors
 through HBM — 4.39 ms of the 10.75 ms AlexNet step at a measured
 320–490 GB/s against the chip's 819, and the one lever behind three rounds
@@ -34,16 +34,14 @@ Semantics vs the composed ops:
     at least as accurate as the composed bf16 chain.
 
 Engagement (``plan_fused_blocks``): opt-in via
-``root.common.engine.fused_elementwise`` (default OFF until a TPU-attached
-bench records the with/without numbers — BASELINE.md "Fused elementwise
-block"), and only where the graph shape matches exactly:
+``root.common.engine.fused_elementwise`` (default OFF: an undecided lever,
+ROADMAP.md "Undecided levers"), and only where the graph shape matches
+exactly:
 Conv(+bias)+StrictRELU (fused or as a standalone activation unit) ->
 LRNormalizerForward (odd window) -> MaxPooling whose windows tile the plane
 exactly (AlexNet's 55/27/13 planes all do; partial edge windows fall back
 to the composed ops), with a channel count that splits into whole
-128-lane tiles (``lanes_tile``; 96 and 256 do).  The LRN-formulation
-experiment knobs (``lrn_pow`` / ``lrn_autodiff`` / ``pallas_lrn``) disable
-fusion so their side-by-side re-runs stay pure.
+128-lane tiles (``lanes_tile``; 96 and 256 do).
 
 Backward wiring: ``fused_block`` carries a ``jax.custom_vjp``, so wherever
 the fused trainer's forward_pass routes through it, ``jax.grad`` of the
@@ -365,15 +363,10 @@ def match_fused_block(forwards: Sequence, i: int) -> Optional[FusedBlockSpec]:
 
 def plan_fused_blocks(forwards: Sequence) -> Dict[int, FusedBlockSpec]:
     """start-index -> FusedBlockSpec for every fusable conv block, or {}
-    when the ``fused_elementwise`` flag is off / an LRN-formulation
-    experiment knob is active (their side-by-side re-runs must stay
-    pure — BASELINE.md anchor-defense protocol)."""
+    when the ``fused_elementwise`` flag is off."""
     from znicz_tpu.core.config import root
 
     if not bool(root.common.engine.get("fused_elementwise", False)):
-        return {}
-    if any(bool(root.common.engine.get(knob, False))
-           for knob in ("lrn_pow", "lrn_autodiff", "pallas_lrn")):
         return {}
     plan: Dict[int, FusedBlockSpec] = {}
     i = 0
@@ -400,9 +393,8 @@ def plan_fused_blocks(forwards: Sequence) -> Dict[int, FusedBlockSpec]:
 # ONLY what already exists (the stage's raw linear input + params): the
 # backward recomputes every mask in-register instead of loading it.
 #
-# Engagement: ``root.common.engine.fused_tail`` (default OFF — same
-# BASELINE.md hand-off discipline as ``fused_elementwise``; bench.py
-# ``--fused-tail`` is the labeled with/without protocol).  Where BOTH
+# Engagement: ``root.common.engine.fused_tail`` (default OFF — an
+# undecided lever like ``fused_elementwise``).  Where BOTH
 # knobs are on, the conv1/conv2 BLOCK matcher wins its span and the tail
 # matcher takes everything else.
 
@@ -659,8 +651,7 @@ def match_seq_epilogue(forwards: Sequence, i: int) -> Optional[FusedTailSpec]:
 
 
 def fused_tail_enabled() -> bool:
-    """The ``root.common.engine.fused_tail`` gate (default OFF — engages
-    per the BASELINE.md r12 protocol; bench.py ``--fused-tail``)."""
+    """The ``root.common.engine.fused_tail`` gate (default OFF)."""
     from znicz_tpu.core.config import root
 
     return bool(root.common.engine.get("fused_tail", False))

@@ -22,8 +22,8 @@ Semantics guaranteed identical to the unit path:
     seeded stream design (mask reuse is implicit — fwd and bwd live in one
     autodiff graph).
 
-Mixed precision: with ``root.common.engine.precision = "bfloat16"``, the
-forward/backward graph runs in bf16 on the MXU while master params, velocity
+Mixed precision: with ``root.common.engine.compute_dtype = "bfloat16"``,
+the forward/backward graph runs in bf16 on the MXU while master params, velocity
 and the update stay float32.
 
 Unit-Array refresh cadence: training state lives in device arrays; the
@@ -33,7 +33,7 @@ of the run — NOT unconditionally every epoch (a device->host pull of the
 whole state each time).  A due HOST-FORMAT snapshot no longer pays even that:
 ``snapshot_from_trees`` hands donation-safe device copies to the
 snapshotter's background writer, which pulls and writes while the next
-epoch computes (r5; the deep pipeline checkpoints the same way at flush
+epoch computes (the deep pipeline checkpoints the same way at flush
 boundaries).  Ad-hoc observers that read weights mid-run must account
 for this.
 """
@@ -153,16 +153,13 @@ class FusedTrainer:
     """Compile and drive fused steps for a built+initialized workflow with
     ``forwards``, ``gds``, ``loader``, ``evaluator``, ``decision``."""
 
-    def __init__(self, workflow, mesh=None, remat=None):
+    def __init__(self, workflow, mesh=None):
         from znicz_tpu.all2all import All2AllSoftmax
         from znicz_tpu.attention import SeqAll2AllSoftmax
         from znicz_tpu.dropout import DropoutForward
         from znicz_tpu.evaluator import EvaluatorSoftmax
         from znicz_tpu.pooling import StochasticPoolingBase
 
-        if remat is None:
-            remat = bool(root.common.engine.get("remat", False))
-        self.remat = remat
         self.scan_chunk = int(root.common.engine.get("scan_chunk",
                                                      type(self).scan_chunk))
         self.pipeline_depth = int(root.common.engine.get(
@@ -236,7 +233,7 @@ class FusedTrainer:
         #: the run in progress — see ``_resident``
         self._twins = []
         #: the live DeviceStager while a staged run is inside
-        #: _run_segmented with async staging on (tests/bench observe it)
+        #: _run_segmented with async staging on (tests observe it)
         self._stager = None
         self._key0 = prng.get("fused_trainer").jax_key(0)
         self.steps_done = 0
@@ -292,12 +289,9 @@ class FusedTrainer:
             size=4096)
         #: compute dtype (activations + gradients; master weights stay
         #: f32): ``root.common.engine.compute_dtype`` is the canonical
-        #: knob ("float32" | "bf16" | "bfloat16"); the pre-r12
-        #: ``precision`` spelling is kept as the legacy alias and applies
-        #: only when compute_dtype is unset.
-        cd = root.common.engine.get("compute_dtype", None)
-        if cd is None:
-            cd = root.common.engine.get("precision", "float32")
+        #: knob ("float32" | "bf16" | "bfloat16"; None, what a fixture
+        #: that saved an unset knob restores, reads as unset)
+        cd = root.common.engine.get("compute_dtype", None) or "float32"
         cd = {"bf16": "bfloat16"}.get(str(cd), str(cd))
         if cd not in ("float32", "bfloat16"):
             raise ValueError(
@@ -325,8 +319,8 @@ class FusedTrainer:
         #: the dominant non-MXU traffic after the r4 bf16 velocities) —
         #: while the update arithmetic stays f32 (cast up, update, cast
         #: back).  This CHANGES convergence semantics (weight rounding):
-        #: a labeled bench variant (--master-bf16), never the headline
-        #: or the anchors.
+        #: an undecided lever (ROADMAP.md), never a cell's default or
+        #: the anchors'.
         md = str(root.common.engine.get("master_dtype", "float32"))
         if md not in ("float32", "bfloat16"):
             raise ValueError(
@@ -425,7 +419,7 @@ class FusedTrainer:
     def tiled_hypers(self, k: int):
         """Per-step hypers rows for a k-step scan with CONSTANT hypers —
         the one home for the scan's hypers-xs layout (callers without an
-        LR schedule: bench, dryrun, hypers_rows' fast path)."""
+        LR schedule: dryrun, hypers_rows' fast path)."""
         return {name: np.tile(np.asarray(t, np.float32), (k, 1))
                 for name, t in self.hypers().items()}
 
@@ -587,9 +581,9 @@ class FusedTrainer:
         one in (``loss_and_metrics`` does, and returns them with the
         step's metrics).  In training a unit is rematerialised
         (``jax.checkpoint`` around that unit alone: its input is all the
-        backward pass keeps of it) where it asks for that (``remat``) or
-        the engine's ``remat`` is on — per unit, so that what is live in
-        the backward pass is one unit's activations, not the network's."""
+        backward pass keeps of it) where it asks for that (``remat =
+        True`` on the unit) — per unit, so that what is live in the
+        backward pass is one unit's activations, not the network's."""
         import jax
 
         from znicz_tpu.ops.linear import linear
@@ -680,7 +674,7 @@ class FusedTrainer:
                     h = f.apply(p, h)
                 return h, counters
 
-            if train and (self.remat or getattr(f, "remat", False)):
+            if train and getattr(f, "remat", False):
                 unit = jax.checkpoint(unit)
             # the device trace speaks the model's names: one scope per
             # forward unit (a fused block or tail span takes its first
@@ -787,14 +781,6 @@ class FusedTrainer:
     #: FC layers at least this wide get tensor-parallel row sharding when
     #: the mesh has a ``model`` axis (AlexNet's 4096-wide fc6/fc7)
     tp_threshold = 1024
-
-    #: rematerialize every unit's forward during backward (one
-    #: ``jax.checkpoint`` a unit: what stays live is each unit's input) —
-    #: trades ~1/3 more FLOPs for not keeping activations live, the
-    #: standard HBM lever for big batches/models
-    #: (root.common.engine.remat or FusedTrainer(..., remat=True); a unit
-    #: asks for itself with ``remat = True``)
-    remat = False
 
     def param_sharding(self, name, k, arr):
         """Per-param placement: wide (out, in) FC weights shard their output
@@ -1277,7 +1263,9 @@ class FusedTrainer:
     #: nothing consumes host state at epoch granularity (no plotters,
     #: snapshotter absent/gated) — see ``_deep_eligible``.  Identical
     #: training semantics: stops are rolled back to the exact stopping
-    #: state (``root.common.engine.pipeline_depth``).
+    #: state (``root.common.engine.pipeline_depth``).  Kept by PR 30's
+    #: ladder: 2 read +16.9 % on four chips, where the host's launches
+    #: are a fifth of an epoch, and nothing on one (PERF.md section 6).
     pipeline_depth = 1
 
     @contextlib.contextmanager
@@ -1634,23 +1622,27 @@ class FusedTrainer:
 
         Two host-sync profiles, identical training semantics:
 
-          - default (``pipeline_depth`` 1): consecutive non-tail TRAIN
-            minibatches run as ONE ``lax.scan`` dispatch of up to
-            ``scan_chunk`` steps, with a one-deep flush pipeline; epoch
-            tails and eval feed the Decision synchronously (so epoch-
-            granular consumers — snapshotter, plotters — see every epoch);
+          - default (``pipeline_depth`` 1; what the three cells run):
+            consecutive non-tail TRAIN minibatches run as ONE ``lax.scan``
+            dispatch of up to ``scan_chunk`` steps, with a one-deep flush
+            pipeline; epoch tails and eval feed the Decision
+            synchronously, so epoch-granular consumers — snapshotter,
+            plotters, an ``on_epoch_end`` callback — see every epoch when
+            it ends;
           - deep (``pipeline_depth`` > 1 and ``_deep_eligible``): whole
             epochs as single dispatches, metrics pulled one fused transfer
-            per epoch, up to depth epochs late (VERDICT r4: the product
-            path on ~100ms-RTT links)."""
+            per epoch, up to ``2 * depth`` epochs late.  What it buys is
+            the host's launches between the programs of an epoch: a fifth
+            of an epoch on four chips (PERF.md section 6).  It books no
+            counting unit's counters and holds a state copy an in-flight
+            epoch, so a decoder that fills the chip cannot run on it."""
         if self.loss_kind != "softmax" and \
                 getattr(self.loader, "streaming", False) and \
                 not self.loader.original_targets:
             raise ValueError(
                 f"{self.loader.name}: a streaming loader with an MSE "
                 "loss needs regression targets — build the StreamingLoader "
-                "source with targets= (ADVICE r4: this used to surface as "
-                "an opaque error deep inside the staging/operand path)")
+                "source with targets=")
         try:
             if self.pipeline_depth > 1 and self._deep_eligible():
                 self._run_deep()
@@ -1793,7 +1785,7 @@ class FusedTrainer:
 
                 stager = DeviceStager(
                     lambda rows: self._stage_direct(rows, put))
-                self._stager = stager       # observable (tests, bench)
+                self._stager = stager       # observable (tests)
         # the lookahead must advance even for memcpy-cheap sources (no
         # decode pool): the stager needs the fifo to predict from
         look_mbs = max(look_mbs if can_prefetch else 0,
@@ -2250,7 +2242,7 @@ class FusedTrainer:
         losses then n_errs; then train losses, train n_errs, tail loss,
         tail n_err) and stacked confusion sums (one per eval run + one
         for TRAIN incl. tail) — all metrics pullable in a single host
-        transfer per epoch (~100ms/RTT links: VERDICT r3 weak #2)."""
+        transfer per epoch."""
         import jax
         import jax.numpy as jnp
 

@@ -168,86 +168,6 @@ class PoolingBase(ForwardBase):
             self.input_offset.devmem = off
 
 
-import functools
-
-
-@functools.lru_cache(maxsize=None)
-def _masked_maxpool(ky: int, kx: int, sy: int, sx: int):
-    """Max pooling with a SCATTER-FREE custom-vjp backward (opt-in —
-    ``root.common.engine.pool_bwd = "mask"``): XLA lowers reduce_window's
-    max gradient to select_and_scatter, which measured ~7% of the whole
-    AlexNet train step on v5e (r5 avg-pool-swap probe).  The masked
-    backward is ky*kx strided compares + interior-padded adds — pure
-    elementwise+pad work XLA fuses.
-
-    TIE SEMANTICS differ from select_and_scatter: dy is split EQUALLY
-    among a window's tied maxima (mass-conserving) instead of routed to
-    the first one.  Ties are common after ReLU (all-zero windows), so
-    this is a (slightly) different subgradient — which is why it is an
-    opt-in lever, not the default, until an anchor-grade side-by-side
-    justifies flipping it (BASELINE.md r5)."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    def _fwd_pool(x):
-        oh, ow = pool_output_hw(x.shape[1], x.shape[2], ky, kx, (sy, sx))
-        ph, pw = (oh - 1) * sy + ky, (ow - 1) * sx + kx
-        return lax.reduce_window(
-            x, x.dtype.type(-np.inf), lax.max,
-            window_dimensions=(1, ky, kx, 1), window_strides=(1, sy, sx, 1),
-            padding=((0, 0), (0, ph - x.shape[1]), (0, pw - x.shape[2]),
-                     (0, 0)))
-
-    @jax.custom_vjp
-    def f(x):
-        return _fwd_pool(x)
-
-    def fwd(x):
-        y = _fwd_pool(x)
-        return y, (x, y)
-
-    def bwd(res, g):
-        x, y = res
-        b, h, w, c = x.shape
-        oh, ow = y.shape[1], y.shape[2]
-        ph, pw = (oh - 1) * sy + ky, (ow - 1) * sx + kx
-        xp = jnp.pad(x, ((0, 0), (0, ph - h), (0, pw - w), (0, 0)),
-                     constant_values=x.dtype.type(-np.inf))
-
-        def win_slice(i, j):
-            return lax.slice(xp, (0, i, j, 0),
-                             (b, i + (oh - 1) * sy + 1,
-                              j + (ow - 1) * sx + 1, c),
-                             (1, sy, sx, 1))
-
-        masks, nt = [], None
-        for i in range(ky):
-            for j in range(kx):
-                m = (win_slice(i, j) == y).astype(g.dtype)
-                masks.append(m)
-                nt = m if nt is None else nt + m
-        inv = g / nt                     # dy split equally among ties
-        dxp, mi = None, 0
-        for i in range(ky):
-            for j in range(kx):
-                contrib = inv * masks[mi]
-                mi += 1
-                # interior padding re-dilates the strided slice back to
-                # padded-input coordinates — pure lax.pad, no scatter
-                part = lax.pad(
-                    contrib, jnp.zeros((), g.dtype),
-                    ((0, 0, 0),
-                     (i, ph - (i + (oh - 1) * sy + 1), sy - 1),
-                     (j, pw - (j + (ow - 1) * sx + 1), sx - 1),
-                     (0, 0, 0)))
-                dxp = part if dxp is None else dxp + part
-        return (dxp[:, :h, :w, :].astype(x.dtype),)
-
-    f.defvjp(fwd, bwd)
-    return f
-
-
 class MaxPooling(PoolingBase):
     PAD_VALUE = -np.inf
 
@@ -261,11 +181,6 @@ class MaxPooling(PoolingBase):
     def apply(self, params, x):
         from jax import lax
 
-        from znicz_tpu.core.config import root
-
-        if str(root.common.engine.get("pool_bwd", "sas")) == "mask":
-            sy, sx = self.sliding
-            return _masked_maxpool(self.ky, self.kx, sy, sx)(x)
         return self._reduce_window(x, -np.inf, lax.max)
 
 

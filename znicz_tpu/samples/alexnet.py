@@ -57,18 +57,9 @@ def make_layers(n_classes: int):
           "gradient_moment": float(cfg.get("gradient_moment")),
           "weights_decay": float(cfg.get("weights_decay"))}
     drop = float(cfg.get("dropout"))
-    # conv1_padding (default none — the reference geometry): an OPT-IN
-    # layout experiment (VERDICT r4 item 2b).  (2,2,2,2) makes the
-    # conv1/lrn1/pool1-input planes 56x56 instead of 55x55 — 56 = 8*7 is
-    # sublane-friendly for the big elementwise fusions — while pool1
-    # still emits 27x27, so everything downstream is unchanged.  It is a
-    # DIFFERENT network at the borders (padded conv taps), so it is a
-    # perf experiment, never the anchor protocol.
-    conv1_pad = tuple(cfg.get("conv1_padding", (0, 0, 0, 0)))
     return [
         {"type": "conv_strict_relu",
-         "->": {"n_kernels": 96, "kx": 11, "ky": 11, "sliding": (4, 4),
-                "padding": conv1_pad},
+         "->": {"n_kernels": 96, "kx": 11, "ky": 11, "sliding": (4, 4)},
          "<-": dict(gd)},
         {"type": "norm"},
         {"type": "max_pooling", "->": {"kx": 3, "ky": 3, "sliding": (2, 2)}},

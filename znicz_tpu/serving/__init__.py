@@ -56,8 +56,8 @@ queue_bound, request_ttl_s}`` + ``root.common.serving.admission.*``
 + ``root.common.serving.mesh.*`` (pod-slice sharding, ISSUE 13)
 + ``root.common.serving.generate.*`` (ISSUE 16);
 CLI: ``python -m znicz_tpu <workflow> --serve [BIND] --snapshot FILE``;
-bench gate: ``python bench.py --serve`` (see README "Serving" and
-"Serving robustness").
+tests: ``tests/test_serving.py`` (see README "Serving" and "Serving
+robustness").
 """
 
 from .balancer import ReplicaBalancer                       # noqa: F401

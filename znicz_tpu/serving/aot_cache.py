@@ -6,8 +6,7 @@ The paper's economics assume workers die and return constantly; after
 PR 15/16 one replica's family is 12 scoring buckets + 22 generation
 executables, and a cold compile of that family dominates
 boot-to-/readyz.  This cache turns a heal/preemption/canary reboot
-into a deserialize pass: measured on this host a cached executable
-loads ~20x faster than it compiles (see BASELINE.md r22).
+into a deserialize pass.
 
 **Mechanism** — ``jax.experimental.serialize_executable``:
 ``serialize(compiled)`` captures a lowered+compiled executable (XLA
@@ -45,8 +44,7 @@ Wire-in: ``ModelRunner.enable_aot_cache`` (model.py) builds one
 ``ExecutableCache`` per runner and routes every warmup/dispatch miss
 through ``_aot_exec``; counters land in the ``warmup`` telemetry scope
 (``znicz_warmup_cache_{hits,misses,stores,refusals}_total``) — the
-fleet panel's warm columns and bench.py --elastic's boot gate read
-them.
+fleet panel's warm columns read them.
 """
 
 from __future__ import annotations

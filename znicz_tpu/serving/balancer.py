@@ -64,7 +64,7 @@ which keeps generation stamps lockstep across preemptions.
 Config home: ``root.common.serving.balance.*`` (declared in the serving
 DEFAULTS table, read through a local alias like the admission subtree).
 CLI: ``python -m znicz_tpu --balance [BIND] --replicas ep1,ep2,...``;
-gate: ``python bench.py --fleet`` (README "Replica fleet").
+tests: ``tests/test_balancer.py`` (README "Replica fleet").
 """
 
 from __future__ import annotations

@@ -253,8 +253,8 @@ class AdmissionPolicy:
       - ``client_queue_bound``: queued rows ONE client may hold
         (0 = no per-client cap — the global ``queue_bound`` is the
         only backpressure);
-      - ``enabled``: master switch — ``bench.py --serve`` toggles it for
-        the interleaved on/off overhead gate.
+      - ``enabled``: master switch (toggled mid-traffic by
+        tests/test_serving.py::test_batcher_admission_toggle_mid_traffic).
     """
 
     __slots__ = ("rate_limit", "rate_burst", "fair", "quantum",

@@ -103,9 +103,8 @@ DEFAULTS = {"max_batch": 32, "max_delay_ms": 5.0, "queue_bound": 256,
             # content-addressed cache next to the snapshot (``dir``
             # overrides the location) and a restarted replica LOADS its
             # whole family instead of compiling it — the zero-cold-start
-            # lever bench.py --elastic gates (>= 3x faster boot-to-
-            # /readyz on this host).  Off by default: long-lived
-            # replicas pay nothing
+            # lever (tests/test_aot_cache.py: a warm boot compiles
+            # nothing).  Off by default: long-lived replicas pay nothing
             "aot_cache": {"enabled": False, "dir": ""},
             # fleet observability (ISSUE 20; read through a local alias
             # like the admission subtree): slow-request exemplar window

@@ -17,7 +17,7 @@ counter ticks inside the traced function body, which Python only runs
 when jax actually (re)traces, i.e. once per cache entry — and
 ``jit_cache_size()`` cross-checks it against jax's own pjit cache, so
 "zero recompiles after warmup" is provable from the outside
-(bench.py --serve's gate).
+(tests/test_serving.py::test_warmup_compiles_ladder_then_zero_recompiles).
 
 **Donated ping-pong staging**: ``stage`` starts an async host->device
 put and ``infer_staged`` DONATES that buffer into the jitted call
@@ -62,7 +62,7 @@ request's rows are a pure function of its rows + the rung executable,
 wherever its rows land across devices); across DIFFERENT mesh layouts
 results agree only numerically — reduction tiling is layout-dependent,
 the same reason PR 4 pinned parity per bucket executable
-(bench.py --shard gates the band; tests/test_shard_serving.py).
+(tests/test_shard_serving.py holds the band).
 """
 
 from __future__ import annotations

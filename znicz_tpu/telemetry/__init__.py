@@ -17,8 +17,7 @@ Surfaces:
     capture holds the program's spans on its own clock with nothing armed;
   - the benchmark's cells, run with ``root.common.telemetry.enabled`` on
     and off, measure what the layer costs on the chip (PERF.md section
-    6); ``bench.py --telemetry`` is the older CPU-relative gate, which
-    nothing runs.
+    6).
 
 ``set_enabled(False)`` turns the OPTIONAL layer off: spans stop
 recording and the trainer's step histogram stops observing.  Service

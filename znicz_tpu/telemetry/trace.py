@@ -35,9 +35,7 @@ capacity evict the oldest event without locking).  When the ring is
 disabled, ``span()`` returns a shared no-op context manager, so
 instrumented hot paths pay one attribute check and emit no annotation.
 What the layer costs on the chip is measured by the benchmark's cells
-with ``root.common.telemetry.enabled`` on and off (PERF.md section 6);
-``bench.py --telemetry`` is the older CPU-relative gate and nothing runs
-it.
+with ``root.common.telemetry.enabled`` on and off (PERF.md section 6).
 """
 
 from __future__ import annotations
