@@ -137,7 +137,10 @@ def test_charlm_snapshot_serves_variable_length(tmp_path):
             np.testing.assert_array_equal(np.array(a.map_read()),
                                           trained[f.name][k])
 
-    srv = InferenceServer(fresh, max_batch=4, max_delay_ms=2.0).start()
+    # a window wide enough that the probe and its neighbour below always
+    # share a batch (full at 4 rows, it leaves at once): at 2 ms a busy
+    # host split them, and the probe alone runs in another rows rung
+    srv = InferenceServer(fresh, max_batch=4, max_delay_ms=100.0).start()
     cli = InferenceClient(srv.endpoint, timeout=60)
     try:
         ladder = srv.batcher.ladder

@@ -9,19 +9,36 @@ import pytest
 from znicz_tpu.core.config import root
 
 
-def fresh_mnist(max_epochs=2):
+def fresh_mnist(max_epochs=2, n_valid=60):
     from znicz_tpu.core import prng
     from znicz_tpu.samples import mnist
 
     prng.reset(1013)
     root.mnist.loader.n_train = 300
-    root.mnist.loader.n_valid = 60
+    root.mnist.loader.n_valid = n_valid
     root.mnist.loader.n_test = 0
     root.mnist.loader.minibatch_size = 60
     root.mnist.decision.max_epochs = max_epochs
-    wf = mnist.MnistWorkflow()
-    wf.initialize(device=None)
+    try:
+        wf = mnist.MnistWorkflow()
+        wf.initialize(device=None)
+    finally:
+        root.mnist.loader.n_valid = 60
     return wf
+
+
+def streaming_mnist(max_epochs, budget):
+    """``tests/test_streaming.py``'s mnist over a streaming loader (its
+    epochs end in a short minibatch): resident (``budget`` large) or
+    host-staged a segment at a time (0)."""
+    from tests import test_streaming as ts
+
+    ts._StreamingMnistLoader.u8 = False
+    ts._StreamingMnistLoader.budget = budget
+    try:
+        return ts._fresh(ts._StreamingMnistLoader, max_epochs)
+    finally:
+        ts._StreamingMnistLoader.budget = 0
 
 
 def run_unit(wf):
@@ -348,7 +365,9 @@ def test_fused_stats_observability(tmp_path):
     from znicz_tpu.web_status import WebStatus
 
     root.common.dirs.snapshots = str(tmp_path)
-    wf = fresh_mnist()
+    # three epochs: the second repeats the first's programs (its tail
+    # rides the scan), the last ends in a shorter scan and a tail alone
+    wf = fresh_mnist(max_epochs=3)
     trainer = FusedTrainer(wf)
     trainer.run()
     s = trainer.stats
@@ -371,6 +390,14 @@ def test_fused_stats_observability(tmp_path):
         snap = status.snapshot()
         info = next(w for w in snap["workflows"] if w["name"] == wf.name)
         assert info["fused"]["train_steps"] == s["train_steps"]
+        # how each epoch's tail ran: /status.json and /metrics
+        assert (info["fused"]["tails_in_scan"],
+                info["fused"]["tails_alone"]) == (2, 1)
+        from znicz_tpu import telemetry
+
+        scraped = telemetry.render_prometheus()
+        assert 'znicz_tails_in_scan_total{component="trainer"}' in scraped
+        assert 'znicz_tails_alone_total{component="trainer"}' in scraped
     finally:
         status.stop()
 
@@ -823,3 +850,300 @@ def test_fused_lr_schedule_matches_unit_path(tmp_path):
     assert wff.lr_adjust.iteration == 14
     np.testing.assert_allclose(wff.gds[0].learning_rate,
                                0.1 * 0.9 ** 13, rtol=1e-6)
+
+
+# -- the epoch's tail in the scan (ISSUE 31) ------------------------------------
+
+
+def _stopping_state(wf):
+    """Where a run left its loader and Decision."""
+    loader, d = wf.loader, wf.decision
+    return {"loader_epoch": int(loader.epoch_number),
+            "samples_served": int(loader.samples_served),
+            "epoch": int(d.epoch_number), "best_epoch": int(d.best_epoch),
+            "best_metric": float(d.best_metric), "fails": int(d._fails),
+            "complete": bool(d.complete), "improved": bool(d.improved),
+            "epochs": len(d.epoch_history)}
+
+
+@pytest.mark.parametrize("n_valid", [60, 0],
+                         ids=["validation-set", "no-validation-set"])
+def test_fused_tail_rides_the_scan_where_the_decision_can_say(n_valid,
+                                                              tmp_path):
+    """With a validation set the Decision rules on VALID, which is final
+    before the epoch's TRAIN minibatches run: every tail but the run's
+    last (``max_epochs``: its update is not adopted) is the last step of
+    its epoch's scan.  Without one the tail's own loss decides, and every
+    tail is evaluated, ruled on and updated alone, as it always was.
+    Either way losses, weights, steps, loader and Decision state are the
+    unit engine's."""
+    from znicz_tpu.parallel.fused import FusedTrainer
+
+    root.common.dirs.snapshots = str(tmp_path)
+    epochs = 3
+    wfu = fresh_mnist(epochs, n_valid)
+    lu, wu = run_unit(wfu)
+    wff = fresh_mnist(epochs, n_valid)
+    losses = []
+    wff.decision.on_epoch_end.append(
+        lambda d: losses.append(d.epoch_metrics[2]["loss"]))
+    trainer = FusedTrainer(wff)
+    trainer.run()
+    riding = epochs - 1 if n_valid else 0
+    assert (trainer.stats["tails_in_scan"],
+            trainer.stats["tails_alone"]) == (riding, epochs - riding)
+    assert len(lu) == epochs
+    np.testing.assert_allclose(lu, losses, rtol=1e-4)
+    for f in wff.forwards:
+        np.testing.assert_allclose(
+            np.array(f.weights.map_read()), wu[f.name], rtol=2e-3,
+            atol=2e-5, err_msg=f.name)
+    assert trainer.steps_done == epochs * 5
+    assert _stopping_state(wff) == pytest.approx(_stopping_state(wfu),
+                                                 rel=1e-4)
+    # the train-mode evaluation and the lone train step are the programs
+    # of a tail alone: a run whose tails ride compiles the first once (its
+    # last tail) and the second never
+    sizes = trainer.jit_cache_sizes()
+    assert sizes["_train_step"] == (0 if n_valid else 1)
+
+
+def test_fused_tail_in_scan_steps_the_lr_schedule_like_the_unit_path(
+        tmp_path):
+    """The schedule advances after a tail in the scan as after any step:
+    the rate of every applied update, step by step, is the unit path's."""
+    from znicz_tpu.core import prng
+    from znicz_tpu.parallel.fused import FusedTrainer
+    from znicz_tpu.samples.mnist import MnistLoader
+    from znicz_tpu.standard_workflow import StandardWorkflow
+
+    def with_schedule():                # test_fused_lr_schedule_...'s
+        prng.reset(1013)
+        root.mnist.loader.n_train = 300
+        root.mnist.loader.n_valid = 60
+        root.mnist.loader.n_test = 0
+        root.common.dirs.snapshots = str(tmp_path)
+        gd = {"learning_rate": 0.1, "gradient_moment": 0.9}
+        wf = StandardWorkflow(
+            name="MnistStdLR",
+            loader=MnistLoader(name="loader", minibatch_size=60),
+            layers=[{"type": "all2all_tanh",
+                     "->": {"output_sample_shape": 100}, "<-": dict(gd)},
+                    {"type": "softmax",
+                     "->": {"output_sample_shape": 10}, "<-": dict(gd)}],
+            loss_function="softmax",
+            decision_config={"max_epochs": 3},
+            lr_adjust_config={"policy": "exp", "gamma": 0.9})
+        wf.initialize(device=None)
+        return wf
+
+    wfu = with_schedule()
+    gd, unit_rates = wfu.gds[0], []
+    gd_run = gd.run
+
+    def recording_run():                # gated like the update itself
+        unit_rates.append(float(gd.learning_rate))
+        gd_run()
+
+    gd.run = recording_run
+    wfu.run()
+
+    wff = with_schedule()
+    trainer = FusedTrainer(wff)
+    name, fused_rates = wff.gds[0].forward.name, []
+    hypers_rows = trainer._hypers_rows
+
+    def recording_rows(k, advance_last=True):
+        rows = hypers_rows(k, advance_last)
+        fused_rates.extend(float(r[0]) for r in rows[name])
+        return rows
+
+    trainer._hypers_rows = recording_rows
+    trainer.run()
+    assert trainer.stats["tails_in_scan"] == 2
+    # 5 + 5 in scans that end in their tail, 4 before the last tail,
+    # whose update is not adopted in either engine
+    assert len(unit_rates) == len(fused_rates) == 14
+    np.testing.assert_allclose(fused_rates, unit_rates, rtol=1e-6)
+    assert wff.lr_adjust.iteration == wfu.lr_adjust.iteration == 14
+
+
+@pytest.mark.parametrize("kind", ["DecisionBase", "DecisionGD",
+                                  "DecisionMSE"])
+def test_the_rule_asked_ahead_is_the_rule_run_adopts(kind):
+    """``tail_stops`` (asked once the epoch's validation is fed, before
+    its TRAIN minibatches) and ``run()`` at the tail agree on every epoch
+    of a ``fail_iterations`` stop, through the ``improvement_metric`` each
+    class has; asking changes no state; asked before the validation is
+    fed it answers only where the metric cannot matter."""
+    import copy
+
+    from znicz_tpu import decision as decision_mod
+    from znicz_tpu.loader.base import TRAIN, VALID
+
+    d = getattr(decision_mod, kind)(name="decision", max_epochs=50,
+                                    fail_iterations=2)
+    d.class_lengths = [0, 60, 120]
+
+    def feed(klass, value, last=False, ended=False):
+        d.minibatch_class, d.last_minibatch = klass, last
+        d.class_ended = ended or last
+        d.minibatch_loss = value
+        d.minibatch_n_err, d.minibatch_size = int(value * 60), 60
+        d.run()
+
+    def state():
+        return copy.deepcopy({k: v for k, v in vars(d).items() if k in (
+            "best_metric", "best_epoch", "_fails", "_acc_loss",
+            "_acc_batches", "_acc_n_err", "_acc_samples", "epoch_metrics",
+            "epoch_history")}), bool(d.complete), bool(d.improved), \
+            bool(d.gd_skip), bool(d.epoch_ended)
+
+    # validation improves twice, stalls, improves, then stalls for good
+    valid = [0.9, 0.5, 0.5, 0.4, 0.45, 0.4]
+    said, early = [], []
+    for epoch, metric in enumerate(valid):
+        d.epoch_number = epoch
+        before = state()
+        early.append(d.tail_stops(epoch, validated=False))
+        assert state() == before
+        feed(VALID, metric, ended=True)
+        before = state()
+        ahead = d.tail_stops(epoch)
+        assert state() == before
+        assert early[-1] in (None, ahead)
+        feed(TRAIN, 1.0 / (epoch + 1))  # nothing TRAIN reads moves the rule
+        assert d.tail_stops(epoch) is ahead
+        feed(TRAIN, 1.0 / (epoch + 1), last=True)
+        assert bool(d.complete) is ahead, (epoch, ahead)
+        said.append(ahead)
+    assert said == [False] * 5 + [True]
+    # one stall behind it, the next epoch's validation decides
+    assert early == [False, False, False, None, False, None]
+    # without fail_iterations the metric cannot matter: answered early
+    d.fail_iterations, d.epoch_number = 0, 6
+    assert d.tail_stops(6, validated=False) is False
+    assert d.tail_stops(49, validated=False) is True
+    # no validation set: improvement is judged on TRAIN, the tail decides
+    d.class_lengths = [0, 0, 120]
+    assert d.tail_stops(6) is None and d.tail_stops(49) is None
+
+
+def test_a_callback_stop_leaves_its_epochs_last_update_applied(tmp_path):
+    """The documented difference: a stop that an ``on_epoch_end``
+    callback asks for cannot be seen ahead, so where that epoch's tail
+    rode the scan its update is applied when the callback runs — the unit
+    engine skips it.  The fused run stands exactly one step further: the
+    unit engine's stopping state plus that tail's update."""
+    from znicz_tpu.core import prng
+    from znicz_tpu.parallel.fused import FusedTrainer
+
+    root.common.dirs.snapshots = str(tmp_path)
+
+    def stop_after_two(d):
+        if d.epoch_number == 1:
+            d.complete.set(True)
+
+    wfu = fresh_mnist(max_epochs=10)
+    wfu.decision.on_epoch_end.append(stop_after_two)
+    _, wu = run_unit(wfu)
+    wff = fresh_mnist(max_epochs=10)
+    wff.decision.on_epoch_end.append(stop_after_two)
+    trainer = FusedTrainer(wff)
+    trainer.run()
+    assert len(wff.decision.epoch_history) == 2 == len(
+        wfu.decision.epoch_history)
+    assert trainer.steps_done == 10
+    assert (trainer.stats["tails_in_scan"],
+            trainer.stats["tails_alone"]) == (2, 0)
+    got = {f.name: np.array(f.weights.map_read()) for f in wff.forwards}
+    assert not all(np.allclose(got[n], wu[n], rtol=2e-3, atol=2e-5)
+                   for n in wu), "the tail's update was skipped"
+    # the unit engine stands at the tail with its update gated off: apply
+    # it (the fused step on the unit engine's state, the tail's rows, the
+    # tenth step's key) and the two runs meet
+    tail = FusedTrainer(wfu)
+    params, velocities, _ = tail.make_train_step()(
+        tail.extract_params(), tail.extract_velocities(), tail.hypers(),
+        tail._op_value(wfu.loader.original_data),
+        tail._op_value(wfu.loader.original_labels),
+        np.array(wfu.loader.minibatch_indices.mem, np.int32),
+        np.int32(wfu.loader.minibatch_size),
+        prng.get("fused_trainer").jax_key(9))
+    assert bool(wfu.loader.last_minibatch)
+    for f in wff.forwards:
+        np.testing.assert_allclose(
+            got[f.name], np.asarray(params[f.name]["weights"]), rtol=2e-3,
+            atol=2e-5, err_msg=f.name)
+
+
+@pytest.mark.parametrize("staged", [False, True],
+                         ids=["resident", "staged"])
+def test_a_snapshot_of_an_epoch_whose_tail_rode_resumes_the_run(staged,
+                                                                tmp_path):
+    """The epoch-end hook of a segment that holds a tail runs before the
+    loader moves on — a staged source's lookahead too: the snapshot has
+    the tail's update and a loader that stands at the boundary, so a run
+    resumed from it continues the uninterrupted run's trajectory (a
+    loader already in the next epoch would shuffle twice)."""
+    from znicz_tpu import snapshotter as snap_mod
+    from znicz_tpu.parallel.fused import FusedTrainer
+    from znicz_tpu.snapshotter import Snapshotter
+
+    def build():
+        return (streaming_mnist(4, budget=0) if staged
+                else fresh_mnist(max_epochs=4))
+
+    root.common.dirs.snapshots = str(tmp_path)
+    wf = build()
+    wf.snapshotter.interval = 1         # an ``epoch_N`` file every epoch
+    losses = []
+    wf.decision.on_epoch_end.append(
+        lambda d: losses.append(d.epoch_metrics[2]["loss"]))
+    trainer = FusedTrainer(wf)
+    trainer.run()
+    assert trainer.stats["tails_in_scan"] == 3
+    want = {f.name: np.array(f.weights.map_read()) for f in wf.forwards}
+    snap = Snapshotter.load(wf.snapshotter.snapshot_path("epoch_1"))
+    assert snap["loader"]["last_minibatch"]
+    assert snap["loader"]["epoch_number"] == 1 == snap["epoch"]
+
+    resumed = []
+    wf2 = build()
+    wf2.decision.on_epoch_end.append(
+        lambda d: resumed.append(d.epoch_metrics[2]["loss"]))
+    snap_mod.restore(wf2, snap)
+    FusedTrainer(wf2).run()
+    assert bool(wf2.decision.complete) and wf2.decision.epoch_number == 3
+    np.testing.assert_allclose(resumed, losses[2:], rtol=1e-4)
+    for f in wf2.forwards:
+        np.testing.assert_allclose(
+            np.array(f.weights.map_read()), want[f.name], rtol=2e-3,
+            atol=2e-5, err_msg=f.name)
+
+
+def test_a_staged_source_predicts_the_segment_that_ends_in_the_tail(
+        tmp_path):
+    """The stager replays the collector's rule, the Decision's word
+    included: a staged run's groups — the epoch's last scan with its tail
+    in it, the last epoch's tail alone — are all predicted (one miss, the
+    run's cold start), and the run ends on the resident run's weights."""
+    from znicz_tpu.parallel.fused import FusedTrainer
+
+    root.common.dirs.snapshots = str(tmp_path)
+    weights = {}
+    for budget in (1 << 30, 0):         # resident, then host-staged
+        wf = streaming_mnist(3, budget)
+        trainer = FusedTrainer(wf)
+        assert trainer.staging == (budget == 0)
+        trainer.run()
+        assert trainer.stats["tails_in_scan"] == 2
+        weights[budget] = {f.name: np.array(f.weights.map_read())
+                           for f in wf.forwards}
+    st = trainer._stager.stats()
+    # an epoch: one validation group, one scan of 4 + the tail; the last:
+    # validation, the scan of 4, the tail alone
+    assert st["stage_hits"] + st["stage_misses"] == 2 + 2 + 3
+    assert st["stage_misses"] <= 1 and st["stage_evictions"] == 0, st
+    for name, want in weights[1 << 30].items():
+        np.testing.assert_array_equal(weights[0][name], want, err_msg=name)

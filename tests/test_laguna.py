@@ -361,8 +361,14 @@ def test_the_sample_trains_through_the_launcher_and_counts(tmp_path,
     steps = stats["train_steps"] + stats["eval_steps"]
     assert stats["moe_counted_steps"] == steps == 3 * (4 + 1)
     assert 0 < stats["moe_rows_routed"] <= 4 * 2 * 128 * steps
-    # train step, train scan, and the evaluation step in both modes
+    # the evaluation step (validation), the scan of 4 that ends in its
+    # epoch's tail (two epochs), and for the run's last tail, which
+    # max_epochs stops: the scan of the 3 before it and the train-mode
+    # evaluation it is ruled on; its update is never run, so no train step
     assert stats["compiles"] == 4
+    sizes = wf.fused_stats["jit_cache_sizes"]
+    assert sizes["_train_step"] == 0 == sizes["_eval_scan"]
+    assert (stats["tails_in_scan"], stats["tails_alone"]) == (2, 1)
     assert any(f.name.endswith(".pickle.gz") or f.name.endswith(".pickle")
                for f in tmp_path.iterdir())
 

@@ -75,9 +75,10 @@ def test_staged_run_ships_each_segment_once_and_matches_resident():
     want, got = weights(wf_r), weights(wf_s)
     for name in want:
         np.testing.assert_array_equal(got[name], want[name], err_msg=name)
-    # an epoch is a 7-step train scan, its tail step and a 2-step
-    # validation scan: each staged exactly once
-    assert sorted(segments) == [1] * 3 + [2] * 3 + [7] * 3, segments
+    # an epoch is a 2-step validation scan and an 8-step train scan that
+    # ends in the tail; the last epoch's tail is ruled on alone (7 + 1):
+    # each staged exactly once
+    assert sorted(segments) == [1] + [2] * 3 + [7] + [8] * 2, segments
     assert len(puts) == 2 * len(segments), (len(puts), len(segments))
 
 

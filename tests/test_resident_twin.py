@@ -211,9 +211,11 @@ def test_programs_on_the_twin_match_raw_rows_bit_for_bit(
 def test_a_run_makes_one_twin_and_lets_it_go(compute_dtype, tmp_path):
     """Two epochs make one twin, in set-up, and the run returns it; a new
     array from the loader gets a new one; the benchmark's ``step_check``
-    call — the tail's compiled ``_train_step`` handed the loader's own
-    array, between runs — runs the tail's program on a twin of its own
-    and compiles nothing."""
+    call — the trainer's ``_train_step`` handed the loader's own array,
+    between runs — runs on a twin of its own.  A job with a validation
+    set whose epochs are not one step over a multiple of ``scan_chunk``
+    never ran that program (its tails ride the scan, the last one is
+    only evaluated): the call compiles it, once, and nothing else."""
     import jax
     import jax.numpy as jnp
 
@@ -252,8 +254,9 @@ def test_a_run_makes_one_twin_and_lets_it_go(compute_dtype, tmp_path):
         copy(jnp.copy, trainer.extract_velocities()), trainer.hypers(),
         raw, wf.loader.original_labels.devmem, idx, np.int32(BATCH),
         prng.get("fused_trainer").jax_key(0))
-    assert trainer.jit_cache_sizes() == sizes
-    assert int(trainer._m_compiles.value) == stats["compiles"]
+    assert sizes["_train_step"] == 0 and stats["tails_in_scan"] == 1
+    assert trainer.jit_cache_sizes() == {**sizes, "_train_step": 1}
+    assert int(trainer._m_compiles.value) == stats["compiles"] + 1
     # a twin for that call alone: a trainer at rest holds no second set
     assert stats["resident_prepares"] == 3 and not trainer._twins
 

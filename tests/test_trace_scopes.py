@@ -284,9 +284,13 @@ def test_leaf_spans_nest_and_dispatches_are_counted(scan_chunk, staged,
         assert tail[5]["parent"] == 0 and tail[5]["epoch"] == i
         leaves = [k[1] for k in sorted(kids[tail[5]["id"]],
                                        key=lambda k: k[2])]
-        # the run's last update is never adopted (gd_skip closed)
-        want = ["tail_eval", "sync", "decide", "tail_update"][:4 - i]
+        # the first epoch's tail rode its scan (the Decision said ahead
+        # that the run goes on): the span is that segment's pull.  The
+        # run's last is ruled on alone and never adopted (gd_skip closed)
+        want = [["sync", "decide"], ["tail_eval", "sync", "decide"]][i]
         assert leaves == want, leaves
+    assert (trainer.stats["tails_in_scan"],
+            trainer.stats["tails_alone"]) == (1, 1)
     for name in ("flush", "eval"):
         for e in (e for e in events if e[1] == name):
             held = [k[1] for k in kids.get(e[5]["id"], [])]
@@ -297,15 +301,15 @@ def test_leaf_spans_nest_and_dispatches_are_counted(scan_chunk, staged,
             "dispatch", "tail_eval", "eval") for e in staging)
     dispatching = [n for n in names if n.split(":")[0] in (
         "dispatch", "eval", "tail_eval", "tail_update")]
-    # from the loader's geometry: each epoch validates, trains its
-    # non-tail minibatches in groups of scan_chunk and ends in a tail of
-    # two programs, the last epoch's in one
+    # from the loader's geometry: each epoch validates and trains its
+    # minibatches, the tail too, in groups of scan_chunk; the last
+    # epoch's tail is one program more, its evaluation
     lengths, batch = trainer.loader.class_lengths, 60
-    per_epoch = (math.ceil(math.ceil(lengths[VALID] / batch) / scan_chunk)
-                 + math.ceil((math.ceil(lengths[TRAIN] / batch) - 1)
-                             / scan_chunk) + 2)
-    assert trainer.stats["dispatches"] == len(dispatching) \
-        == 2 * per_epoch - 1
+    validating = math.ceil(math.ceil(lengths[VALID] / batch) / scan_chunk)
+    training = math.ceil(lengths[TRAIN] / batch)
+    assert trainer.stats["dispatches"] == len(dispatching) == (
+        2 * validating + math.ceil(training / scan_chunk)
+        + math.ceil((training - 1) / scan_chunk) + 1)
     # each kind's first call is not warm
     assert 0 < trainer.stats["warm_dispatches"] < len(dispatching)
     steps = [e[5]["step0"] for e in events

@@ -96,6 +96,43 @@ class DecisionBase(Unit):
     def improvement_metric(self) -> float:
         return self._class_metric(self._validation_class())
 
+    # -- the stop rule ---------------------------------------------------------
+
+    def _rule(self, epoch_number: int, improved: Optional[bool] = None):
+        """``(improved, fails, done)`` of an epoch that ends now with the
+        metric accumulated so far (or with ``improved`` as given): the one
+        home of the stop rule.  ``run()`` adopts it at the epoch's tail;
+        ``tail_stops()`` asks it ahead.  Reads, changes nothing."""
+        if improved is None:
+            improved = self.improvement_metric() < self.best_metric - 1e-12
+        fails = 0 if improved else self._fails + 1
+        done = bool(epoch_number + 1 >= self.max_epochs or
+                    (self.fail_iterations and
+                     fails >= self.fail_iterations))
+        return improved, fails, done
+
+    def tail_stops(self, epoch_number: int,
+                   validated: bool = True) -> Optional[bool]:
+        """Asked BEFORE the tail of epoch ``epoch_number`` is fed: will
+        ``run()`` raise ``complete`` there?  ``False`` the run goes on (the
+        tail's update will be adopted), ``True`` it stops, ``None`` cannot
+        say: improvement is judged on TRAIN, so the tail's own loss
+        decides.  With a validation set the answer is exact once this
+        epoch's VALID minibatches are fed — the loader serves them before
+        TRAIN and no TRAIN minibatch moves what the rule reads.  Asked
+        earlier still (``validated`` False: VALID minibatches of this
+        epoch are yet to be fed) it answers where the rule says the same
+        whichever way the metric falls, as it does with no
+        ``fail_iterations``.  A stop that an ``on_epoch_end`` callback
+        asks for is not the rule's and cannot be seen here."""
+        if self._validation_class() == TRAIN:
+            return None
+        if validated:
+            return self._rule(epoch_number)[2]
+        either = {self._rule(epoch_number, improved)[2]
+                  for improved in (True, False)}
+        return either.pop() if len(either) == 1 else None
+
     # -- run ------------------------------------------------------------------
 
     def run(self):
@@ -105,18 +142,11 @@ class DecisionBase(Unit):
         if self.class_ended:
             self.epoch_metrics[klass] = self._summarize(klass)
         if self.last_minibatch:            # end of TRAIN == end of epoch
-            metric = self.improvement_metric()
-            if metric < self.best_metric - 1e-12:
-                self.best_metric = metric
+            improved, self._fails, done = self._rule(self.epoch_number)
+            if improved:
+                self.best_metric = self.improvement_metric()
                 self.best_epoch = int(self.epoch_number)
-                self.improved.set(True)
-                self._fails = 0
-            else:
-                self.improved.set(False)
-                self._fails += 1
-            done = (self.epoch_number + 1 >= self.max_epochs or
-                    (self.fail_iterations and
-                     self._fails >= self.fail_iterations))
+            self.improved.set(improved)
             self.complete.set(done)
             self.epoch_ended.set(True)
             self.epoch_history.append(

@@ -260,7 +260,11 @@ class FusedTrainer:
                       "epoch_hook_s": 0.0,
                       # twins made of a resident set (``_resident``): one
                       # a run where the set needs one, none in steady state
-                      "resident_prepares": 0}
+                      "resident_prepares": 0,
+                      # epoch tails that rode the epoch's last scan (the
+                      # Decision said beforehand that the run goes on) and
+                      # those evaluated, ruled on and updated alone
+                      "tails_in_scan": 0, "tails_alone": 0}
         workflow.fused_stats = self.stats
         # telemetry (ISSUE 5): hot-loop metrics + spans.  The histogram
         # observes and the spans record only while telemetry is enabled
@@ -284,6 +288,13 @@ class FusedTrainer:
         self._m_resident_prepares = _sc.counter(
             "resident_prepares", "resident sets laid out for their gather "
             "(a whole-set pass each: once per set, never per dispatch)")
+        self._m_tails = {
+            "tails_in_scan": _sc.counter(
+                "tails_in_scan", "epoch tails dispatched as the last step "
+                "of the epoch's last scan segment"),
+            "tails_alone": _sc.counter(
+                "tails_alone", "epoch tails evaluated, ruled on and "
+                "updated alone (a stop, or no validation set)")}
         self._m_step_seconds = _sc.histogram(
             "step_seconds", "per-step wall time (pipelined intervals)",
             size=4096)
@@ -1217,9 +1228,10 @@ class FusedTrainer:
     def make_eval_step(self):
         """Metrics-only step.  ``train`` is static: True replays the exact
         train-mode forward (dropout/stochastic masks from the same key) —
-        used at epoch tails to let the Decision rule on this minibatch's
-        metrics BEFORE the update is adopted, matching the unit path where
-        gd_skip gates the final update off once ``complete`` flips."""
+        used at an epoch tail that runs alone (``_run_segmented``) to let
+        the Decision rule on this minibatch's metrics BEFORE the update
+        is adopted, matching the unit path where gd_skip gates the final
+        update off once ``complete`` flips."""
         compiles = self._m_compiles
         psh, _, repl = self._state_shardings()
 
@@ -1238,9 +1250,9 @@ class FusedTrainer:
 
     # -- the epoch driver ------------------------------------------------------
 
-    #: scan this many consecutive TRAIN steps per dispatch (the epoch tail
-    #: and eval minibatches always go one-at-a-time, preserving the
-    #: Decision's gd_skip semantics).  1 disables scanning.
+    #: scan this many consecutive TRAIN steps per dispatch, or eval
+    #: steps of one class (an epoch tail the Decision cannot let ride goes
+    #: alone, preserving its gd_skip semantics).  1 disables scanning.
     scan_chunk = 8
 
     def _advance(self):
@@ -1331,6 +1343,11 @@ class FusedTrainer:
                             self._counter_gauges[key] = self._scope.gauge(
                                 key, "counted on the device by the units")
                         self._counter_gauges[key].set(float(v))
+
+    def _book_tail(self, which) -> None:
+        """An epoch tail dispatched: ``tails_in_scan`` or ``tails_alone``."""
+        self.stats[which] += 1
+        self._m_tails[which].inc()
 
     def _reset_accounting(self):
         self._acct_seen = set()
@@ -1623,12 +1640,27 @@ class FusedTrainer:
         Two host-sync profiles, identical training semantics:
 
           - default (``pipeline_depth`` 1; what the three cells run):
-            consecutive non-tail TRAIN minibatches run as ONE ``lax.scan``
+            consecutive TRAIN minibatches run as ONE ``lax.scan``
             dispatch of up to ``scan_chunk`` steps, with a one-deep flush
-            pipeline; epoch tails and eval feed the Decision
-            synchronously, so epoch-granular consumers — snapshotter,
-            plotters, an ``on_epoch_end`` callback — see every epoch when
-            it ends;
+            pipeline; eval and each epoch's last segment feed the
+            Decision synchronously, so epoch-granular consumers —
+            snapshotter, plotters, an ``on_epoch_end`` callback — see
+            every epoch when it ends.  The epoch's last TRAIN minibatch
+            (the tail, on which ``complete`` may flip and whose update is
+            then not adopted: ``gd_skip``) is the last step of that
+            segment wherever the Decision can say BEFOREHAND that the run
+            goes on (``Decision.tail_stops``: exact with a validation
+            set, where the stop rule reads nothing of the tail's own);
+            where it says stop, or cannot say (no validation set), the
+            tail is evaluated, ruled on and only then updated, alone.  So
+            the Decision's own stops (``max_epochs``,
+            ``fail_iterations``) leave the unit engine's stopping state.
+            **A stop that an ``on_epoch_end`` callback asks for by
+            raising ``complete`` cannot be seen ahead: where that epoch's
+            tail rode the scan its update IS applied when the callback
+            runs** (the unit engine, and this loop without a validation
+            set, skip it).  Every step's mathematics is the same; which
+            step is such a run's last moves by one;
           - deep (``pipeline_depth`` > 1 and ``_deep_eligible``): whole
             epochs as single dispatches, metrics pulled one fused transfer
             per epoch, up to ``2 * depth`` epochs late.  What it buys is
@@ -1801,47 +1833,63 @@ class FusedTrainer:
                     return stager.take(rows)
                 return self._stage_direct(rows, put)
 
-        def upcoming_segments():
+        def scans(mb, fed=True):
+            """The TRAIN side of the segment-collection rule, for the
+            dispatch loop and the stager's replay of it: a TRAIN
+            minibatch runs as a step of a scan segment unless it is an
+            epoch tail whose update the Decision cannot promise
+            beforehand (a stop, or no validation set to judge on:
+            ``Decision.tail_stops``).  A tail that scans ends its
+            segment.  ``fed`` False (the replay's: an eval minibatch
+            served before ``mb`` is not fed yet) gives ``None`` where the
+            answer waits for that feed."""
+            if mb["class"] != TRAIN or not mb["last_minibatch"]:
+                return mb["class"] == TRAIN
+            stops = decision.tail_stops(mb["epoch_number"], validated=fed)
+            return None if stops is None and not fed else stops is False
+
+        def upcoming_segments(fed):
             """The dispatch groups the loop WILL form from the fifo — the
             segment-collection rules replayed without consuming: TRAIN
-            segments (consecutive non-tail, up to scan_chunk), eval runs
-            (same class, up to scan_chunk), the tail as its own group.
-            Stops at the first group whose boundary the fifo cannot
-            prove yet (the lookahead refill will)."""
-            from znicz_tpu.loader.base import TRAIN as _TRAIN
-
+            segments (consecutive ``scans`` minibatches, up to scan_chunk,
+            ended by a tail), eval runs (same class, up to scan_chunk), a
+            tail that does not scan as its own group.  Stops at the first
+            group whose boundary the fifo cannot prove yet (the lookahead
+            refill will), and at a tail the Decision cannot rule on yet:
+            ``fed`` says whether it has every eval minibatch served so
+            far, and an eval group in the fifo ends that."""
             groups, i, n = [], 0, len(fifo)
             while i < n:
                 m = fifo[i]
-                if m["class"] == _TRAIN and m["last_minibatch"]:
-                    groups.append([m])          # the tail dispatches alone
-                    i += 1
-                    continue
-                is_train = m["class"] == _TRAIN
+                is_train = m["class"] == TRAIN
                 scan = self._train_scan if is_train else self._eval_scan
                 cap = self.scan_chunk if scan else 1
                 seg = [m]
                 i += 1
-                while i < n and len(seg) < cap:
+                while i < n and len(seg) < cap \
+                        and not seg[-1]["last_minibatch"]:
                     nxt = fifo[i]
-                    same = (nxt["class"] == _TRAIN
-                            and not nxt["last_minibatch"]
-                            if is_train else nxt["class"] == m["class"])
-                    if not same:
+                    joins = (scans(nxt, fed) if is_train
+                             else nxt["class"] == m["class"])
+                    if joins is None:
+                        return groups           # the Decision cannot say yet
+                    if not joins:
                         break
                     seg.append(nxt)
                     i += 1
-                if len(seg) < cap and i >= n:
+                if len(seg) < cap and i >= n \
+                        and not seg[-1]["last_minibatch"]:
                     break                       # boundary not proven
+                fed = fed and is_train
                 groups.append(seg)
             return groups
 
-        def submit_upcoming():
+        def submit_upcoming(fed=True):
             """Start staging the provable upcoming groups, oldest first,
             until the ping-pong is full (``stager.depth``)."""
             if stager is None:
                 return
-            for seg in upcoming_segments():
+            for seg in upcoming_segments(fed):
                 if stager.outstanding >= stager.depth:
                     break
                 stager.submit([s["idx"] for s in seg])
@@ -1899,18 +1947,24 @@ class FusedTrainer:
             AFTER the next segment is dispatched, so the host round-trip
             overlaps device compute (one-deep pipeline); non-tail TRAIN
             feeds cannot flip `complete`/`gd_skip`, so deferring them one
-            segment changes no control flow — tails/eval flush first.
-            Confusion stays on device (``epoch_conf``), transferred once
-            at the epoch tail."""
+            segment changes no control flow — eval and a tail alone flush
+            first, and a segment that holds a tail is flushed as soon as
+            it is dispatched.  Confusion stays on device
+            (``epoch_conf``), handed over once with the epoch tail."""
             nonlocal inflight, epoch_conf
             if inflight is None:
                 return
             seg, kind, res, t0, step0 = inflight
             inflight = None
             # the host-sync span: waiting out the previous dispatch's
-            # device work + pulling its metrics, then the Decision
-            with span("train", "flush", steps=len(seg), kind=kind,
-                      step0=step0):
+            # device work + pulling its metrics, then the Decision.  A
+            # segment that holds the epoch's tail is the epoch's end: its
+            # flush is the ``tail`` span (what the chips wait for there)
+            tail = seg[-1]["last_minibatch"]
+            name, args = (("tail", {"epoch": int(seg[-1]["epoch_number"])})
+                          if tail else ("flush", {}))
+            with span("train", name, steps=len(seg), kind=kind, step0=step0,
+                      **args):
                 if kind == "single":
                     loss, n_err, conf, *counted = res
                     epoch_conf = conf if epoch_conf is None \
@@ -1925,6 +1979,10 @@ class FusedTrainer:
                     losses, n_errs, *counted = self._sync(*ms)
                     stacked = [(losses[i], n_errs[i], None)
                                for i in range(len(seg))]
+                if tail:
+                    # the epoch's confusion sum rides with the tail's feed
+                    stacked[-1] = (*stacked[-1][:2], epoch_conf)
+                    epoch_conf = None
                 self._book_counted(counted)
                 with self._timed("decide_s", "decide"):
                     for s, m in zip(seg, stacked):
@@ -1938,22 +1996,25 @@ class FusedTrainer:
                 with span("train", "advance"):
                     mb = take_mb()
                 is_train = (mb["class"] == TRAIN)
-                if is_train and not mb["last_minibatch"]:
-                    # collect the segment of consecutive non-tail TRAIN
-                    # minibatches (they cannot flip `complete`) and run it
-                    # as one scan dispatch
+                if scans(mb):
+                    # collect the segment of consecutive TRAIN minibatches
+                    # that cannot flip `complete` — the non-tail ones, and
+                    # the tail where the Decision says beforehand that the
+                    # run goes on — and run it as one scan dispatch
                     seg = [mb]
                     max_seg = self.scan_chunk if self._train_scan else 1
                     with span("train", "advance", steps=max_seg):
-                        while len(seg) < max_seg:
+                        while len(seg) < max_seg \
+                                and not seg[-1]["last_minibatch"]:
                             nxt = take_mb()
-                            if nxt["class"] == TRAIN and \
-                                    not nxt["last_minibatch"]:
+                            if scans(nxt):
                                 seg.append(nxt)
                             else:
                                 fifo.appendleft(nxt)
                                 break
-                        extend_lookahead()  # future segments' decode starts
+                        holds_tail = seg[-1]["last_minibatch"]
+                        if not holds_tail:  # (the loader stays in the epoch)
+                            extend_lookahead()  # future segments' decode
                         if stager is not None:
                             submit_upcoming()
                     if stager is not None:
@@ -2020,10 +2081,19 @@ class FusedTrainer:
                     if stager is None:
                         flush()         # previous segment, AFTER dispatch
                     inflight = (seg, kind, result, t_iter, step0)
+                    if holds_tail:
+                        # the epoch ends in this segment: pull it and let
+                        # the Decision rule NOW, before the loader moves on
+                        # — the epoch-end hook below sees the tail's state
+                        self._book_tail("tails_in_scan")
+                        flush()
                 elif is_train:
+                    self._book_tail("tails_alone")
                     flush()
-                    # epoch tail: metrics first, Decision rules, and the
-                    # update applies only if gd_skip stayed open
+                    # an epoch tail the Decision could not let ride (a
+                    # stop, or no validation set: at most once a job
+                    # where there is one): metrics first, Decision rules,
+                    # and the update applies only if gd_skip stayed open
                     # (unit-path parity).  The epoch's device-side
                     # confusion sum rides along in this one transfer.
                     # The leaves say which of these the device waits for.
@@ -2097,8 +2167,9 @@ class FusedTrainer:
                             # the upcoming groups stage while this eval
                             # segment computes (the eval/train boundary is
                             # where each epoch's first train segment would
-                            # otherwise pay the full assembly inline)
-                            submit_upcoming()
+                            # otherwise pay the full assembly inline); its
+                            # metrics are not fed yet
+                            submit_upcoming(fed=False)
                         # segment confusion fed once, with the first step
                         if staging:
                             dseg, tseg = stage_segment(seg)
