@@ -29,6 +29,15 @@ from znicz_tpu.ops.attention import (apply_rope, blocked_attention,
 ref = spec.load_module("references", "laguna")
 driver = spec.load_module("drivers", "train_tokens")
 
+#: what holds for every decoder is one test with a case a family (ISSUE
+#: 32): the cell, its driver and reference, the sample, its layers
+FAMILIES = {
+    "laguna": {"cell": "laguna-train-8k", "driver": "train_tokens",
+               "reference": "laguna", "layers": 5, "sparse": 4, "top_k": 2},
+    "zaya": {"cell": "zaya-train-32k", "driver": "train_tokens_blocked",
+             "reference": "zaya", "layers": 4, "sparse": 4, "top_k": 1},
+}
+
 
 def rel(got, want):
     got, want = (np.asarray(t, np.float64) for t in (got, want))
@@ -91,11 +100,15 @@ def test_a_block_that_does_not_divide_the_sequence_falls_back_whole():
                ref.attention(q, k, v, None, 48)) < 2e-6
 
 
-@pytest.mark.parametrize("kind", ["full_attention", "sliding_attention"])
+@pytest.mark.parametrize("kind", ["full_attention", "sliding_attention",
+                                  "hybrid"])
 def test_rotary_tables_match_the_reference(kind):
     from znicz_tpu.samples import laguna
 
-    for model in laguna.MODELS.values():
+    models = [m for m in laguna.MODELS.values()
+              if kind in m["rope_parameters"]]
+    assert len(models) == 2             # the published one and its tiny
+    for model in models:
         rope = laguna.rope_of(model, kind)
         cos, sin = rope_tables(96, rope["rotary_dim"], rope["theta"],
                                rope.get("yarn"))
@@ -238,15 +251,23 @@ def test_adamw_update_is_the_reference_rule(step, decay):
 
 
 @pytest.fixture()
-def tiny_job(tmp_path, restore_root):
-    """The tiny preset built as the benchmark's driver builds the cell."""
-    cell = spec.Cell(spec.load(), "laguna-train-8k")
-
-    def build(seed=11):
-        return driver.build(cell, seed, True)
-
+def family_job(tmp_path, restore_root):
+    """``build(family)``: a family's tiny preset built as the benchmark's
+    driver builds its cell."""
     root.common.dirs.snapshots = str(tmp_path)
-    return cell, build
+
+    def build(family, seed=11):
+        cell = spec.Cell(spec.load(), FAMILIES[family]["cell"])
+        return cell, driver.build(cell, seed, True)
+
+    return build
+
+
+@pytest.fixture()
+def tiny_job(family_job):
+    """The Laguna cell's tiny preset."""
+    cell = spec.Cell(spec.load(), "laguna-train-8k")
+    return cell, lambda seed=11: driver.build(cell, seed, True)
 
 
 def test_system_matches_reference_logits_loss_gradient_and_adamw(tiny_job):
@@ -280,12 +301,13 @@ def test_system_matches_reference_logits_loss_gradient_and_adamw(tiny_job):
             assert err < (5e-2 if kind == "update" else 2e-3), (group, kind)
 
 
-def test_integer_ids_reach_the_embedding_unchanged_under_bf16(tiny_job):
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_integer_ids_reach_the_embedding_unchanged_under_bf16(family,
+                                                              family_job):
     """The resident twin, the gather and the compute-dtype cast leave
     int32 ids alone: what the embedding looks up under bf16 compute is
     what the loader holds."""
-    cell, build = tiny_job
-    built = build()
+    _, built = family_job(family)
     wf, trainer = built.wf, built.trainer
     assert str(trainer.compute_dtype) == "bfloat16"
     raw = wf.loader.original_data.devmem
@@ -315,9 +337,19 @@ def test_integer_ids_reach_the_embedding_unchanged_under_bf16(tiny_job):
     np.testing.assert_array_equal(got, np.asarray(raw)[idx])
 
 
-def test_decay_skips_norms_gates_and_the_router(tiny_job):
-    cell, build = tiny_job
-    built = build()
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_decay_skips_what_the_reference_says_it_skips(family, family_job):
+    """``decay_exempt`` is derived from the layer's keys: it lists what
+    the reference's ``NO_DECAY`` lists among the unit's tensors (norms,
+    gates and a linear router; scales, shifts, temperatures, biases and
+    ``gamma``), and an update with a zero gradient decays exactly the
+    others."""
+    _, built = family_job(family)
+    reference = spec.load_module("references", FAMILIES[family]["reference"])
+    renamed = {"weights": "head"}       # the untied head's own tensor
+    for f in built.wf.forwards:
+        assert {renamed.get(k, k) for k in f.decay_exempt} == {
+            renamed.get(k, k) for k in f.params()} & set(reference.NO_DECAY)
     sparse = next(f for f in built.wf.forwards
                   if getattr(f, "sparse", False))
     gd = built.trainer.gd_of[sparse.name]
@@ -328,23 +360,25 @@ def test_decay_skips_norms_gates_and_the_router(tiny_job):
     hypers = tuple(np.float32(v) for v in (0.5, 0.1, 0.9, 0.95, 1e-8))
     new_p, new_s = gd.apply_update(params, grads, state, hypers)
     for key, w in new_p.items():
-        want = 1.0 if key in ("norm_attn", "norm_ffn", "w_gate",
-                              "router") else 0.95
+        want = 1.0 if key in reference.NO_DECAY else 0.95
         np.testing.assert_allclose(w, want, rtol=1e-6, err_msg=key)
     assert int(new_s["step"]) == 1
 
 
-def test_the_sample_trains_through_the_launcher_and_counts(tmp_path,
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_the_sample_trains_through_the_launcher_and_counts(family, tmp_path,
                                                            restore_root):
     """``python -m znicz_tpu <sample>``'s path: StandardWorkflow ->
     FusedTrainer.run with loader, Decision and snapshotter; tokens and the
-    expert layers' counters land in ``fused_stats``; nothing recompiles."""
+    expert layers' counters land in ``fused_stats``; nothing recompiles,
+    schedule or not."""
     from znicz_tpu.launcher import Launcher
 
+    fam = FAMILIES[family]
     launcher = Launcher([
-        os.path.join(REPO, "znicz_tpu", "samples", "laguna.py"),
-        "--backend", "cpu", "root.laguna.preset=tiny",
-        "root.laguna.decision.max_epochs=3",
+        os.path.join(REPO, "znicz_tpu", "samples", f"{family}.py"),
+        "--backend", "cpu", f"root.{family}.preset=tiny",
+        f"root.{family}.decision.max_epochs=3",
         f"root.common.dirs.snapshots={tmp_path}"])
     assert launcher.run() == 0
     wf = launcher.workflow
@@ -357,10 +391,11 @@ def test_the_sample_trains_through_the_launcher_and_counts(tmp_path,
     rows = stats["moe_rows_by_expert"]
     assert rows["max"] >= rows["mean"] >= rows["min"] >= 0
     # every step whose loss the host pulled is counted, validation too;
-    # 4 expert layers, 2 a token, 128 tokens a step
+    # 4 expert layers, top_k a token, 128 tokens a step
     steps = stats["train_steps"] + stats["eval_steps"]
     assert stats["moe_counted_steps"] == steps == 3 * (4 + 1)
-    assert 0 < stats["moe_rows_routed"] <= 4 * 2 * 128 * steps
+    assert 0 < stats["moe_rows_routed"] <= (
+        fam["sparse"] * fam["top_k"] * 128 * steps)
     # the evaluation step (validation), the scan of 4 that ends in its
     # epoch's tail (two epochs), and for the run's last tail, which
     # max_epochs stops: the scan of the 3 before it and the train-mode
@@ -369,8 +404,23 @@ def test_the_sample_trains_through_the_launcher_and_counts(tmp_path,
     sizes = wf.fused_stats["jit_cache_sizes"]
     assert sizes["_train_step"] == 0 == sizes["_eval_scan"]
     assert (stats["tails_in_scan"], stats["tails_alone"]) == (2, 1)
+    assert stats["attn_cores_composed"] == fam["layers"]
     assert any(f.name.endswith(".pickle.gz") or f.name.endswith(".pickle")
                for f in tmp_path.iterdir())
+    if family == "zaya":
+        assert stats["router_states_carried"] == 3
+        # the tiny preset's head runs its 128 rows in 2 blocks
+        assert (stats["tied_tensors"], stats["loss_blocks"]) == (1, 2)
+        assert stats["router_biases_moved"] == 4
+        # 11 updates were applied (max_epochs stops the run at its last
+        # tail, whose update is not adopted: the schedule is gated like
+        # the update); the 12th would run at 12 / 2,000 of the rate
+        assert float(wf.gds[0].learning_rate) == pytest.approx(
+            3e-4 * 12 / 2000)
+    else:
+        assert "router_states_carried" not in stats
+        assert stats["tied_tensors"] == 0
+        assert float(wf.gds[0].learning_rate) == pytest.approx(3e-4)
 
 
 def test_rows_no_group_computed_never_reach_result_or_gradient(monkeypatch):

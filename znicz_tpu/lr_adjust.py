@@ -6,6 +6,8 @@ Caffe-style policies applied to GD units over training iterations:
   - ``step``      — lr(t) = base · gamma^floor(t / step)
   - ``exp``       — lr(t) = base · gamma^t
   - ``inv``       — lr(t) = base · (1 + gamma·t)^(−power)
+  - ``warmup``    — train step s runs at base · min(1, (s + 1) / steps):
+                    a linear warm-up from the job's FIRST step
   - ``arbitrary`` — lr(t) = fn(base, t)
 
 ``LearningRateAdjust`` sits in the control graph after the GD chain (or the
@@ -13,6 +15,10 @@ decision in fused mode), counts train iterations, and writes the scheduled
 lr into each bound GD unit's ``learning_rate``/``learning_rate_bias`` —
 which both execution paths read per step (the fused step takes hypers as
 traced arguments precisely so this never recompiles).
+
+The clock: ``policy(base, it)`` is written after train step ``it`` and so
+is the rate of step ``it + 1``; step 0 runs at ``policy.first(base)``,
+which ``initialize`` writes — the base itself for every Caffe policy.
 """
 
 from __future__ import annotations
@@ -25,6 +31,10 @@ from znicz_tpu.core.units import Unit
 class LRPolicyBase:
     def __call__(self, base: float, it: int) -> float:
         raise NotImplementedError
+
+    def first(self, base: float) -> float:
+        """The rate of train step 0, before any step was counted."""
+        return base
 
 
 class FixedPolicy(LRPolicyBase):
@@ -56,6 +66,23 @@ class InvPolicy(LRPolicyBase):
         return base * (1.0 + self.gamma * it) ** (-self.power)
 
 
+class WarmupPolicy(LRPolicyBase):
+    """Linear warm-up: train step ``s`` runs at ``base * (s + 1) / steps``
+    while ``s < steps`` and at ``base`` after."""
+
+    def __init__(self, steps=2000):
+        self.steps = int(steps)
+
+    def rate(self, base, step):
+        return base * min(1.0, (step + 1) / self.steps)
+
+    def __call__(self, base, it):       # written after step ``it``
+        return self.rate(base, it + 1)
+
+    def first(self, base):
+        return self.rate(base, 0)
+
+
 class ArbitraryPolicy(LRPolicyBase):
     def __init__(self, fn: Callable[[float, int], float]):
         self.fn = fn
@@ -65,7 +92,7 @@ class ArbitraryPolicy(LRPolicyBase):
 
 
 POLICIES = {"fixed": FixedPolicy, "step": StepPolicy, "exp": ExpPolicy,
-            "inv": InvPolicy}
+            "inv": InvPolicy, "warmup": WarmupPolicy}
 
 
 def make_policy(name: str, **kwargs) -> LRPolicyBase:
@@ -88,6 +115,16 @@ class LearningRateAdjust(Unit):
             (gd, float(gd.learning_rate), float(gd.learning_rate_bias),
              policy, bias_policy or policy))
 
+    def _apply_first(self) -> None:
+        for gd, base, base_bias, pol, bias_pol in self._bindings:
+            gd.learning_rate = pol.first(base)
+            gd.learning_rate_bias = bias_pol.first(base_bias)
+
+    def initialize(self, **kwargs):
+        super().initialize(**kwargs)
+        if self.iteration == 0:
+            self._apply_first()
+
     def _apply(self, it: int) -> None:
         for gd, base, base_bias, pol, bias_pol in self._bindings:
             gd.learning_rate = pol(base, it)
@@ -106,6 +143,4 @@ class LearningRateAdjust(Unit):
         if self.iteration > 0:
             self._apply(self.iteration - 1)
         else:
-            for gd, base, base_bias, _pol, _bias_pol in self._bindings:
-                gd.learning_rate = base
-                gd.learning_rate_bias = base_bias
+            self._apply_first()

@@ -1,5 +1,8 @@
-"""The expert layer's ops: a router over all the experts of the model, and
-the part of the layer's result that the experts HELD here give.
+"""The expert layer's ops: a router over all the experts of the model (a
+linear map with sigmoid scores, ``route``; or a small MLP on a state that
+one layer hands the next, ``router_state`` / ``route_mlp``, with a
+selection bias that each step's load moves, ``balance_step``), and the
+part of the layer's result that the experts HELD here give.
 
 An expert-parallel deployment spreads a layer's routed experts over chips.
 Each chip routes its tokens over all of them (the router keeps its
@@ -30,6 +33,76 @@ def route(x, w_router, top_k: int, scale: float):
                                preferred_element_type=jnp.float32))
     top, experts = jax.lax.top_k(s, top_k)
     return experts, scale * top / jnp.sum(top, axis=-1, keepdims=True)
+
+
+def router_state(x, w_down, b_down, gamma=None, carried=None):
+    """The router's state of one layer: ``x @ w_down + b_down`` (the
+    hidden stream DOWN to the router's width), plus ``gamma *`` the state
+    the layer before handed on, where there is one.  The result is both
+    what this layer's router reads and what the next layer receives."""
+    r = x @ w_down + b_down
+    if carried is not None:
+        r = r + gamma * carried
+    return r
+
+
+def route_mlp(r, norm, w1, b1, w2, b2, w3, bias, top_k: int, eps: float):
+    """``(experts, weights, move)`` from the router's state ``r``
+    ``(tokens, width)``: scores ``w3 . gelu(w2 . gelu(w1 . RMSNorm(r) +
+    b1) + b2)`` over ALL the model's experts, a softmax ``p`` in float32,
+    the ``top_k`` experts with the largest ``p + bias`` chosen
+    (``experts`` ``(tokens, top_k)``), each one's probability its weight
+    (no renormalisation over the chosen; no gradient reaches ``bias``
+    ``(experts,)``), and the move that this step's load asks of the bias
+    (``balance_step``)."""
+    import jax
+    import jax.numpy as jnp
+
+    r32 = r.astype(jnp.float32)
+    rn = (r32 * jax.lax.rsqrt(jnp.mean(jnp.square(r32), axis=-1,
+                                       keepdims=True) + eps)
+          * norm.astype(jnp.float32)).astype(r.dtype)
+    h = jax.nn.gelu(rn @ w1 + b1, approximate=False)
+    h = jax.nn.gelu(h @ w2 + b2, approximate=False)
+    p = jax.nn.softmax(jnp.dot(h, w3, preferred_element_type=jnp.float32),
+                       axis=-1)
+    biased = jax.lax.stop_gradient(p) + bias.astype(jnp.float32)
+    experts = jax.lax.top_k(biased, top_k)[1]
+    return (experts, jnp.take_along_axis(p, experts, axis=-1),
+            balance_step(biased, top_k))
+
+
+#: how much of the move that would even one expert's load, the others'
+#: biases held, a step makes: every expert moves at once, and where two
+#: trade tokens both moves count, so half is the whole for a pair
+BALANCE_DAMPING = 0.5
+
+
+def balance_step(biased, top_k: int):
+    """The move ``(experts,)`` float32 of a selection bias that this
+    step's load asks for, from the scores the choice was made by
+    (``biased`` ``(tokens, experts)``: probability plus bias).  Expert
+    ``e``'s margin at a token is its score less the best score among the
+    others that decides whether ``e`` is chosen there (the ``top_k``-th
+    best of the others).  Lowering ``e``'s bias by the ``tokens * top_k /
+    experts``-th largest margin would, the others held, leave it exactly
+    its even share of the rows; the step is ``BALANCE_DAMPING`` of that,
+    centred (a common shift chooses nothing).  Counts alone would not say
+    how FAR to move: the scores' scale is the router's, 1e-4 at seeded
+    weights and 1e-1 in a trained one, and a fixed step is too coarse for
+    the first or too slow for the second."""
+    import jax
+    import jax.numpy as jnp
+
+    tokens, experts = biased.shape
+    top = jax.lax.top_k(biased, top_k + 1)[0]
+    chosen = biased >= top[:, top_k - 1:top_k]
+    margin = biased - jnp.where(chosen, top[:, top_k:],
+                                top[:, top_k - 1:top_k])
+    share = max(tokens * top_k // experts, 1)
+    even = jnp.sort(margin.T, axis=-1)[:, tokens - share]
+    step = -BALANCE_DAMPING * even
+    return step - jnp.mean(step)
 
 
 def dispatch(experts, first_expert: int, experts_held: int):

@@ -563,7 +563,7 @@ class FusedTrainer:
     # -- the pure step ---------------------------------------------------------
 
     def forward_pass(self, params, x, key, train: bool, cast=None,
-                     counted=None):
+                     counted=None, loss_of=None, hidden=False, moved=None):
         """Compose the units' pure applies; returns the last unit's output
         (LOGITS for a softmax last layer — loss and probs both derive from
         them, matching the evaluator's math).  ``cast`` re-casts activations
@@ -586,15 +586,27 @@ class FusedTrainer:
         absorbed unit's own ``fold_in(key, i)`` draw, so masks are
         bit-identical to the unit path's).
 
-        Two things are observed from the units.  A unit with
-        ``apply_counted`` returns ``(y, counters)``; the counters go into
-        the dict ``counted`` under the unit's name where the caller hands
-        one in (``loss_and_metrics`` does, and returns them with the
-        step's metrics).  In training a unit is rematerialised
-        (``jax.checkpoint`` around that unit alone: its input is all the
-        backward pass keeps of it) where it asks for that (``remat =
-        True`` on the unit) — per unit, so that what is live in the
-        backward pass is one unit's activations, not the network's."""
+        What is observed from the units.  A unit with ``apply_carried``
+        returns ``(y, carry, counters)``: the counters go into the dict
+        ``counted`` under the unit's name where the caller hands one in
+        (``loss_and_metrics`` does, and returns them with the step's
+        metrics); ``carry`` is what the unit hands the next one BESIDE its
+        output (``None`` until a unit makes one; units without
+        ``apply_carried`` neither see nor drop it).  An entry ``moves`` of
+        the counters — ``{tensor key: step}``, what a train step adds to
+        tensors of the unit that the loss has no gradient for — goes into
+        the dict ``moved`` instead (``_update_core`` hands one in and
+        applies it; an evaluation hands none, and the steps are never
+        computed).  A unit that ``borrowed`` a tensor gets the owning
+        unit's under its own key (a tied head).  A last unit with
+        ``apply_loss`` takes the loss itself where ``loss_of`` —
+        ``(targets, batch size)`` — is handed in: the result is then
+        ``(loss sum, errors)``, not logits.  ``hidden`` stops before the
+        last unit and returns what it would be given.  In training a unit
+        is rematerialised (``jax.checkpoint`` around that unit alone: its
+        input is all the backward pass keeps of it) where it asks for that
+        (``remat = True`` on the unit) — per unit, so that what is live in
+        the backward pass is one unit's activations, not the network's."""
         import jax
 
         from znicz_tpu.ops.linear import linear
@@ -606,18 +618,20 @@ class FusedTrainer:
 
         plan = plan_fused_blocks(self.forwards)
         tail_plan = plan_fused_tail(self.forwards, plan)
-        h = x
+        h, carry = x, None
         last = self.forwards[-1]
         i = 0
-        while i < len(self.forwards):
+        while i < len(self.forwards) - bool(hidden):
             f = self.forwards[i]
             p = params.get(f.name, {})
+            for k, (owner, theirs) in getattr(f, "borrowed", {}).items():
+                p = dict(p, **{k: params[owner][theirs]})
             blk = plan.get(i)
             tl = tail_plan.get(i) if blk is None else None
             span = blk.span if blk is not None else (
                 tl.span if tl is not None else 1)
 
-            def unit(p, h, i=i, f=f, blk=blk, tl=tl):
+            def unit(p, h, carry, i=i, f=f, blk=blk, tl=tl):
                 counters = {}
                 if blk is not None:
                     h = f.apply_linear(p, h)
@@ -677,13 +691,16 @@ class FusedTrainer:
 
                     h = seq_linear(h, p["weights"], p.get("bias"),
                                    weights_transposed=f.weights_transposed)
+                elif f is last and loss_of is not None \
+                        and hasattr(f, "apply_loss"):
+                    h = f.apply_loss(p, h, *loss_of)
                 elif f is last and hasattr(f, "apply_logits"):
                     h = f.apply_logits(p, h)
-                elif hasattr(f, "apply_counted"):
-                    h, counters = f.apply_counted(p, h)
+                elif hasattr(f, "apply_carried"):
+                    h, carry, counters = f.apply_carried(p, h, carry)
                 else:
                     h = f.apply(p, h)
-                return h, counters
+                return h, carry, counters
 
             if train and getattr(f, "remat", False):
                 unit = jax.checkpoint(unit)
@@ -693,28 +710,37 @@ class FusedTrainer:
             with jax.named_scope(f.name):
                 if cast is not None:
                     h = cast(h)
-                h, counters = unit(p, h)
+                h, carry, counters = unit(p, h, carry)
+            moves = counters.pop("moves", None)
+            if moved is not None and moves:
+                moved[f.name] = moves
             if counted is not None and counters:
                 counted[f.name] = counters
             i += span
         return h
 
     def loss_and_metrics(self, params, data, target, batch_size, key,
-                         train: bool):
+                         train: bool, moved=None):
         """``(loss, metrics)`` of one minibatch: ``metrics`` is ``(loss,
         n_err, confusion)`` and, where units counted something in this
-        pass (``apply_counted``), a fourth entry ``{unit: counters}`` —
-        it leaves the device with the loss, in the same pull."""
+        pass (``apply_carried``), a fourth entry ``{unit: counters}`` —
+        it leaves the device with the loss, in the same pull.  ``moved``
+        is ``forward_pass``'s."""
         import jax.numpy as jnp
 
         import jax
 
         counted = {}
+        # a head that takes the loss itself (``forward_pass``)
+        own_loss = self.loss_kind == "softmax" and hasattr(
+            self.forwards[-1], "apply_loss")
+        loss_of = (target, batch_size) if own_loss else None
         if self.compute_dtype == np.dtype("float32"):
             cast = None
             cparams = params
             out = self.forward_pass(cparams, data, key, train,
-                                    counted=counted)
+                                    counted=counted, loss_of=loss_of,
+                                    moved=moved)
         else:
             def cast(t):
                 return t.astype("bfloat16") if t.dtype == jnp.float32 else t
@@ -730,9 +756,19 @@ class FusedTrainer:
             with jax.named_scope("input"):
                 data = cast(data)
             out = self.forward_pass(cparams, data, key, train, cast=cast,
-                                    counted=counted)
+                                    counted=counted, loss_of=loss_of,
+                                    moved=moved)
         with jax.named_scope("loss"):
-            loss, metrics = self._loss_head(out, target, batch_size)
+            if own_loss:                # the head's: (loss sum, errors)
+                if self.compute_confusion:
+                    raise FusedUnsupportedError(
+                        f"{self.forwards[-1].name} takes the loss itself "
+                        f"and hands on no logits: no confusion matrix")
+                loss = out[0] / jnp.maximum(batch_size * target.shape[1], 1)
+                metrics = (loss, out[1].astype(jnp.int32),
+                           jnp.zeros((1, 1), jnp.int32))
+            else:
+                loss, metrics = self._loss_head(out, target, batch_size)
         return loss, metrics + ((counted,) if counted else ())
 
     def _loss_head(self, out, target, batch_size):
@@ -1036,11 +1072,13 @@ class FusedTrainer:
                 tgt = jax.lax.with_sharding_constraint(tgt, shard)
 
         def lf(p):
-            return self.loss_and_metrics(p, data, tgt, batch_size, key,
-                                         train=True)
+            moved = {}
+            loss, metrics = self.loss_and_metrics(
+                p, data, tgt, batch_size, key, train=True, moved=moved)
+            return loss, (metrics, moved)
 
         # rematerialisation is per unit, inside ``forward_pass``
-        grads, metrics = jax.grad(lf, has_aux=True)(params)
+        grads, (metrics, moved) = jax.grad(lf, has_aux=True)(params)
         new_p, new_v = {}, {}
         for name, layer_p in params.items():
             rule = getattr(self.gd_of[name], "apply_update", None)
@@ -1072,6 +1110,13 @@ class FusedTrainer:
                     if self._master_dtype is not None:
                         p_new = p_new.astype(self._master_dtype)
                 new_p[name][k], new_v[name][k] = p_new, v_new
+        # what the units themselves move (``forward_pass``): the gradient
+        # of these tensors is zero and the rule above left them alone
+        for name, moves in moved.items():
+            with jax.named_scope(f"update/{name}"):
+                for k, step in moves.items():
+                    new_p[name][k] = new_p[name][k] + step.astype(
+                        new_p[name][k].dtype)
         return new_p, new_v, metrics
 
     def make_train_step(self):
@@ -2248,7 +2293,7 @@ class FusedTrainer:
         selects segmented mode.  Decision semantics are preserved
         exactly either way — metrics are fed in order, just later in
         wall time, and stops are rolled back to the exact stopping
-        state.  What counting units count (``apply_counted``) is not
+        state.  What counting units count (``apply_carried``) is not
         booked on this path: its packed scalar vector holds loss and
         error counts only."""
         from znicz_tpu.core.mutable import Bool

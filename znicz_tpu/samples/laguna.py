@@ -14,7 +14,10 @@ The decoder is built from DATA: ``MODELS`` holds public configurations by
 the keys of their ``config.json`` (``layer_types``, ``mlp_layer_types``,
 ``num_attention_heads_per_layer``, ``rope_parameters`` ...), ``layers()``
 turns one into the ``StandardWorkflow`` layer list, and a second decoder
-is a second dictionary.  The first is Laguna-XS.2
+is a second dictionary: ``samples/zaya.py`` selects ``zaya1-8b`` below
+and shares loader, workflow and ``layers()`` with this file (a job names
+its ``root`` namespace and its presets, ``LagunaWorkflow.namespace`` /
+``.presets``).  The first is Laguna-XS.2
 (https://huggingface.co/poolside/Laguna-XS.2/blob/main/config.json): 40
 layers, hidden 2,048, 8 KV heads of 128, 48 query heads in full-attention
 layers and 64 in window layers (window 512, one full layer in four), one
@@ -89,6 +92,39 @@ MODELS = {
         "moe_routed_scaling_factor": 2.5,
         "num_attention_heads_per_layer": [48, 64, 64, 64] * 10,
     },
+    # https://huggingface.co/Zyphra/ZAYA1-8B/blob/main/config.json
+    "zaya1-8b": {
+        "attention_bias": False, "cca_time0": 2, "cca_time1": 2,
+        "head_dim": 128, "hidden_act": "silu", "hidden_size": 2048,
+        "layer_types": ["hybrid"] * 40, "lm_head_bias": False,
+        "max_position_embeddings": 131072, "model_type": "zaya",
+        "moe_intermediate_size": 2048, "num_attention_heads": 8,
+        "num_experts": 16, "num_experts_per_tok": 1,
+        "num_hidden_layers": 40, "num_key_value_heads": 2,
+        "partial_rotary_factor": 0.5, "rms_norm_eps": 1e-05,
+        "rope_parameters": {
+            "hybrid": {"partial_rotary_factor": 0.5,
+                       "rope_theta": 5000000, "rope_type": "default"},
+            "hybrid_sliding": {"partial_rotary_factor": 0.5,
+                               "rope_theta": 10000,
+                               "rope_type": "default"},
+            "rope_type": "default"},
+        "router_hidden_size": 256, "sliding_window": None,
+        "tie_word_embeddings": True, "vocab_size": 262272,
+    },
+    "zaya-tiny": {
+        "cca_time0": 2, "cca_time1": 2, "head_dim": 16, "hidden_size": 64,
+        "layer_types": ["hybrid"] * 8, "model_type": "zaya",
+        "moe_intermediate_size": 32, "num_attention_heads": 4,
+        "num_experts": 8, "num_experts_per_tok": 1,
+        "num_hidden_layers": 8, "num_key_value_heads": 2,
+        "partial_rotary_factor": 0.5, "rms_norm_eps": 1e-05,
+        "rope_parameters": {
+            "hybrid": {"partial_rotary_factor": 0.5, "rope_theta": 5000000,
+                       "rope_type": "default"}},
+        "router_hidden_size": 16, "sliding_window": None,
+        "tie_word_embeddings": True, "vocab_size": 512,
+    },
     # the same block at sizes a CPU test finishes in seconds
     "tiny": {
         "vocab_size": 512, "hidden_size": 64, "intermediate_size": 128,
@@ -155,17 +191,23 @@ root.laguna.defaults({
                   "beta1": 0.9, "beta2": 0.95, "epsilon": 1e-8},
     "decision": {"max_epochs": 2, "fail_iterations": 0},
     "snapshotter": {"prefix": "laguna", "interval": 0},
+    "lr_adjust": {},            # no schedule: ``ASSUMED["optimizer"]``
+    "head": {},                 # ``LMHead``'s own arguments
 })
 
 
-def settings() -> dict:
-    """``{"model", "share", "loader"}`` as the job runs them: the preset
-    with ``root.laguna.share`` / ``.loader`` laid over it."""
-    preset = PRESETS[str(root.laguna.get("preset"))]
-    return {"model": dict(MODELS[preset["model"]]),
-            "share": dict(preset["share"], **root.laguna.share.to_dict()),
-            "loader": dict(preset["loader"],
-                           **root.laguna.loader.to_dict())}
+def settings(namespace: str = "laguna", presets: dict = None) -> dict:
+    """``{"model", "share", "loader", "head"}`` as the job runs them: the
+    preset with ``root.<namespace>.share`` / ``.loader`` / ``.head`` laid
+    over it, the preset's ``assumed`` keys (readings the published
+    dictionary does not carry) laid over the model's."""
+    cfg = getattr(root, namespace)
+    preset = (presets or PRESETS)[str(cfg.get("preset"))]
+    return {"model": dict(MODELS[preset["model"]],
+                          **preset.get("assumed", {})),
+            "share": dict(preset["share"], **cfg.share.to_dict()),
+            "loader": dict(preset["loader"], **cfg.loader.to_dict()),
+            "head": dict(preset.get("head", {}), **cfg.head.to_dict())}
 
 
 def rope_of(model: dict, kind: str) -> dict:
@@ -174,6 +216,8 @@ def rope_of(model: dict, kind: str) -> dict:
     rope = {"theta": float(cfg["rope_theta"]),
             "rotary_dim": int(model["head_dim"]
                               * cfg.get("partial_rotary_factor", 1))}
+    if rope["rotary_dim"] % 2:
+        raise ValueError(f"rotary dimension {rope['rotary_dim']} is odd")
     if cfg.get("rope_type") == "yarn":
         rope["yarn"] = {k: cfg[k] for k in (
             "factor", "original_max_position_embeddings", "beta_fast",
@@ -181,10 +225,33 @@ def rope_of(model: dict, kind: str) -> dict:
     return rope
 
 
-def layers(model: dict, share: dict) -> list:
+def family_keys(model: dict) -> dict:
+    """What a ``DecoderLayer`` chooses by key, read off the model's
+    dictionary: a model with ``cca_time0`` attends in a compressed latent
+    with that many mixing taps; one with ``router_hidden_size`` routes by
+    an MLP of that width on a state carried from layer to layer, with a
+    selection bias that the load moves; one with ``scale_residual_merge``
+    merges with learned scales — a key that a preset lays over a
+    dictionary whose published form dropped it (``assumed``;
+    ``samples/zaya.py`` ``ASSUMED_KEYS``)."""
+    unit = {}
+    if "cca_time0" in model:
+        unit.update(attention="cca", mixing_taps=(int(model["cca_time0"]),
+                                                  int(model["cca_time1"])))
+    if "router_hidden_size" in model:
+        unit.update(router="mlp",
+                    router_width=int(model["router_hidden_size"]))
+    if model.get("scale_residual_merge"):
+        unit["residual_scale"] = True
+    return unit
+
+
+def layers(model: dict, share: dict, optimizer=None, head=None) -> list:
     """The ``StandardWorkflow`` layer list of ``share``'s part of
-    ``model``: embedding, ``share["layers"]`` decoder layers, head."""
-    opt = root.laguna.optimizer
+    ``model``: embedding, ``share["layers"]`` decoder layers, head;
+    ``optimizer`` is the job's (``root.<namespace>.optimizer``), ``head``
+    further arguments of ``LMHead`` (``settings()["head"]``)."""
+    opt = optimizer or root.laguna.optimizer
     gd = {"learning_rate": float(opt.get("learning_rate")),
           "weights_decay": float(opt.get("weights_decay")),
           "beta1": float(opt.get("beta1")), "beta2": float(opt.get("beta2")),
@@ -192,32 +259,46 @@ def layers(model: dict, share: dict) -> list:
     out = [{"type": "token_embedding",
             "->": {"vocab": int(share["vocab_held"]),
                    "hidden": int(model["hidden_size"])}, "<-": dict(gd)}]
+    depth = int(model["num_hidden_layers"])
+    heads = model.get("num_attention_heads_per_layer",
+                      [model["num_attention_heads"]] * depth)
+    mlps = model.get("mlp_layer_types", ["sparse"] * depth)
     for i in range(int(share["layers"])):
         kind = model["layer_types"][i]
         unit = {
-            "heads": int(model["num_attention_heads_per_layer"][i]),
+            "heads": int(heads[i]),
             "kv_heads": int(model["num_key_value_heads"]),
             "head_dim": int(model["head_dim"]),
             "window": (int(model["sliding_window"])
-                       if kind == "sliding_attention" else None),
+                       if kind.endswith("sliding_attention") else None),
             "rope": rope_of(model, kind),
-            "gating": bool(model["gating"]),
-            "norm_eps": float(model["rms_norm_eps"])}
-        if model["mlp_layer_types"][i] == "dense":
+            "gating": bool(model.get("gating", False)),
+            "norm_eps": float(model["rms_norm_eps"]),
+            **family_keys(model)}
+        if mlps[i] == "dense":
             unit["dense_width"] = int(model["intermediate_size"])
         else:
             unit.update(
                 expert_width=int(model["moe_intermediate_size"]),
-                shared_width=int(model["shared_expert_intermediate_size"]),
+                shared_width=int(model.get(
+                    "shared_expert_intermediate_size", 0)),
                 experts_total=int(model["num_experts"]),
                 experts_held=int(share["experts_held"]),
                 first_expert=int(share["first_expert"]),
                 experts_per_token=int(model["num_experts_per_tok"]),
-                routed_scale=float(model["moe_routed_scaling_factor"]))
+                routed_scale=float(model.get("moe_routed_scaling_factor",
+                                             1.0)))
+            if unit.get("router") == "mlp":
+                # the router's state comes from the sparse layer before
+                unit["receives_state"] = any(
+                    m != "dense" for m in mlps[:i])
         out.append({"type": "decoder_layer", "->": unit, "<-": dict(gd)})
     out.append({"type": "lm_head",
                 "->": {"vocab": int(share["vocab_held"]),
-                       "norm_eps": float(model["rms_norm_eps"])},
+                       "norm_eps": float(model["rms_norm_eps"]),
+                       "tied": bool(model.get("tie_word_embeddings",
+                                              False)),
+                       **(head or {})},
                 "<-": dict(gd)})
     return out
 
@@ -233,10 +314,14 @@ def zipf_rows(rng, n: int, seq_len: int, vocab: int, s: float):
 
 class LagunaLoader(FullBatchLoader):
     """Rows of int32 ids, each one document; the labels are the row
-    shifted by one."""
+    shifted by one.  ``job`` is the job's ``settings()``."""
+
+    def __init__(self, workflow=None, name=None, job=None, **kwargs):
+        super().__init__(workflow=workflow, name=name, **kwargs)
+        self.job = job
 
     def load_data(self):
-        cfg = settings()
+        cfg = self.job or settings()
         ldr, vocab = cfg["loader"], int(cfg["share"]["vocab_held"])
         lengths = [int(ldr.get("n_test", 0)), int(ldr["n_valid"]),
                    int(ldr["n_train"])]
@@ -258,16 +343,26 @@ class LagunaLoader(FullBatchLoader):
 
 
 class LagunaWorkflow(StandardWorkflow):
+    """A decoder's training job; a second decoder's job subclasses it with
+    its own ``root`` namespace and presets (``samples/zaya.py``).
+    ``root.<namespace>.lr_adjust`` (``{"policy": ..., ...}``:
+    ``lr_adjust.POLICIES``) wires a learning-rate schedule."""
+
+    namespace = "laguna"
+    presets = PRESETS
+
     def __init__(self, **kwargs):
-        cfg = settings()
-        root_cfg = root.laguna
+        cfg = settings(self.namespace, self.presets)
+        root_cfg = getattr(root, self.namespace)
         loader = LagunaLoader(
-            name="loader",
+            name="loader", job=cfg,
             minibatch_size=int(cfg["loader"]["minibatch_size"]))
         super().__init__(
-            name="LagunaWorkflow", loader=loader,
-            layers=layers(cfg["model"], cfg["share"]),
+            name=type(self).__name__, loader=loader,
+            layers=layers(cfg["model"], cfg["share"], root_cfg.optimizer,
+                          cfg["head"]),
             loss_function="softmax",
+            lr_adjust_config=root_cfg.lr_adjust.to_dict(),
             decision_config={
                 "max_epochs": int(root_cfg.decision.get("max_epochs")),
                 "fail_iterations": int(
@@ -291,8 +386,8 @@ class LagunaWorkflow(StandardWorkflow):
                                   ("batch_size", "minibatch_size"))
 
 
-def run(device=None, mesh=None) -> LagunaWorkflow:
-    wf = LagunaWorkflow()
+def run(device=None, mesh=None, workflow=LagunaWorkflow) -> LagunaWorkflow:
+    wf = workflow()
     wf.initialize(device=device)
     from znicz_tpu.parallel.fused import FusedTrainer
     from znicz_tpu.parallel.mesh import train_mesh_from_config
