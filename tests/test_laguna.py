@@ -366,6 +366,89 @@ def test_decay_skips_what_the_reference_says_it_skips(family, family_job):
 
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_what_a_layer_keeps_changes_memory_not_mathematics(family,
+                                                           family_job):
+    """Loss and gradient of a train step on seeded weights, float32, the
+    composed path: with what a decoder layer keeps across
+    rematerialisation as shipped (its core's output and log-sum-exp,
+    ISSUE 33), with nothing kept (the bare ``jax.checkpoint``) and with
+    no rematerialisation at all — equal, tensor by tensor, to float32
+    rounding.  The traced program shows the difference: the core's
+    forward pass (two exponentials a block pair: the probabilities and
+    the running sum's correction) stands once a layer where the layer
+    keeps its results, twice where it does not."""
+    from znicz_tpu.ops.attention import CORE_KEEPS
+
+    cell, _ = family_job(family)
+    root.common.engine.compute_dtype = "float32"
+    cell.config["tiny"]["root"]["root.common.engine.compute_dtype"] = \
+        "float32"
+    built = driver.build(cell, 11, True)
+    trainer = built.trainer
+    layers = built.wf.forwards[1:-1]
+    assert len(layers) == FAMILIES[family]["layers"]
+    assert all(f.remat and f.remat_keeps == CORE_KEEPS for f in layers)
+    params = trainer.extract_params()
+    ids, targets = built.data[2:4], built.labels[2:4]
+
+    def exponentials(jaxpr):
+        from jax._src.core import jaxprs_in_params
+
+        return sum((eqn.primitive.name == "exp") + sum(
+            exponentials(sub) for sub in jaxprs_in_params(eqn.params))
+            for eqn in jaxpr.eqns)
+
+    def value_and_gradient():
+        traced = jax.jit(jax.value_and_grad(
+            lambda p: trainer.loss_and_metrics(
+                p, ids, targets, 2, trainer._key0, train=True)[0])
+        ).trace(params)
+        return (traced.lower().compile()(params),
+                exponentials(traced.jaxpr.jaxpr))
+
+    (loss, grads), exps_kept = value_and_gradient()
+    assert [f.remat_kept for f in layers] == [CORE_KEEPS] * len(layers)
+    for f in layers:
+        f.remat_keeps = ()
+    (loss_bare, grads_bare), exps_bare = value_and_gradient()
+    assert [f.remat_kept for f in layers] == [()] * len(layers)
+    for f in layers:
+        f.remat = False
+    (loss_all, grads_all), exps_all = value_and_gradient()
+    assert exps_all <= exps_kept == exps_bare - 2 * len(layers)
+    for other_loss, other in ((loss_bare, grads_bare), (loss_all, grads_all)):
+        assert float(loss) == pytest.approx(float(other_loss), rel=1e-6)
+        for name in grads:
+            for key, g in grads[name].items():
+                # float32 rounding: XLA fuses the three programs apart
+                assert rel(g, other[name][key]) < 1e-5, (name, key)
+    # the gradient passes through every core down to the first layer's
+    assert float(jnp.abs(grads[layers[0].name]["wq"]).max()) > 0
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_kept_cores_are_counted_by_a_training_run_not_an_evaluation(
+        family, family_job):
+    """``attn_cores_kept``: layers whose core's results cross
+    rematerialisation — none while only an evaluation was traced, every
+    decoder layer once a run traced its train step."""
+    from znicz_tpu import decoder
+
+    _, built = family_job(family)
+    trainer, wf = built.trainer, built.wf
+    layers = [f for f in wf.forwards if isinstance(f, decoder.DecoderLayer)]
+    jax.jit(lambda p: trainer.loss_and_metrics(
+        p, built.data[2:4], built.labels[2:4], 2, trainer._key0,
+        train=False)[0]).trace(trainer.extract_params())
+    assert decoder.DecoderLayer.run_stats(layers)["attn_cores_kept"] == 0
+    assert "attn_cores_kept" not in trainer.stats
+    wf.decision.max_epochs = 1
+    trainer.run()
+    assert trainer.stats["attn_cores_kept"] == len(layers) \
+        == FAMILIES[family]["layers"]
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
 def test_the_sample_trains_through_the_launcher_and_counts(family, tmp_path,
                                                            restore_root):
     """``python -m znicz_tpu <sample>``'s path: StandardWorkflow ->
@@ -405,6 +488,7 @@ def test_the_sample_trains_through_the_launcher_and_counts(family, tmp_path,
     assert sizes["_train_step"] == 0 == sizes["_eval_scan"]
     assert (stats["tails_in_scan"], stats["tails_alone"]) == (2, 1)
     assert stats["attn_cores_composed"] == fam["layers"]
+    assert stats["attn_cores_kept"] == fam["layers"]
     assert any(f.name.endswith(".pickle.gz") or f.name.endswith(".pickle")
                for f in tmp_path.iterdir())
     if family == "zaya":

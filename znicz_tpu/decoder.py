@@ -49,9 +49,15 @@ which the fused trainer OBSERVES rather than is told:
     lowered the core's kernels; how many layers received a state; the
     head's blocks) into ``FusedTrainer.stats`` when the run ends — no
     device work, nothing in a step;
-  - a unit that asks for rematerialisation (``remat = True``): training
-    keeps a decoder layer's input only, 67 MB a layer at 16,384 tokens of
-    width 2,048, and recomputes the rest on the way back.
+  - a unit that asks for rematerialisation (``remat = True``) and says
+    what it keeps beside its input (``remat_keeps``, names of tensors:
+    ``ops.attention.CORE_KEEPS``).  Training keeps a decoder layer's input
+    (67 MB a layer at 16,384 tokens of width 2,048), its attention core's
+    output (as many bytes as its queries: 201 or 268 MB at Laguna's 48 or
+    64 heads of 128, 67 MB at ZAYA's 8 over 32,768 rows) and the core's
+    float32 log-sum-exp (one a query and head), and recomputes the rest
+    on the way back: norms, projections, rotations, mixing, router and
+    experts run again, the core's forward kernel does not.
 
 Weights are made ON THE DEVICE from the unit's seeded stream
 (``init_params``; the same seed gives the same tensors), so 0.7 billion
@@ -73,8 +79,9 @@ from znicz_tpu.core import prng
 from znicz_tpu.memory import Array
 from znicz_tpu.nn_units import ForwardBase
 from znicz_tpu.ops import cca, moe
-from znicz_tpu.ops.attention import (apply_rope, blocked_attention,
-                                     core_tiles, kernel_counts, rope_tables)
+from znicz_tpu.ops.attention import (CORE_KEEPS, apply_rope,
+                                     blocked_attention, core_tiles,
+                                     kernel_counts, rope_tables)
 
 
 def rms_norm(x, gain, eps: float):
@@ -203,6 +210,8 @@ class DecoderLayer(_DeviceInitialised):
     would add is left out, here and in the reference alike."""
 
     remat = True
+    #: what rematerialisation keeps of this unit beside its input
+    remat_keeps = CORE_KEEPS
 
     def __init__(self, workflow=None, name=None, heads=4, kv_heads=2,
                  head_dim=16, window=None, rope=None, gating=False,
@@ -252,6 +261,9 @@ class DecoderLayer(_DeviceInitialised):
         # noted when ``apply_carried`` traces
         self.core_in_kernels = None
         self.received_state = None
+        # noted by ``FusedTrainer.forward_pass`` when a train step traces:
+        # the names it kept across this layer's rematerialisation
+        self.remat_kept = ()
 
     @property
     def sparse(self) -> bool:
@@ -369,13 +381,18 @@ class DecoderLayer(_DeviceInitialised):
         """What the decoder layers of a run noted while they were traced:
         how many run their attention core in the Pallas kernels and how
         many composed of XLA operations (``core_in_kernels``, set by the
-        last trace of ``apply_carried``), the process's count of kernel
-        traces and lowerings (``ops.attention.kernel_counts``) and, where
-        a router keeps a state, how many layers received one."""
+        last trace of ``apply_carried``), how many had their core's
+        output and log-sum-exp kept across rematerialisation
+        (``remat_kept``: none where no train step was traced), the
+        process's count of kernel traces and lowerings
+        (``ops.attention.kernel_counts``) and, where a router keeps a
+        state, how many layers received one."""
         ways = [f.core_in_kernels for f in layers
                 if f.core_in_kernels is not None]
         out = {"attn_cores_kernel": sum(ways),
                "attn_cores_composed": len(ways) - sum(ways),
+               "attn_cores_kept": sum(
+                   set(CORE_KEEPS) <= set(f.remat_kept) for f in layers),
                **kernel_counts()}
         if any(f.router == "mlp" for f in layers):
             out["router_states_carried"] = sum(

@@ -447,8 +447,9 @@ def kernel_counts() -> dict:
     no core ever ran in the kernels): kinds of core x 4 traces a process
     at most (forward, forward again under ``jax.checkpoint``, dq, dk/dv)
     however many layers, passes and programs there are, and kinds x 3
-    lowerings a training program.  A regression of set-up shows here as a
-    number."""
+    lowerings a training program, kinds x 1 an evaluation, whatever the
+    layers keep across rematerialisation.  A regression of set-up shows
+    here as a number."""
     import sys
 
     kernels = sys.modules.get("znicz_tpu.ops.attention_pallas")
@@ -457,12 +458,23 @@ def kernel_counts() -> dict:
             "attn_kernel_lowerings": counts["lowerings"]}
 
 
+#: the names (``jax.ad_checkpoint.checkpoint_name``) of what the core's
+#: forward pass hands its backward pass beside q, k and v: the output and
+#: the log-sum-exp.  A rematerialised unit that keeps them
+#: (``jax.checkpoint_policies.save_only_these_names``; ``DecoderLayer.
+#: remat_keeps``) recomputes q, k and v on the way back and not the core.
+#: Outside ``jax.checkpoint`` a name is an identity.
+CORE_KEEPS = ("attn_core_out", "attn_core_lse")
+
+
 def _core(q, k, v, window, block: int, tiles):
     """The attention core behind one ``custom_vjp`` whose residuals are
-    q, k, v, the output and one float32 log-sum-exp a query and head:
+    q, k, v, the output and one float32 log-sum-exp a query and head
+    (the last two under the names ``CORE_KEEPS``, whichever way it runs):
     a block pair runs in the Pallas kernels with ``tiles``, composed of
     XLA operations in blocks of ``block`` without."""
     import jax
+    from jax.ad_checkpoint import checkpoint_name
 
     if tiles is None:
         def forward(q, k, v):
@@ -489,6 +501,8 @@ def _core(q, k, v, window, block: int, tiles):
 
     def fwd(q, k, v):
         out, lse = forward(q, k, v)
+        out = checkpoint_name(out, CORE_KEEPS[0])
+        lse = checkpoint_name(lse, CORE_KEEPS[1])
         return out, (q, k, v, out, lse)
 
     core.defvjp(fwd, backward)

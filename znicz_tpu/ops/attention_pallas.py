@@ -48,13 +48,23 @@ every layer, pass and program tracing and lowering 12-16 Python-unrolled
 copies of a pair's arithmetic anew):
 
   - each kernel is a module-level jitted function, static in what it is
-    specialised on, so jax's trace cache hands every layer of a kind, the
-    recomputed forward pass and every later program the first trace (the
-    forward pass is traced twice a kind: ``jax.checkpoint``'s trace
-    context is another cache key), and one lowered program holds one
-    Mosaic body a kind and kernel, inlined at every layer's call (each
-    call keeps its own scope: ``op_name`` is the call site's).
-    ``COUNTS`` says how often either happened in this process;
+    specialised on, so jax's trace cache hands every layer of a kind and
+    every later program the first trace (the forward pass is traced twice
+    a kind: ``jax.checkpoint``'s trace context is another cache key), and
+    one lowered program holds one Mosaic body a kind and kernel, inlined
+    at every layer's call (each call keeps its own scope: ``op_name`` is
+    the call site's).  ``COUNTS`` says how often either happened in this
+    process.  Since a decoder layer keeps its core's output and
+    log-sum-exp across rematerialisation (PR 33;
+    ``ops.attention.CORE_KEEPS``) the recomputed forward pass calls no
+    kernel: a training program holds layers x 3 calls where it held
+    layers x 4, and the counts read what they read before — the
+    recomputed call had shared the first pass's trace and body.  One
+    thing moved in the lowered TEXT: under a policy ``jax.checkpoint``'s
+    partial evaluation gives each layer's jitted ``forward`` a jaxpr of
+    its own, so the text holds one ``forward`` function a layer (each
+    inlining the one cached body) where it held two a kind; ``dq`` and
+    ``dkv`` stay one a kind;
   - the heads of a group go through ONE traced body, a ``lax.fori_loop``
     over lane-aligned dynamic slices (``pl.ds(h * dim, dim)``: a head is
     whole lane tiles), unrolled when the kernel is lowered.  Left rolled
@@ -94,7 +104,8 @@ VMEM_LIMIT = 64 * 1024 * 1024
 #: ``attn_kernel_lowerings``): kinds of core x 3 kernels however many
 #: layers, passes and programs there are (the forward one once more a
 #: kind, under ``jax.checkpoint``), and kinds x 3 lowerings a training
-#: program, kinds x 1 an evaluation
+#: program, kinds x 1 an evaluation — whatever the layers keep across
+#: rematerialisation
 COUNTS = {"traces": 0, "lowerings": 0}
 
 # An identity whose only work is done while it is lowered: it counts.  jax

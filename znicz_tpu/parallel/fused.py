@@ -68,6 +68,20 @@ class FusedStagingUnsupportedError(FusedUnsupportedError):
     ``ValueError`` that would also swallow real config errors."""
 
 
+def rematerialised(f, unit):
+    """``unit``, the traced function of forward unit ``f``, under
+    ``jax.checkpoint``: the backward pass keeps its inputs and the
+    tensors ``f`` names (``remat_keeps``; none: the bare
+    ``jax.checkpoint``) and recomputes the rest; ``f`` is told what was
+    kept (``remat_kept``)."""
+    import jax
+
+    keeps = f.remat_kept = tuple(getattr(f, "remat_keeps", ()))
+    policy = (jax.checkpoint_policies.save_only_these_names(*keeps)
+              if keeps else None)
+    return jax.checkpoint(unit, policy=policy)
+
+
 def _default_order(sharding, shape, dtype):
     """Axis order, major to minor, of the layout the devices of
     ``sharding`` give an array of this shape and dtype by default."""
@@ -603,10 +617,15 @@ class FusedTrainer:
         ``(targets, batch size)`` — is handed in: the result is then
         ``(loss sum, errors)``, not logits.  ``hidden`` stops before the
         last unit and returns what it would be given.  In training a unit
-        is rematerialised (``jax.checkpoint`` around that unit alone: its
-        input is all the backward pass keeps of it) where it asks for that
-        (``remat = True`` on the unit) — per unit, so that what is live in
-        the backward pass is one unit's activations, not the network's."""
+        is rematerialised (``jax.checkpoint`` around that unit alone)
+        where it asks for that (``remat = True`` on the unit) — per unit,
+        so that what is live in the backward pass is one unit's
+        activations, not the network's.  The backward pass keeps the
+        unit's input and the tensors the unit names (``remat_keeps``: names
+        given with ``jax.ad_checkpoint.checkpoint_name``, a decoder layer's
+        attention output and log-sum-exp; ``save_only_these_names``), and
+        the unit is told what was kept (``remat_kept``); a unit that names
+        nothing keeps its input alone."""
         import jax
 
         from znicz_tpu.ops.linear import linear
@@ -703,7 +722,7 @@ class FusedTrainer:
                 return h, carry, counters
 
             if train and getattr(f, "remat", False):
-                unit = jax.checkpoint(unit)
+                unit = rematerialised(f, unit)
             # the device trace speaks the model's names: one scope per
             # forward unit (a fused block or tail span takes its first
             # unit's); jax names the backward ``transpose(jvp(<unit>))``
