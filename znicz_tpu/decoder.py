@@ -1,12 +1,14 @@
-"""Decoder-stack units: a token embedding, a decoder layer (an attention
-block and a dense or sparse-expert feed-forward, both merged into the
-residual stream) and the head (final RMSNorm and the logits, over a
-tensor of its own or TIED to the embedding's), built from the keys of a
+"""Decoder-stack units: a token embedding, a decoder layer (a mixer —
+an attention block or a state-space scan — and a dense or sparse-expert
+feed-forward, each merged into the residual stream; a layer may hold ONE
+of the two parts alone) and the head (final RMSNorm and the logits, over
+a tensor of its own or TIED to the embedding's), built from the keys of a
 model's public configuration — data, not code: a second decoder is a
-second dictionary (``samples/laguna.py`` ``MODELS`` holds both), and what
-two decoders do differently the LAYER chooses by its keys (``attention``,
-``router``, ``residual_scale``, ``shared_width``), everything else being
-one code path.
+second dictionary (``samples/laguna.py`` ``MODELS`` holds them), and what
+two decoders do differently the LAYER chooses by its keys (``mixer``,
+``feed_forward``, ``attention``, ``router``, ``activation``,
+``residual_scale``, ``shared_width``), everything else being one code
+path.
 
 They follow the framework's unit contract (pure ``apply(params, x)``,
 ``params()`` a dict of ``Array``s, a GD twin, registry types
@@ -78,7 +80,7 @@ import numpy as np
 from znicz_tpu.core import prng
 from znicz_tpu.memory import Array
 from znicz_tpu.nn_units import ForwardBase
-from znicz_tpu.ops import cca, moe
+from znicz_tpu.ops import cca, moe, ssm
 from znicz_tpu.ops.attention import (CORE_KEEPS, apply_rope,
                                      blocked_attention, core_tiles,
                                      kernel_counts, rope_tables)
@@ -99,7 +101,8 @@ def rms_norm(x, gain, eps: float):
 class _DeviceInitialised(ForwardBase):
     """A forward unit whose tensors are listed by ``param_shapes()`` as
     ``key -> (shape, stddev)`` (stddev ``None``: ones, a gain; ``0``:
-    zeros, a bias) and made on the device."""
+    zeros, a bias; a function ``(key, shape) -> float32 array``: a
+    tensor with a start of its own) and made on the device."""
 
     #: keys of ``params()`` that weight decay skips
     decay_exempt = ()
@@ -125,6 +128,8 @@ class _DeviceInitialised(ForwardBase):
 
         def make(base):                 # one program a unit, not a tensor
             return {key: (jnp.ones(shape, jnp.float32) if std is None else
+                          std(jax.random.fold_in(base, i), shape)
+                          if callable(std) else
                           jnp.zeros(shape, jnp.float32) if std == 0 else
                           jax.random.normal(jax.random.fold_in(base, i),
                                             shape, jnp.float32) * std)
@@ -168,19 +173,42 @@ class TokenEmbedding(_DeviceInitialised):
                         mode="clip")
 
 
+#: the rotation of a layer that is told none of its own; ``rope=None``
+#: is a layer WITHOUT rotation
+DEFAULT_ROPE = {"theta": 10000.0}
+
+
 class DecoderLayer(_DeviceInitialised):
     """One decoder layer::
 
-        h = merge(x, Attn(RMSNorm(x))),    y = merge(h, FFN(RMSNorm(h)))
+        h = merge(x, Mix(RMSNorm(x))),    y = merge(h, FFN(RMSNorm(h)))
+
+    or ONE of the two parts alone: ``mixer`` is ``"attention"``,
+    ``"mamba"`` or ``None`` (no mixer: the layer is its feed-forward),
+    ``feed_forward`` ``False`` leaves the mixer alone.
 
     ``merge(x, a)`` is ``x + a``, or with ``residual_scale`` ``(x + b_r) *
     s_r + (a + b_h) * s_h``: four learned vectors a sub-block, scales 1
     and shifts 0 at the start.
 
+    ``Mix`` ``"mamba"`` (``ops.ssm``): ``[z | xBC | dt] = x^ W_in``; the
+    ``xBC`` channels through a causal depthwise convolution of
+    ``conv_kernel`` taps (with a bias) and a SiLU, then split into ``x``
+    (``ssm_heads`` heads of ``ssm_head_dim``) and ``B``, ``C``
+    (``ssm_groups`` groups of ``ssm_state``); ``dt = softplus(dt +
+    dt_bias)`` in float32; the scan ``S_t = exp(dt_t A_h) S_{t-1} + dt_t
+    x_t B_t^T``, ``y_t = S_t C_t + D_h x_t`` in chunks of ``ssm_chunk``
+    positions (``A_h = -exp(A_log_h)``); ``RMSNorm over groups (y *
+    silu(z))`` and ``W_out``.  At the start ``dt_bias`` is the inverse
+    softplus of a ``dt`` drawn log-uniform in ``dt_range``'s first two
+    numbers and floored at its third, ``A_log = log U[1, 16]``, ``D = 1``,
+    the taps uniform in ``+-1 / sqrt(conv_kernel)``.
+
     ``Attn``: grouped-query (``heads`` query heads read ``kv_heads``),
     no biases, rotary positions on the first ``rotary_dim`` dimensions of
     every head (``rope``: ``theta`` and optionally the YaRN numbers —
-    ``ops.attention.rope_tables``), causal and, with ``window``, limited
+    ``ops.attention.rope_tables``; ``rope=None``: no rotation at all),
+    causal and, with ``window``, limited
     to the last ``window`` keys (``ops.attention.blocked_attention``).
     What stands between the projections and the core is chosen by
     ``attention``: ``"plain"`` — q, k, v as projected; with ``gating``
@@ -193,16 +221,22 @@ class DecoderLayer(_DeviceInitialised):
     ``FFN``: ``dense_width`` set — a SwiGLU of that width; else the expert
     layer: ``experts_total`` routed experts of ``expert_width`` with
     ``experts_per_token`` a token plus, with ``shared_width``, one shared
-    expert.  ``router``: ``"sigmoid"`` — a linear map (``ops.moe.route``:
-    weights ``routed_scale * s / sum of the chosen``); ``"mlp"`` — an MLP
+    expert.  ``activation``: ``"swiglu"`` — three matrices an expert,
+    ``(silu(x W_g) * x W_u) W_d``; ``"relu2"`` — two, ``relu(x W_u)^2
+    W_d``, for routed, shared and dense alike.  ``router``:
+    ``"sigmoid"`` — a linear map (``ops.moe.route``:
+    weights ``routed_scale * s / sum of the chosen``; with
+    ``selection_bias`` the choice is by ``s + router_bias``,
+    ``ops.moe.route_balanced``); ``"mlp"`` — an MLP
     of ``router_width`` on a state that is this layer's down-projection
     plus, with ``receives_state``, ``gamma *`` the state the layer before
     handed on (``ops.moe.router_state`` / ``route_mlp``: a softmax, the
     chosen expert's probability its weight); the state goes on to the
     next layer as ``apply_carried``'s carry; the choice is by probability
-    plus ``router_bias``, a tensor that no gradient reaches: every train
-    step moves it by what that step's load asks
-    (``ops.moe.balance_step``; the counters' entry ``moves``).  The layer
+    plus ``router_bias``).  ``router_bias``, in either router, is a
+    tensor that no gradient reaches: every train step moves it by what
+    that step's load asks (``ops.moe.balance_step``; the counters' entry
+    ``moves``).  The layer
     is TOLD what it holds — ``experts_held`` experts from
     ``first_expert`` — routes over all ``experts_total``, and adds its own
     experts' part
@@ -210,22 +244,33 @@ class DecoderLayer(_DeviceInitialised):
     would add is left out, here and in the reference alike."""
 
     remat = True
-    #: what rematerialisation keeps of this unit beside its input
-    remat_keeps = CORE_KEEPS
 
     def __init__(self, workflow=None, name=None, heads=4, kv_heads=2,
-                 head_dim=16, window=None, rope=None, gating=False,
+                 head_dim=16, window=None, rope=DEFAULT_ROPE, gating=False,
                  dense_width=0, expert_width=0, shared_width=0,
                  experts_total=0, experts_held=0, first_expert=0,
                  experts_per_token=0, routed_scale=1.0, norm_eps=1e-6,
                  attention="plain", mixing_taps=(2, 2), router="sigmoid",
                  router_width=0, receives_state=False,
-                 residual_scale=False, **kwargs):
+                 residual_scale=False, mixer="attention",
+                 feed_forward=True, activation="swiglu",
+                 selection_bias=False, out_scale=1.0, ssm_heads=0, ssm_head_dim=0, ssm_groups=1,
+                 ssm_state=0, conv_kernel=4, ssm_chunk=128,
+                 dt_range=(0.001, 0.1, 1e-4), **kwargs):
         super().__init__(workflow=workflow, name=name, **kwargs)
         self.heads, self.kv_heads = int(heads), int(kv_heads)
         self.head_dim = int(head_dim)
         self.window = int(window) if window else None
-        self.rope = dict(rope or {"theta": 10000.0})
+        self.rope = dict(rope) if rope else None
+        self.mixer = str(mixer) if mixer else None
+        self.feed_forward = bool(feed_forward)
+        self.activation = str(activation)
+        self.selection_bias = bool(selection_bias)
+        self.out_scale = float(out_scale)
+        self.ssm_heads, self.ssm_head_dim = int(ssm_heads), int(ssm_head_dim)
+        self.ssm_groups, self.ssm_state = int(ssm_groups), int(ssm_state)
+        self.conv_kernel, self.ssm_chunk = int(conv_kernel), int(ssm_chunk)
+        self.dt_range = tuple(float(v) for v in dt_range)
         self.gating = bool(gating)
         self.dense_width = int(dense_width)
         self.expert_width = int(expert_width)
@@ -245,10 +290,23 @@ class DecoderLayer(_DeviceInitialised):
             raise ValueError(f"{self.name}: {self.heads} query heads do "
                              f"not divide over {self.kv_heads} KV heads")
         if self.attention not in ("plain", "cca") \
-                or self.router not in ("sigmoid", "mlp"):
-            raise ValueError(f"{self.name}: attention {self.attention!r}, "
-                             f"router {self.router!r}")
-        if not self.dense_width and not (
+                or self.router not in ("sigmoid", "mlp") \
+                or self.mixer not in ("attention", "mamba", None) \
+                or self.activation not in ("swiglu", "relu2") \
+                or not (self.mixer or self.feed_forward):
+            raise ValueError(
+                f"{self.name}: mixer {self.mixer!r}, attention "
+                f"{self.attention!r}, feed_forward {self.feed_forward}, "
+                f"router {self.router!r}, activation {self.activation!r}")
+        if self.mixer == "mamba" and (
+                self.ssm_groups < 1 or self.ssm_heads % self.ssm_groups
+                or min(self.ssm_heads, self.ssm_head_dim,
+                       self.ssm_state) < 1):
+            raise ValueError(
+                f"{self.name}: {self.ssm_heads} scan heads of "
+                f"{self.ssm_head_dim} over {self.ssm_groups} groups of "
+                f"state {self.ssm_state}")
+        if self.feed_forward and not self.dense_width and not (
                 0 < self.experts_held <= self.experts_total
                 and 0 <= self.first_expert
                 <= self.experts_total - self.experts_held
@@ -261,13 +319,19 @@ class DecoderLayer(_DeviceInitialised):
         # noted when ``apply_carried`` traces
         self.core_in_kernels = None
         self.received_state = None
+        self.scan_way, self.scan_chunks = None, 0
+        #: what rematerialisation keeps of this unit beside its input: the
+        #: attention core's output and log-sum-exp where the layer has a
+        #: core; a scan or a feed-forward alone is made again from the input
+        self.remat_keeps = CORE_KEEPS if self.mixer == "attention" else ()
         # noted by ``FusedTrainer.forward_pass`` when a train step traces:
         # the names it kept across this layer's rematerialisation
         self.remat_kept = ()
 
     @property
     def sparse(self) -> bool:
-        return not self.dense_width
+        """The layer holds routed experts (and counts their rows)."""
+        return self.feed_forward and not self.dense_width
 
     def output_shape_for(self, in_shape):
         return tuple(in_shape)
@@ -276,28 +340,32 @@ class DecoderLayer(_DeviceInitialised):
         """``(key, shape, stddev, decays)`` of every tensor this layer's
         keys ask for, in the order the seeded stream makes them."""
         d, std = self.hidden, self.init_std
-        hd, kvh = self.head_dim, self.kv_heads
-        q, kv = self.heads * hd, kvh * hd
-        out = [("norm_attn", (d,), None, False), ("wq", (d, q), std, True),
-               ("wk", (d, kv), std, True), ("wv", (d, kv), std, True)]
-        if self.attention == "cca":
-            n, (t0, t1) = self.heads + kvh, self.mixing_taps
-            out += [("mix_w", (t0, n, hd), std, True),
-                    ("mix_b", (n, hd), 0, False),
-                    ("mix_heads", (t1, n, hd, hd), std, True),
-                    ("mix_heads_b", (n, hd), 0, False),
-                    ("temp", (kvh,), 0, False)]
-        if self.gating:
-            out.append(("w_gate", (d, self.heads), std, False))
-        out.append(("wo", (q, d), std, True))
-        out += self._merge_tensors("attn")
+        # a part's output projection starts smaller where the layer is
+        # told so (``out_scale``: a prenorm residual stream rescaled by
+        # its depth)
+        std_out = std * self.out_scale
+        out = []
+        if self.mixer == "attention":
+            out += self._attention_tensors(std_out)
+        elif self.mixer == "mamba":
+            out += self._scan_tensors(std_out)
+        if self.mixer:
+            out += self._merge_tensors("attn")
+        if not self.feed_forward:
+            return out
+        gated = self.activation == "swiglu"
+
+        def mlp(prefix, lead, w):
+            """A gated part's three matrices, or the two of ``relu2``."""
+            return ([(f"{prefix}_gate", lead + (d, w), std, True)]
+                    if gated else []) + [
+                (f"{prefix}_up", lead + (d, w), std, True),
+                (f"{prefix}_down", lead + (w, d), std_out, True)]
+
         out.append(("norm_ffn", (d,), None, False))
         if not self.sparse:
-            w = self.dense_width
-            out += [("ffn_gate", (d, w), std, True),
-                    ("ffn_up", (d, w), std, True),
-                    ("ffn_down", (w, d), std, True)]
-            return out + self._merge_tensors("ffn")
+            return out + mlp("ffn", (), self.dense_width) \
+                + self._merge_tensors("ffn")
         if self.router == "mlp":
             r = self.router_width
             out += [("router_down", (d, r), std, True),
@@ -313,16 +381,61 @@ class DecoderLayer(_DeviceInitialised):
                     ("router_bias", (self.experts_total,), 0, False)]
         else:
             out.append(("router", (d, self.experts_total), std, False))
+            if self.selection_bias:
+                out.append(("router_bias", (self.experts_total,), 0, False))
         if self.shared_width:
-            s = self.shared_width
-            out += [("shared_gate", (d, s), std, True),
-                    ("shared_up", (d, s), std, True),
-                    ("shared_down", (s, d), std, True)]
-        held, f = self.experts_held, self.expert_width
-        out += [("experts_gate", (held, d, f), std, True),
-                ("experts_up", (held, d, f), std, True),
-                ("experts_down", (held, f, d), std, True)]
+            out += mlp("shared", (), self.shared_width)
+        out += mlp("experts", (self.experts_held,), self.expert_width)
         return out + self._merge_tensors("ffn")
+
+    def _attention_tensors(self, std_out):
+        d, std = self.hidden, self.init_std
+        hd, kvh = self.head_dim, self.kv_heads
+        q, kv = self.heads * hd, kvh * hd
+        out = [("norm_attn", (d,), None, False), ("wq", (d, q), std, True),
+               ("wk", (d, kv), std, True), ("wv", (d, kv), std, True)]
+        if self.attention == "cca":
+            n, (t0, t1) = self.heads + kvh, self.mixing_taps
+            out += [("mix_w", (t0, n, hd), std, True),
+                    ("mix_b", (n, hd), 0, False),
+                    ("mix_heads", (t1, n, hd, hd), std, True),
+                    ("mix_heads_b", (n, hd), 0, False),
+                    ("temp", (kvh,), 0, False)]
+        if self.gating:
+            out.append(("w_gate", (d, self.heads), std, False))
+        return out + [("wo", (q, d), std_out, True)]
+
+    def _scan_tensors(self, std_out):
+        import jax
+        import jax.numpy as jnp
+
+        d, std, h = self.hidden, self.init_std, self.ssm_heads
+        inner = h * self.ssm_head_dim
+        mixed = inner + 2 * self.ssm_groups * self.ssm_state     # x, B, C
+        lo, hi, floor = self.dt_range
+        bound = self.conv_kernel ** -0.5
+
+        def taps(key, shape):
+            return jax.random.uniform(key, shape, jnp.float32, -bound, bound)
+
+        def dt_bias(key, shape):
+            dt = jnp.maximum(jnp.exp(jax.random.uniform(
+                key, shape, jnp.float32, np.log(lo), np.log(hi))), floor)
+            return dt + jnp.log(-jnp.expm1(-dt))        # softplus^-1(dt)
+
+        def a_log(key, shape):
+            return jnp.log(jax.random.uniform(key, shape, jnp.float32,
+                                              1.0, 16.0))
+
+        return [("norm_ssm", (d,), None, False),
+                ("ssm_in", (d, inner + mixed + h), std, True),
+                ("ssm_conv_w", (self.conv_kernel, mixed), taps, True),
+                ("ssm_conv_b", (mixed,), 0, False),
+                ("ssm_dt_bias", (h,), dt_bias, False),
+                ("ssm_a_log", (h,), a_log, False),
+                ("ssm_d", (h,), None, False),
+                ("ssm_norm", (inner,), None, False),
+                ("ssm_out", (inner, d), std_out, True)]
 
     def _merge_tensors(self, part: str):
         if not self.residual_scale:
@@ -385,8 +498,11 @@ class DecoderLayer(_DeviceInitialised):
         output and log-sum-exp kept across rematerialisation
         (``remat_kept``: none where no train step was traced), the
         process's count of kernel traces and lowerings
-        (``ops.attention.kernel_counts``) and, where a router keeps a
-        state, how many layers received one."""
+        (``ops.attention.kernel_counts``), where a router keeps a
+        state, how many layers received one, where a router's selection
+        bias is moved by the load, how many, and where a layer scans a
+        state, the layers by kind, the scans by the way they ran and
+        their chunks a row."""
         ways = [f.core_in_kernels for f in layers
                 if f.core_in_kernels is not None]
         out = {"attn_cores_kernel": sum(ways),
@@ -397,9 +513,26 @@ class DecoderLayer(_DeviceInitialised):
         if any(f.router == "mlp" for f in layers):
             out["router_states_carried"] = sum(
                 bool(f.received_state) for f in layers)
-            out["router_biases_moved"] = sum(
-                f.sparse and f.router == "mlp" for f in layers)
+        if any(f.moves_bias for f in layers):
+            out["router_biases_moved"] = sum(f.moves_bias for f in layers)
+        if any(f.mixer == "mamba" for f in layers):
+            ways = [f.scan_way for f in layers if f.scan_way]
+            out.update(
+                layers_mamba=sum(f.mixer == "mamba" for f in layers),
+                layers_attention=sum(f.mixer == "attention"
+                                     for f in layers),
+                layers_experts=sum(f.sparse for f in layers),
+                ssm_scans_kernel=ways.count("kernel"),
+                ssm_scans_composed=ways.count("composed"),
+                ssm_chunks=max(f.scan_chunks for f in layers))
         return out
+
+    @property
+    def moves_bias(self) -> bool:
+        """The layer's router chooses by a selection bias that every
+        train step's load moves."""
+        return self.sparse and (self.router == "mlp"
+                                or self.selection_bias)
 
     # -- pure compute ----------------------------------------------------------
 
@@ -434,6 +567,38 @@ class DecoderLayer(_DeviceInitialised):
         return rope_tables(t, int(rope.get("rotary_dim", self.head_dim)),
                            float(rope["theta"]), rope.get("yarn"))
 
+    def _mix_scan(self, p, x):
+        """The state-space mixer, merged: scopes ``ssm_in``, ``ssm_conv``,
+        ``ssm_scan``, ``ssm_out``."""
+        import jax
+        import jax.numpy as jnp
+
+        b, t, d = x.shape
+        h, hd = self.ssm_heads, self.ssm_head_dim
+        groups, n = self.ssm_groups, self.ssm_state
+        inner, bc = h * hd, groups * n
+        with jax.named_scope("ssm_in"):
+            xn = rms_norm(x, p["norm_ssm"], self.norm_eps)
+            z, mixed, dt = jnp.split(xn @ p["ssm_in"],
+                                     [inner, 2 * inner + 2 * bc], axis=-1)
+        with jax.named_scope("ssm_conv"):
+            mixed = jax.nn.silu(ssm.causal_conv(mixed, p["ssm_conv_w"],
+                                                p["ssm_conv_b"]))
+            xs, bs, cs = jnp.split(mixed, [inner, inner + bc], axis=-1)
+        with jax.named_scope("ssm_scan"):
+            dt = jax.nn.softplus(dt.astype(jnp.float32)
+                                 + p["ssm_dt_bias"].astype(jnp.float32))
+            y = ssm.chunked_scan(
+                xs.reshape(b, t, h, hd), dt, p["ssm_a_log"],
+                bs.reshape(b, t, groups, n), cs.reshape(b, t, groups, n),
+                p["ssm_d"], self.ssm_chunk)
+            self.scan_way = ssm.STATS["way"]
+            self.scan_chunks = ssm.STATS["chunks"]
+        with jax.named_scope("ssm_out"):
+            y = ssm.gated_norm(y.reshape(b, t, inner), z, p["ssm_norm"],
+                               groups, self.norm_eps)
+            return self._merge(p, "attn", x, y @ p["ssm_out"])
+
     def _attend_plain(self, p, x):
         import jax
 
@@ -444,8 +609,9 @@ class DecoderLayer(_DeviceInitialised):
             q = (xn @ p["wq"]).reshape(b, t, h, hd)
             k = (xn @ p["wk"]).reshape(b, t, kv, hd)
             v = (xn @ p["wv"]).reshape(b, t, kv, hd)
-            cos, sin = self._rope_tables(t)
-            q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+            if self.rope:
+                cos, sin = self._rope_tables(t)
+                q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
             gate = (jax.nn.sigmoid(xn @ p["w_gate"]) if self.gating
                     else None)
         o = self._core(q, k, v)
@@ -476,14 +642,24 @@ class DecoderLayer(_DeviceInitialised):
         import jax
 
         b, t, d = x.shape
-        x = (self._attend_cca if self.attention == "cca"
-             else self._attend_plain)(p, x)
+        if self.mixer == "mamba":
+            x = self._mix_scan(p, x)
+        elif self.mixer:
+            x = (self._attend_cca if self.attention == "cca"
+                 else self._attend_plain)(p, x)
         counters = {}
+        if not self.feed_forward:
+            return x, carry, counters
+
+        def mlp(prefix, xn):
+            if self.activation == "relu2":
+                return moe.relu2(xn, p[f"{prefix}_up"], p[f"{prefix}_down"])
+            return moe.swiglu(xn, p[f"{prefix}_gate"], p[f"{prefix}_up"],
+                              p[f"{prefix}_down"])
+
         if not self.sparse:
             with jax.named_scope("dense_ffn"):
-                xn = rms_norm(x, p["norm_ffn"], self.norm_eps)
-                y = moe.swiglu(xn, p["ffn_gate"], p["ffn_up"],
-                               p["ffn_down"])
+                y = mlp("ffn", rms_norm(x, p["norm_ffn"], self.norm_eps))
             return self._merge(p, "ffn", x, y), carry, counters
         with jax.named_scope("router"):
             xn = rms_norm(x, p["norm_ffn"], self.norm_eps).reshape(b * t, d)
@@ -501,6 +677,10 @@ class DecoderLayer(_DeviceInitialised):
                     carry, p["router_norm"], p["router_w1"], p["router_b1"],
                     p["router_w2"], p["router_b2"], p["router_w3"],
                     p["router_bias"], self.experts_per_token, self.norm_eps)
+            elif self.selection_bias:
+                experts, weights, move = moe.route_balanced(
+                    xn, p["router"], p["router_bias"],
+                    self.experts_per_token, self.routed_scale)
             else:
                 experts, weights = moe.route(xn, p["router"],
                                              self.experts_per_token,
@@ -508,14 +688,13 @@ class DecoderLayer(_DeviceInitialised):
         y = None
         if self.shared_width:
             with jax.named_scope("shared_expert"):
-                y = moe.swiglu(xn, p["shared_gate"], p["shared_up"],
-                               p["shared_down"])
+                y = mlp("shared", xn)
         part, counters = moe.held_experts(
-            xn, experts, weights, p["experts_gate"], p["experts_up"],
+            xn, experts, weights, p.get("experts_gate"), p["experts_up"],
             p["experts_down"], self.first_expert)
         with jax.named_scope("combine"):
             y = (part if y is None else y + part).reshape(b, t, d)
-        if self.router == "mlp":
+        if self.moves_bias:
             counters["moves"] = {"router_bias": move}
         return self._merge(p, "ffn", x, y), carry, counters
 

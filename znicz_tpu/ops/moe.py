@@ -1,8 +1,10 @@
 """The expert layer's ops: a router over all the experts of the model (a
-linear map with sigmoid scores, ``route``; or a small MLP on a state that
-one layer hands the next, ``router_state`` / ``route_mlp``, with a
-selection bias that each step's load moves, ``balance_step``), and the
-part of the layer's result that the experts HELD here give.
+linear map with sigmoid scores, ``route``, or the same chosen by score
+plus a selection bias, ``route_balanced``; or a small MLP on a state that
+one layer hands the next, ``router_state`` / ``route_mlp``; a selection
+bias is moved by each step's load, ``balance_step``), and the part of the
+layer's result that the experts HELD here give — gated (``swiglu``) or
+two matrices around a squared ReLU (``relu2``).
 
 An expert-parallel deployment spreads a layer's routed experts over chips.
 Each chip routes its tokens over all of them (the router keeps its
@@ -33,6 +35,24 @@ def route(x, w_router, top_k: int, scale: float):
                                preferred_element_type=jnp.float32))
     top, experts = jax.lax.top_k(s, top_k)
     return experts, scale * top / jnp.sum(top, axis=-1, keepdims=True)
+
+
+def route_balanced(x, w_router, bias, top_k: int, scale: float):
+    """``(experts, weights, move)``: ``route`` with the choice made by ``s
+    + bias`` (``bias`` ``(experts,)``; no gradient reaches it) and the
+    weights still ``scale * s_e / sum of the chosen s`` from the scores
+    alone, and the move that this step's load asks of the bias
+    (``balance_step``)."""
+    import jax
+    import jax.numpy as jnp
+
+    s = jax.nn.sigmoid(jnp.dot(x, w_router,
+                               preferred_element_type=jnp.float32))
+    biased = jax.lax.stop_gradient(s) + bias.astype(jnp.float32)
+    experts = jax.lax.top_k(biased, top_k)[1]
+    top = jnp.take_along_axis(s, experts, axis=-1)
+    return (experts, scale * top / jnp.sum(top, axis=-1, keepdims=True),
+            balance_step(biased, top_k))
 
 
 def router_state(x, w_down, b_down, gamma=None, carried=None):
@@ -196,6 +216,14 @@ def swiglu(x, w_gate, w_up, w_down):
     return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
 
 
+def relu2(x, w_up, w_down):
+    """``relu(x W_u)^2 W_d``: two matrices, no gate."""
+    import jax
+    import jax.numpy as jnp
+
+    return jnp.square(jax.nn.relu(x @ w_up)) @ w_down
+
+
 def held_experts(x, experts, weights, w_gate, w_up, w_down,
                  first_expert: int):
     """The held experts' part of the layer's result, ``(tokens, hidden)``,
@@ -204,14 +232,15 @@ def held_experts(x, experts, weights, w_gate, w_up, w_down,
     ``x`` is ``(tokens, hidden)``; ``experts``/``weights`` come from
     ``route``; ``w_gate``/``w_up`` are ``(experts_held, hidden, width)``
     and ``w_down`` ``(experts_held, width, hidden)``, expert
-    ``first_expert + e`` at index ``e``.  The weight multiplies the
-    expert's output."""
+    ``first_expert + e`` at index ``e``; ``w_gate`` ``None``: experts of
+    two matrices around a squared ReLU (``relu2``).  The weight
+    multiplies the expert's output."""
     import jax
     import jax.numpy as jnp
     from jax.lax import ragged_dot
 
     tokens, top_k = experts.shape
-    n_held = w_gate.shape[0]
+    n_held = w_up.shape[0]
     with jax.named_scope("dispatch"):
         order, inverse, key, rows = dispatch(experts, first_expert, n_held)
         rows_x = _pairs_of_tokens(top_k)(x, order, inverse)
@@ -227,7 +256,11 @@ def held_experts(x, experts, weights, w_gate, w_up, w_down,
             return jnp.where(computed, ragged_dot(
                 jnp.where(computed, a, 0), w, rows), 0)
 
-        h = jax.nn.silu(grouped(rows_x, w_gate)) * grouped(rows_x, w_up)
+        if w_gate is None:
+            h = jnp.square(jax.nn.relu(grouped(rows_x, w_up)))
+        else:
+            h = (jax.nn.silu(grouped(rows_x, w_gate))
+                 * grouped(rows_x, w_up))
         out = grouped(h, w_down)
     with jax.named_scope("combine"):
         # back to (token, slot) order; pairs whose expert lives elsewhere

@@ -203,8 +203,9 @@ def settings(namespace: str = "laguna", presets: dict = None) -> dict:
     dictionary does not carry) laid over the model's."""
     cfg = getattr(root, namespace)
     preset = (presets or PRESETS)[str(cfg.get("preset"))]
-    return {"model": dict(MODELS[preset["model"]],
-                          **preset.get("assumed", {})),
+    model = preset["model"]         # a name in MODELS, or the dictionary
+    return {"model": dict(MODELS[model] if isinstance(model, str)
+                          else model, **preset.get("assumed", {})),
             "share": dict(preset["share"], **cfg.share.to_dict()),
             "loader": dict(preset["loader"], **cfg.loader.to_dict()),
             "head": dict(preset.get("head", {}), **cfg.head.to_dict())}
@@ -246,11 +247,70 @@ def family_keys(model: dict) -> dict:
     return unit
 
 
+def hybrid_unit(model: dict, share: dict, kind: str) -> dict:
+    """``DecoderLayer``'s arguments for one character of a model's
+    ``hybrid_override_pattern``: every layer of such a model is ONE part
+    — ``M`` a state-space mixer (``mamba_num_heads``, ``mamba_head_dim``,
+    ``n_groups``, ``ssm_state_size``, ``conv_kernel``, ``chunk_size``,
+    ``time_step_*``), ``*`` attention (rotated by ``rope_theta`` unless
+    the dictionary says ``attention_positions: "none"``), ``E`` routed
+    experts (``n_routed_experts``, ``moe_intermediate_size``,
+    ``moe_shared_expert_intermediate_size``, ``routed_scaling_factor``;
+    ``router_selection_bias`` gives the router a selection bias that
+    the load moves).  ``mlp_hidden_act`` ``relu2`` makes every
+    feed-forward two matrices around a squared ReLU;
+    ``rescale_prenorm_residual`` starts each part's output projection
+    ``1 / sqrt(num_hidden_layers)`` smaller."""
+    unit = {"norm_eps": float(model["layer_norm_epsilon"]),
+            "mixer": {"M": "mamba", "*": "attention"}.get(kind),
+            "feed_forward": kind == "E",
+            "activation": ("relu2" if model.get("mlp_hidden_act") == "relu2"
+                           else "swiglu"),
+            "out_scale": (int(model["num_hidden_layers"]) ** -0.5
+                          if model.get("rescale_prenorm_residual") else 1.0)}
+    if kind == "M":
+        unit.update(
+            ssm_heads=int(model["mamba_num_heads"]),
+            ssm_head_dim=int(model["mamba_head_dim"]),
+            ssm_groups=int(model["n_groups"]),
+            ssm_state=int(model["ssm_state_size"]),
+            conv_kernel=int(model["conv_kernel"]),
+            ssm_chunk=int(model["chunk_size"]),
+            dt_range=(float(model["time_step_min"]),
+                      float(model["time_step_max"]),
+                      float(model["time_step_floor"])))
+    elif kind == "*":
+        rotated = model.get("attention_positions", "rotary") != "none"
+        unit.update(
+            heads=int(model["num_attention_heads"]),
+            kv_heads=int(model["num_key_value_heads"]),
+            head_dim=int(model["head_dim"]),
+            rope=({"theta": float(model["rope_theta"]), "rotary_dim": int(
+                model["head_dim"] * model.get("partial_rotary_factor", 1))}
+                if rotated else None))
+    elif kind == "E":
+        unit.update(
+            expert_width=int(model["moe_intermediate_size"]),
+            shared_width=int(model.get(
+                "moe_shared_expert_intermediate_size", 0)),
+            experts_total=int(model["n_routed_experts"]),
+            experts_held=int(share["experts_held"]),
+            first_expert=int(share["first_expert"]),
+            experts_per_token=int(model["num_experts_per_tok"]),
+            routed_scale=float(model.get("routed_scaling_factor", 1.0)),
+            selection_bias=bool(model.get("router_selection_bias", False)))
+    else:
+        raise ValueError(f"hybrid_override_pattern holds {kind!r}")
+    return unit
+
+
 def layers(model: dict, share: dict, optimizer=None, head=None) -> list:
     """The ``StandardWorkflow`` layer list of ``share``'s part of
     ``model``: embedding, ``share["layers"]`` decoder layers, head;
     ``optimizer`` is the job's (``root.<namespace>.optimizer``), ``head``
-    further arguments of ``LMHead`` (``settings()["head"]``)."""
+    further arguments of ``LMHead`` (``settings()["head"]``).  A model
+    with ``hybrid_override_pattern`` gives one character a layer
+    (``hybrid_unit``); the others ``layer_types`` x ``mlp_layer_types``."""
     opt = optimizer or root.laguna.optimizer
     gd = {"learning_rate": float(opt.get("learning_rate")),
           "weights_decay": float(opt.get("weights_decay")),
@@ -263,7 +323,14 @@ def layers(model: dict, share: dict, optimizer=None, head=None) -> list:
     heads = model.get("num_attention_heads_per_layer",
                       [model["num_attention_heads"]] * depth)
     mlps = model.get("mlp_layer_types", ["sparse"] * depth)
+    pattern = model.get("hybrid_override_pattern")
+    eps = float(model["layer_norm_epsilon"] if pattern
+                else model["rms_norm_eps"])
     for i in range(int(share["layers"])):
+        if pattern:
+            out.append({"type": "decoder_layer", "<-": dict(gd),
+                        "->": hybrid_unit(model, share, pattern[i])})
+            continue
         kind = model["layer_types"][i]
         unit = {
             "heads": int(heads[i]),
@@ -295,7 +362,7 @@ def layers(model: dict, share: dict, optimizer=None, head=None) -> list:
         out.append({"type": "decoder_layer", "->": unit, "<-": dict(gd)})
     out.append({"type": "lm_head",
                 "->": {"vocab": int(share["vocab_held"]),
-                       "norm_eps": float(model["rms_norm_eps"]),
+                       "norm_eps": eps,
                        "tied": bool(model.get("tie_word_embeddings",
                                               False)),
                        **(head or {})},
